@@ -2,8 +2,8 @@
 
 Every bench since r02 has run on the CPU backend, so the TPU-shaped
 codepaths (ops/pallas_topk.py, ops/paged_attention.py, Tick Forge's
-jitted segments) were only ever exercised in interpret mode — and the
-BENCH_r02 k=10 crash proved interpret-green is NOT lowerable-green.
+jitted segments) are exercised by the tests in interpret mode — and a
+kernel that passes in interpret mode has not thereby lowered for a TPU.
 This module turns "will it compile for TPU" into a static, hardware-free
 proof with three layers:
 
@@ -47,7 +47,7 @@ from pathway_tpu.analysis.diagnostics import Diagnostic, Severity
 
 # Mosaic vector-layout geometry: a vreg tiles (sublane, lane) = (8, 128)
 # for 32-bit types; every Pallas block's trailing two dims must respect
-# it (see /opt/skills/guides pallas guidance and the BENCH_r02 lesson).
+# it.
 SUBLANE = 8
 LANE = 128
 
@@ -264,8 +264,8 @@ def _topk_case(b: int, d: int, n: int, k: int, pad: bool = True):
             vmem=vmem,
         )
 
-    # raw un-lane-padded k tile — the exact layout BENCH_r02 shipped,
-    # which the shared gate must keep rejecting
+    # raw un-lane-padded k tile: a (1, 1, k) block breaks the 8x128
+    # rule, and the shared gate must keep rejecting it
     nblk = max(n // pt.BLK, 1)
 
     def bad_static():
@@ -283,7 +283,7 @@ def _topk_case(b: int, d: int, n: int, k: int, pad: bool = True):
 @kernel_family("pallas_topk")
 def _topk_cases() -> list[LoweringCase]:
     cases = [
-        # the BENCH_r02 crash shape: k=10 forces the 128-lane pad
+        # k=10 is not lane-aligned: it forces the 128-lane pad
         _topk_case(8, 128, 2048, 10),
         _topk_case(8, 128, 2048, 1),
         _topk_case(8, 64, 1024, 100),
@@ -532,7 +532,7 @@ def _export_case(fn: Callable, args: tuple, platform: str, x64: bool):
     if not isinstance(fn, wrapped_t):
         fn = jax.jit(fn)
     ctx = (
-        jax.experimental.enable_x64() if x64 else contextlib.nullcontext()
+        jax.enable_x64(True) if x64 else contextlib.nullcontext()
     )
     # drop caller-frame provenance from MLIR locations: the loc() lines
     # otherwise embed the *call site* of the prover, which would make

@@ -30,8 +30,11 @@ def _spawn(args, extra: list[str]) -> int:
     env_base["PATHWAY_FIRST_PORT"] = str(args.first_port)
     # -t T workers = T engine key-shards over the device mesh (reference:
     # PATHWAY_THREADS timely workers per process, config.rs:88-121; here
-    # engine/sharded.py execs). The XLA flag only widens the host-CPU
-    # fallback pool — on a TPU host make_mesh picks the real chips.
+    # engine/sharded.py execs). The mesh is built from the default
+    # backend's own devices and T more than it has is an error
+    # (parallel/mesh.py): on a TPU host that means T <= local chips; the
+    # XLA flag below gives a CPU-backend run its T virtual devices and
+    # does nothing on a TPU.
     if args.threads > 1:
         env_base["PATHWAY_ENGINE_SHARDS"] = str(args.threads)
         flags = env_base.get("XLA_FLAGS", "")

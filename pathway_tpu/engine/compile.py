@@ -317,6 +317,8 @@ def _has_compute(node: Any) -> bool:
 _I64 = np.dtype(np.int64)
 _F64 = np.dtype(np.float64)
 _BOOL = np.dtype(bool)
+# refusal reason on a backend without native float64 (ops/backend.py)
+_F64_EMULATED = "float64 (emulated on this backend)"
 
 
 def _ev(entry: tuple, inp: dict, memo: dict):
@@ -651,7 +653,8 @@ def _build_program(
             outs.append(_ev(key_e, inp, memo))
         return tuple(outs)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
+        _refuse_emulated_float64(fn, [dtypes[c] for c in in_cols])
         jfn = jax.jit(fn)
 
     return _Program(
@@ -663,6 +666,28 @@ def _build_program(
         jfn,
         out_names,
     )
+
+
+def _refuse_emulated_float64(fn: Callable, in_dtypes: Sequence[np.dtype]):
+    """Where the backend only emulates float64 (ops/backend.py
+    float64_native: a TPU), a program that touches a float64 anywhere —
+    input, constant, cast or intermediate — cannot be proven equal to the
+    interpreter's numpy float64, so it is not compiled.  The traced
+    jaxpr is the one place every such value shows up."""
+    from pathway_tpu.ops.backend import float64_native
+
+    if float64_native():
+        return
+    import jax
+
+    closed = jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct((8,), d) for d in in_dtypes)
+    )
+    values = list(closed.jaxpr.invars)
+    for eqn in closed.jaxpr.eqns:
+        values.extend(eqn.outvars)
+    if any(getattr(v.aval, "dtype", None) == _F64 for v in values):
+        raise NotCompilable(_F64_EMULATED)
 
 
 # ---------------------------------------------------------------------------
@@ -699,7 +724,6 @@ class SegmentRunner:
         self._lock = threading.Lock()
         self.compiled_ticks = 0
         self.fallback_ticks = 0
-        self.broken = False  # permanent fallback after a runtime error
         self._min_rows = compiled_min_rows()
 
     # --- runtime hooks ----------------------------------------------------
@@ -709,43 +733,30 @@ class SegmentRunner:
 
     def process(self, t: int, inputs: list[list[DiffBatch]]) -> list[DiffBatch]:
         # gate on the raw input lengths BEFORE paying the head-batch
-        # concat: a broken (or chronically small-tick) segment must not
-        # add a full memory pass on top of the interpreter redoing the
-        # same concat inside the head exec
+        # concat: a chronically small-tick segment must not add a full
+        # memory pass on top of the interpreter redoing the same concat
+        # inside the head exec
         n = sum(len(b) for batches in inputs for b in batches)
         if not n:
             return []
-        if self.broken or n < self._min_rows:
+        if n < self._min_rows:
             return self._interpret(t, inputs)
         batch = self._head_batch(inputs)
+        # NotCompilable is a decision (this dtype signature does not
+        # lower) and runs the interpreter. Any other exception is a bug
+        # in the compiled path or a device/compiler failure: it fails
+        # the tick, so a broken device path is a red run, never a
+        # silently interpreted one.
         try:
             out = self._run_compiled(t, batch, inputs)
         except NotCompilable as nc:
             _metrics()[3].labels(nc.reason[:60]).inc()
             self._journal_fallback(t, nc.reason[:60])
             return self._interpret(t, inputs)
-        except Exception:
-            # any real failure disables the segment permanently: the
-            # interpreter is always correct, and a flapping device path
-            # would otherwise log per tick
-            logger.warning(
-                "compiled tick: segment %d failed; falling back to the "
-                "interpreter permanently for this run",
-                self.seg_id,
-                exc_info=True,
-            )
-            self.broken = True
-            _metrics()[3].labels("error").inc()
-            self._journal_fallback(t, "error", permanent=True)
-            return self._interpret(t, inputs)
-        if out is None:
-            return self._interpret(t, inputs)
         self.compiled_ticks += 1
         return out
 
-    def _journal_fallback(
-        self, t: int, reason: str, permanent: bool = False
-    ) -> None:
+    def _journal_fallback(self, t: int, reason: str) -> None:
         """Incident-journal a compiled-segment fallback ONCE per
         (segment, reason) — the fallback counter ticks every tick, the
         journal records the state transition."""
@@ -764,7 +775,6 @@ class SegmentRunner:
             tick=t,
             segment=self.seg_id,
             reason=reason,
-            permanent=permanent,
         )
 
     # --- paths ------------------------------------------------------------
@@ -809,7 +819,7 @@ class SegmentRunner:
 
     def _run_compiled(
         self, t: int, batch: DiffBatch, inputs: list[list[DiffBatch]]
-    ) -> list[DiffBatch] | None:
+    ) -> list[DiffBatch]:
         import jax
 
         prog, bucket_key = self._program_for(batch)
@@ -828,7 +838,7 @@ class SegmentRunner:
         # stay inside the window — device->host sync is part of what the
         # tick actually waits for.
         _rt0 = time.perf_counter()
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             res = prog.fn(*ins)
             outs = [np.asarray(r) for r in res]
         try:
@@ -967,7 +977,7 @@ class SegmentRunner:
                 jax.ShapeDtypeStruct((bucket,), dtypes[c])
                 for c in prog.in_cols
             )
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 flops, nbytes = _ts.estimate_program_cost(prog.fn, *args)
             _ts.roofline().register(
                 "compiled_tick",
@@ -1092,9 +1102,13 @@ def semigroup_partials(
     arg_sig = tuple(
         None if a is None else np.dtype(a.dtype).str for a in args
     )
+    from pathway_tpu.ops.backend import float64_native
+
     for a in args:
         if a is not None and a.dtype not in (_I64, _F64):
             raise NotCompilable(f"semigroup arg dtype {a.dtype}")
+        if a is not None and a.dtype == _F64 and not float64_native():
+            raise NotCompilable(_F64_EMULATED)
     key = (nb, gb, arg_sig)
     with _SEMIGROUP_LOCK:
         fn = _SEMIGROUP_CACHE.get(key)
@@ -1119,7 +1133,7 @@ def semigroup_partials(
                 )
             return (dcounts, *parts)
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             fn = jax.jit(build)
         with _SEMIGROUP_LOCK:
             _SEMIGROUP_CACHE[key] = fn
@@ -1143,7 +1157,7 @@ def semigroup_partials(
         if pad:
             ap = np.concatenate([ap, np.zeros(pad, dtype=ap.dtype)])
         arg_in.append(ap)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         res = fn(codes_p, diffs_p, *arg_in)
         res = [np.asarray(r) for r in res]
     dcounts = res[0][:nu]

@@ -305,7 +305,7 @@ class DcnJoinExec(_InnerArrangedMixin, NodeExec):
 
 
 # ---------------------------------------------------------------------------
-# Generic stateful exchange (VERDICT r4 item 2): every remaining stateful
+# Generic stateful exchange: every remaining stateful
 # operator type gets a cross-process wrapper, mirroring the reference's
 # universal Exchange pact (external/timely-dataflow/timely/src/dataflow/
 # channels/pact.rs:56-59; src/engine/dataflow/operators.rs:415 Reshard).

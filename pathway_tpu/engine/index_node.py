@@ -20,6 +20,17 @@ from pathway_tpu.internals.api import Pointer
 from pathway_tpu.internals.errors import record_error
 
 
+# What a failed search means. A query whose data is malformed — a value
+# that does not convert to a float vector, a vector of the wrong length,
+# a filter that does not parse — is a DATA error: the index raises
+# ValueError/TypeError from its host-side preparation, the error is
+# recorded in the error log and the batch answers empty. Anything else
+# (a kernel the compiler refuses, an XLA compile or runtime error, device
+# OOM) is a DEVICE error: it propagates and fails the tick, because an
+# empty 200 would report a broken device path as "no matches".
+QUERY_DATA_ERRORS = (ValueError, TypeError)
+
+
 class IndexImpl(Protocol):
     """Host-side index protocol (device work happens inside search)."""
 
@@ -160,7 +171,7 @@ class ExternalIndexExec(NodeExec):
             t0 = _time.perf_counter()
             try:
                 results = self.index.search(triples)
-            except Exception as exc:
+            except QUERY_DATA_ERRORS as exc:
                 record_error(exc, str(self.node))
                 results = [() for _ in triples]
         self._m_query_seconds.observe(

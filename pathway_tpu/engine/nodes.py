@@ -515,7 +515,7 @@ class GroupByExec(NodeExec):
         # Tick Forge: the semigroup partial-aggregation pass
         # (dcounts/sums) can run as one jitted segment_sum program —
         # opt-in/auto per backend (compile.compiled_groupby_enabled);
-        # None = not yet resolved, False after any device failure
+        # None = not yet resolved
         self._compiled_semigroup: bool | None = None
 
     def enable_state_ledger(self) -> None:
@@ -738,20 +738,13 @@ class GroupByExec(NodeExec):
                 a if (s.kind in ("sum", "avg")) else None
                 for s, a in zip(self.specs, arg_arrays)
             ]
+            # NotCompilable is a decision (unsupported dtype this batch:
+            # host path below); any other failure of the device program
+            # fails the tick, as in compile.SegmentRunner.process
             try:
                 return semigroup_partials(codes, diffs, sem_args, nu)
             except NotCompilable:
-                pass  # unsupported dtype this batch: host path below
-            except Exception:
-                import logging
-
-                logging.getLogger("pathway_tpu").warning(
-                    "compiled groupby partials failed for %s; using the "
-                    "host scatter path from now on",
-                    self.node,
-                    exc_info=True,
-                )
-                self._compiled_semigroup = False
+                pass
         dcounts = np.zeros(nu, dtype=np.int64)
         np.add.at(dcounts, codes, diffs)
         partials: list[np.ndarray | None] = []

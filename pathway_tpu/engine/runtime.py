@@ -361,22 +361,13 @@ class Runtime:
             node.id: node.make_exec() for node in self.order
         }
         # Tick Forge: fuse stateless operator chains into jitted XLA
-        # programs (engine/compile.py). Planning failures are never
-        # fatal — the interpreter path below is always complete.
+        # programs (engine/compile.py). Planning is structural (no
+        # device); a failure in it is a bug and raises.
         # PATHWAY_COMPILED_TICK=0 skips planning entirely (byte-
         # identical interpreter).
-        self.compiled_plan = None
-        try:
-            from pathway_tpu.engine.compile import plan_segments
+        from pathway_tpu.engine.compile import plan_segments
 
-            self.compiled_plan = plan_segments(self.order, self.execs)
-        except Exception:
-            import logging
-
-            logging.getLogger("pathway_tpu").warning(
-                "compiled-tick planning failed; running interpreted",
-                exc_info=True,
-            )
+        self.compiled_plan = plan_segments(self.order, self.execs)
         self.autocommit_ms = autocommit_ms
         self.on_tick = on_tick
         self.current_time = 0

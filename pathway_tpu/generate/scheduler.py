@@ -320,11 +320,12 @@ class DecodeScheduler:
         self.pool = PagePool(self.config.n_pages)
         self.ledger = KvLedger()
         if self.config.kernel == "auto":
-            import jax
+            # the Pallas kernel where it compiles (a TPU), the pure-JAX
+            # twin elsewhere — the mode comes from ops/backend.py, the
+            # one place it is decided
+            from pathway_tpu.ops.backend import pallas_interpret
 
-            self.kernel = (
-                "pallas" if jax.default_backend() == "tpu" else "ref"
-            )
+            self.kernel = "ref" if pallas_interpret() else "pallas"
         else:
             self.kernel = self.config.kernel
         self._lock = threading.Lock()
@@ -336,6 +337,7 @@ class DecodeScheduler:
         self._step_count = 0
         self._n_params: int | None = None  # roofline: counted on demand
         self._stopping = False
+        self._failed: str | None = None  # cause of a failed decode step
         # out-of-thread snapshot(): executed AT the step boundary by
         # the decode thread (the pools are donated into the jitted
         # step — touching them mid-step from another thread races the
@@ -426,6 +428,8 @@ class DecodeScheduler:
                 1.0,
             )
         with self._lock:
+            if self._failed is not None:
+                raise ShedError(503, self._failed, 1.0)
             if self._stopping:
                 raise ShedError(503, "generation scheduler stopped", 1.0)
             backlog = len(self._waiting) + len(self._staged)
@@ -475,8 +479,13 @@ class DecodeScheduler:
                 # tenant cannot bank virtual credit
                 self.tenant_ledger.note_dispatched(r.order)
         with self._lock:
-            self._staged.extend(reqs)
-            self._cond.notify()
+            cause = self._failed
+            if cause is None:
+                self._staged.extend(reqs)
+                self._cond.notify()
+                return
+        for r in reqs:  # queued before the failure, flushed after it
+            r.finish({"status": 500, "error": cause})
 
     def _reject(self, req: Any, exc: BaseException) -> None:
         if isinstance(exc, DeadlineExceeded):
@@ -511,23 +520,31 @@ class DecodeScheduler:
                 return
             try:
                 self._step()
-            except Exception:
+            except Exception as exc:
+                # Nothing a client sends can make a step raise (tokens
+                # are bytes, callbacks are guarded one by one), so this
+                # is the device, the compiler or a bug.  Fail-stop: the
+                # batch answers 500 naming the cause, every later
+                # submit() sheds 503 with it, stats()/health carry it,
+                # and the loop ends — no request is ever answered by a
+                # plane whose decode step does not run.
                 import logging
 
                 logging.getLogger("pathway_tpu").exception(
-                    "generate: decode step failed; dropping the batch"
+                    "generate: decode step failed; generation stopped"
                 )
+                cause = f"decode step failed: {type(exc).__name__}: {exc}"
                 with self._lock:
-                    doomed, self._active = self._active, []
-                for s in doomed:
-                    self._finish_seq(
-                        s,
-                        {
-                            "status": 500,
-                            "error": "decode step failed",
-                        },
-                        outcome="error",
-                    )
+                    self._failed = cause
+                    doomed = self._active + self._waiting + self._staged
+                    self._active, self._waiting, self._staged = [], [], []
+                for item in doomed:
+                    result = {"status": 500, "error": cause}
+                    if isinstance(item, _Seq):
+                        self._finish_seq(item, result, outcome="error")
+                    else:
+                        item.finish(result)
+                return
 
     def _sweep_expired(self, now: float) -> None:
         """Deadline propagation MID-decode: expired actives answer 504
@@ -959,6 +976,7 @@ class DecodeScheduler:
                 "free_pages": self.pool.free_pages,
                 "page_capacity": self.pool.capacity,
                 "kernel": self.kernel,
+                "failed": self._failed,
             }
 
     def drain(self, timeout: float = 30.0) -> bool:

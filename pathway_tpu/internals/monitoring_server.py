@@ -5,8 +5,7 @@ TPU-native equivalent of the reference's per-process metrics server
 20000 + process_id with input/output latency gauges), rebuilt on the
 Flight Recorder registry (pathway_tpu/observability): ``/metrics`` renders
 the process-wide MetricsRegistry (runtime counters are promoted onto it
-at scrape time), and the debug endpoints answer the questions the
-BENCH_r05 hung-probe investigation couldn't: ``/debug/threads``
+at scrape time), and the debug endpoints: ``/debug/threads``
 (all-thread stack dump), ``/debug/graph`` (per-node rows/ns/backlog as
 JSON), ``/debug/profile?seconds=N`` (on-demand jax profiler trace),
 ``/debug/trace?seconds=N`` (the Trace Weaver span ring as Chrome

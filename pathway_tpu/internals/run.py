@@ -81,6 +81,9 @@ def run(
             "are disabled — set PATHWAY_JAX_DISTRIBUTED=1 to join the "
             "device group as well"
         )
+    from pathway_tpu.internals.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     runtime = Runtime(seeds, autocommit_ms=autocommit_duration_ms)
     G.runtime = runtime
     G.last_runtime = runtime

@@ -1,9 +1,8 @@
 """On-demand debugging surfaces: thread dumps, graph tables, profiling.
 
-``thread_stack_dump`` is the tool the BENCH_r05 hung-probe investigation
-was missing — eight TPU probes spent 90 s inside backend init with zero
-visibility into *where*; a GET /debug/threads against a live process
-answers that in one request. ``take_profile`` wraps ``jax.profiler``
+``thread_stack_dump`` answers *where* a live process is stuck: a GET
+/debug/threads returns every thread's stack in one request.
+``take_profile`` wraps ``jax.profiler``
 trace capture (guarded — callers surface 501 when unavailable instead of
 crashing the serving process).
 """
@@ -76,7 +75,7 @@ def graph_table(runtime: Any) -> list[dict]:
         # back to the interpreter (tail carries the counters; member
         # rows/ns are attributed to the tail)
         seg = seg_of.get(node.id)
-        row["compiled"] = seg is not None and not seg.broken
+        row["compiled"] = seg is not None
         if seg is not None:
             row["segment"] = seg.seg_id
             if node.id == seg.tail.id:

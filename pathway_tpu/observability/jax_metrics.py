@@ -4,10 +4,8 @@ Bridges ``jax.monitoring`` (compile events emitted by jit/pjit) and
 per-device memory stats onto the metrics registry, plus a
 ``pathway_build_info`` info-style metric carrying platform/backend
 labels. Everything here is defensive: the gauges must never *initialize*
-a backend (the hung-probe failure mode BENCH_r05 recorded was 90 s spent
-inside backend init — a scrape that triggered init would hang the same
-way), and must degrade to absent series when jax or a given hook is
-unavailable.
+a backend (a scrape is not the place to claim the chip), and must
+degrade to absent series when jax or a given hook is unavailable.
 """
 
 from __future__ import annotations
@@ -70,11 +68,24 @@ def _install_build_info(registry: MetricsRegistry) -> None:
 
     info = registry.gauge(
         "pathway_build_info",
-        "constant 1; build/runtime identity in labels (platform/backend "
-        "resolve once jax initializes — scraping never forces init)",
-        labelnames=("version", "python", "jax", "platform", "backend"),
+        "constant 1; build/runtime identity in labels (platform/backend/"
+        "pallas resolve once jax initializes — scraping never forces "
+        "init; pallas is compiled|interpret, see ops/backend.py)",
+        labelnames=(
+            "version", "python", "jax", "platform", "backend", "pallas"
+        ),
     )
-    state = {"platform": "uninitialized", "backend": "uninitialized"}
+    state = dict.fromkeys(("platform", "backend", "pallas"), "uninitialized")
+
+    def _labels() -> tuple:
+        return (
+            pw_version,
+            _platform.python_version(),
+            jax_version,
+            state["platform"],
+            state["backend"],
+            state["pallas"],
+        )
 
     def _collect() -> None:
         if state["platform"] == "uninitialized":
@@ -82,24 +93,15 @@ def _install_build_info(registry: MetricsRegistry) -> None:
             if devices:
                 # retire the placeholder series, or a scrape that raced
                 # backend init would expose two build_info identities
-                info.remove(
-                    pw_version,
-                    _platform.python_version(),
-                    jax_version,
-                    state["platform"],
-                    state["backend"],
-                )
+                info.remove(*_labels())
                 state["platform"] = devices[0].platform
                 state["backend"] = getattr(
                     devices[0], "device_kind", devices[0].platform
                 )
-        info.labels(
-            pw_version,
-            _platform.python_version(),
-            jax_version,
-            state["platform"],
-            state["backend"],
-        ).set(1)
+                from pathway_tpu.ops.backend import pallas_mode
+
+                state["pallas"] = pallas_mode()
+        info.labels(*_labels()).set(1)
 
     registry.register_collector(_collect)
 

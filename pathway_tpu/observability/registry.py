@@ -72,7 +72,7 @@ def log_linear_buckets(
     """Bucket upper bounds: each power-of-two octave [b, 2b) split into
     ``per_octave`` linear sub-buckets (HdrHistogram layout). The default
     spans 0.1 ms .. 64 s in ~78 buckets — wide enough for a sub-ms device
-    top-k and a 60 s hung backend init in the same histogram, with
+    top-k and a minute-long cold compile in the same histogram, with
     quantile interpolation error bounded by one sub-bucket (≤25%)."""
     bounds: list[float] = []
     base = lo

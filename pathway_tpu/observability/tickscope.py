@@ -673,16 +673,19 @@ def wire_snapshot() -> dict[str, dict[str, int]]:
 # ---------------------------------------------------------------------------
 # roofline attribution
 
-# peak FLOP/s per jax platform when PATHWAY_PEAK_FLOPS is unset. TPU
-# numbers are the published per-chip bf16 peaks; the CPU entry is a
-# deliberately crude per-core estimate (2 GHz x 2 FMA x 8 f32 lanes) —
-# set PATHWAY_PEAK_FLOPS for honest CPU MFU, the *achieved* FLOP/s
-# column is measured either way.
+# peak FLOP/s when PATHWAY_PEAK_FLOPS is unset. TPU entries are the
+# published per-chip bf16 peaks, matched as substrings of the lowercased
+# ``device_kind`` jax reports (a v5e reports "TPU v5 lite"); a TPU that
+# is not listed is an error, never another chip's figure. The CPU entry
+# is a deliberately crude per-core estimate (2 GHz x 2 FMA x 8 f32
+# lanes) — set PATHWAY_PEAK_FLOPS for honest CPU MFU, the *achieved*
+# FLOP/s column is measured either way.
 _PEAK_TABLE = {
-    "tpu v4": 275e12,
-    "tpu v5e": 197e12,
-    "tpu v5p": 459e12,
-    "tpu v6e": 918e12,
+    "v4": 275e12,
+    "v5 lite": 197e12,
+    "v5e": 197e12,
+    "v5p": 459e12,
+    "v6e": 918e12,
 }
 _CPU_CORE_PEAK = 32e9
 
@@ -694,18 +697,19 @@ def peak_flops() -> float:
             return float(env)
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        if dev.platform == "tpu":
-            kind = getattr(dev, "device_kind", "").lower()
-            for name, peak in _PEAK_TABLE.items():
-                if name.replace("tpu ", "") in kind:
-                    return peak
-            return 275e12  # unknown TPU: v4 as the conservative floor
-    except Exception:
-        pass
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        kind = dev.device_kind.lower()
+        for name, peak in _PEAK_TABLE.items():
+            if name in kind:
+                return peak
+        raise LookupError(
+            f"no peak FLOP/s on record for TPU device_kind "
+            f"{dev.device_kind!r}: add it to tickscope._PEAK_TABLE or "
+            "set PATHWAY_PEAK_FLOPS"
+        )
     return float(os.cpu_count() or 1) * _CPU_CORE_PEAK
 
 

@@ -1,7 +1,7 @@
 """IVF (inverted-file) KNN kernels — the scale-out story past HBM-resident
 brute force.
 
-Design note (VERDICT r3 item 10): the reference carries usearch HNSW for
+Design note: the reference carries usearch HNSW for
 sub-linear queries (reference: src/external_integration/
 usearch_integration.rs:20). HNSW is a pointer-chasing CPU structure — the
 worst possible shape for a TPU. The TPU-native answer is IVF: both of its

@@ -166,10 +166,6 @@ def shard_base_indices(n: int, n_shards: int) -> np.ndarray:
 def _sharded_topk_impl(queries, corpus, valid, base_idx, k, metric, bf16, mesh, axis):
     from jax.sharding import PartitionSpec as P
 
-    from pathway_tpu.parallel.collectives import _shard_map_compat
-
-    shard_map, check_kw = _shard_map_compat()
-
     def local(q, c, v, b):
         s = _scores(q, c, metric, bf16)
         s = jnp.where(v[None, :], s, -jnp.inf)
@@ -184,12 +180,12 @@ def _sharded_topk_impl(queries, corpus, valid, base_idx, k, metric, bf16, mesh, 
         ix_f = jnp.where(jnp.isfinite(sc_f), ix_f, -1)
         return sc_f, ix_f
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(axis, None), P(axis), P(axis)),
         out_specs=(P(), P()),
-        **check_kw,
+        check_vma=False,
     )(queries, corpus, valid, base_idx)
 
 

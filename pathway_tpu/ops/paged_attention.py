@@ -12,9 +12,8 @@ the KV block index_map reads it, so grid step (b, j) stages sequence
 b's j-th logical page (one [H, P, Dp] tile) into VMEM without ever
 materializing a gathered [B, L, H, Dp] tensor in HBM.
 
-Layout honors the Mosaic (8, 128) tiling rule the same way the
-pallas_topk fix did (the BENCH_r02 lesson: interpret-green is NOT
-lowerable-green):
+Layout honors the Mosaic (8, 128) tiling rule the same way pallas_topk
+does (a kernel that passes in interpret mode has not thereby lowered):
 
 * pools are ``[n_pages, H, P, Dp]`` with ``Dp = head_dim`` padded up to
   a 128-lane multiple (``lane_pad``); the padded tail lanes are zero in
@@ -34,9 +33,9 @@ seq_len 0) use a large-negative finite mask value instead of -inf so
 the rescale never produces NaN; their denominator stays 0 and the
 final write zero-fills them.
 
-``paged_attention_ref`` is the jitted pure-JAX twin — the CPU/interpret
-fallback the decode step uses off-TPU and the differential oracle the
-tests pin the kernel against.
+``paged_attention_ref`` is the jitted pure-JAX twin — what the decode
+step runs where the kernel does not compile (any backend but a TPU) and
+the differential oracle the tests pin the kernel against.
 """
 
 from __future__ import annotations
@@ -46,9 +45,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # shared 8x128 gate: analysis/lowering.py is the single source of truth
 # for the Mosaic tiling rules (re-exported for existing callers)
+from pathway_tpu.ops.backend import pallas_interpret
 from pathway_tpu.analysis.lowering import (  # noqa: F401
     LoweringRuleViolation,
     RULE_LANE_PAD,
@@ -208,9 +209,13 @@ def paged_attention(
     seq_lens: jax.Array,  # [B] int32 valid tokens per sequence
     *,
     sm_scale: float,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """One ragged paged-attention decode step: [B, H, Dp] outputs."""
+    """One ragged paged-attention decode step: [B, H, Dp] outputs.
+    ``interpret=None`` takes the mode from the backend (compiled on a
+    TPU, see ops/backend.py); tests pass it explicitly."""
+    if interpret is None:
+        interpret = pallas_interpret()
     b, h, dp = q.shape
     n_pages, _h, p, _dp = k_pool.shape
     max_pages = page_tables.shape[1]
@@ -218,24 +223,17 @@ def paged_attention(
         b, h, p, dp, n_pages, max_pages
     )
     kernel = functools.partial(_decode_kernel, p, float(sm_scale))
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[spec for spec, _ in in_specs],
-            out_specs=out_specs[0][0],
-            scratch_shapes=[
-                pltpu.VMEM((h, 128), jnp.float32),
-                pltpu.VMEM((h, 128), jnp.float32),
-                pltpu.VMEM((h, dp), jnp.float32),
-            ],
-        )
-    except ImportError:  # pragma: no cover - pallas TPU frontend absent
-        raise NotImplementedError(
-            "pallas TPU grid spec unavailable; use paged_attention_ref"
-        ) from None
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=grid,
+        in_specs=[spec for spec, _ in in_specs],
+        out_specs=out_specs[0][0],
+        scratch_shapes=[
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, dp), jnp.float32),
+        ],
+    )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -261,8 +259,8 @@ def paged_attention_ref(
     sm_scale: float | jax.Array = 1.0,
 ) -> jax.Array:
     """Jitted pure-JAX twin — gathers each sequence's pages dense and
-    runs a masked softmax.  The CPU/interpret fallback of the decode
-    step and the differential oracle for the Pallas kernel."""
+    runs a masked softmax.  The decode step's attention off the TPU and
+    the differential oracle for the Pallas kernel."""
     b, h, dp = q.shape
     _n, _h, p, _dp = k_pool.shape
     max_pages = page_tables.shape[1]

@@ -10,7 +10,7 @@ so only [B, nblk*KP] candidates ever return to HBM instead of the full
 argument as ops/knn._masked_topk). Runs in interpreter mode off-TPU so
 tests cover it on the CPU backend.
 
-TPU lowering constraint (the round-2 failure): the last two dims of every
+TPU lowering constraint: the last two dims of every
 block must be divisible by (8, 128) or equal the overall array dims. The
 outputs are therefore laid out 2-D as [B, nblk*KP] where KP = k padded up
 to a multiple of 128 — each grid step writes its own lane-aligned (B, KP)
@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 
 # the 8x128 rules live in ONE place (analysis/lowering.py) — re-exported
 # here for the existing test gates and callers
+from pathway_tpu.ops.backend import pallas_interpret
 from pathway_tpu.analysis.lowering import (  # noqa: F401
     check_block_specs,
     check_tpu_block_rules,
@@ -116,10 +117,13 @@ def pallas_block_topk(
     prep: jax.Array,  # [N, D] prepared corpus (N multiple of BLK)
     valid: jax.Array,  # [N] bool
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-block candidates: ([B, nblk, k] scores, [B, nblk, k] global
-    indices)."""
+    indices).  ``interpret=None`` takes the mode from the backend
+    (compiled on a TPU, see ops/backend.py)."""
+    if interpret is None:
+        interpret = pallas_interpret()
     bq, d = queries.shape
     n = prep.shape[0]
     assert n % BLK == 0, "pad the corpus to a multiple of BLK"
@@ -148,7 +152,7 @@ def pallas_dense_topk(
     valid: jax.Array,
     k: int,
     metric: str = "dot",  # dot | cosine
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Exact dense top-k via the Pallas block kernel + lax.top_k merge.
     Owns the query-side metric handling (normalize + cast to the corpus

@@ -62,31 +62,15 @@ def exchange_by_shard(values, dest_shard, mesh, axis: str = "data"):
     return out, counts
 
 
-def _shard_map_compat():
-    """(shard_map, replication-check kwarg) across the API move: new
-    jax exposes `jax.shard_map` with `check_vma`; 0.4.x ships it under
-    `jax.experimental.shard_map` with `check_rep`."""
-    try:
-        from jax import shard_map  # jax >= 0.6
-
-        return shard_map, {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map, {"check_rep": False}
-
-
 @functools.partial(jax.jit, static_argnames=("mesh", "axis"))
 def frontier_allreduce(local_time, mesh, axis: str = "data"):
     """Global frontier = min over shards' local clocks — the tiny all-reduce
     per tick replacing timely's progress-update broadcast
     (reference: timely progress tracking, SURVEY §5.8)."""
-    shard_map, check_kw = _shard_map_compat()
-
     def local(t):
         return jax.lax.pmin(t, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
-        **check_kw,
+        check_vma=False,
     )(local_time)

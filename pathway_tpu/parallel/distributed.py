@@ -45,9 +45,9 @@ def process_env() -> tuple[int, int, str]:
 
 def maybe_initialize() -> bool:
     """Join the process group when PATHWAY_PROCESSES > 1 (idempotent).
-    Returns True when running multi-process. On the CPU backend the gloo
-    collectives implementation is selected so cross-process collectives
-    work in tests and the driver's dryrun."""
+    Returns True when running multi-process. On the CPU backend jax's
+    default collectives implementation (gloo) carries the cross-process
+    collectives of the tests and the CPU dry run."""
     global _initialized
     n, pid, coord = process_env()
     if n <= 1:
@@ -56,10 +56,6 @@ def maybe_initialize() -> bool:
         return True
     import jax
 
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # unavailable on this jax version: TPU backends don't need it
     try:
         jax.distributed.initialize(
             coordinator_address=coord, num_processes=n, process_id=pid

@@ -89,10 +89,6 @@ def _impl(n_shards: int, capacity: int, mesh: Any, axis: str):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from pathway_tpu.parallel.collectives import _shard_map_compat
-
-    shard_map, check_kw = _shard_map_compat()
-
     def local(words, dst):
         # words: [per, W] i32; dst: [per] i32 (-1 = padding row)
         per, width = words.shape
@@ -120,12 +116,12 @@ def _impl(n_shards: int, capacity: int, mesh: Any, axis: str):
         )
         return recv.reshape(n_shards * capacity, width + 1)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis)),
         out_specs=P(axis, None),
-        **check_kw,
+        check_vma=False,
     )
 
 

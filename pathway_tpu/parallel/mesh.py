@@ -23,10 +23,12 @@ def make_mesh(
     *,
     backend: str | None = None,
 ):
-    """Build a Mesh over available devices. Falls back to the virtual CPU
-    device pool (xla_force_host_platform_device_count) when the accelerator
-    has fewer devices than requested — how unit tests and the driver's
-    dryrun exercise multi-chip code paths on one host."""
+    """Build a Mesh over this process's devices of the default backend
+    (or of ``backend``).  Asking for more devices than the backend has
+    raises: a mesh is never silently built from another backend's
+    devices.  Unit tests and the CPU dry run get their eight devices
+    from ``--xla_force_host_platform_device_count`` on the CPU backend
+    itself."""
     import jax
     from jax.sharding import Mesh
 
@@ -38,17 +40,11 @@ def make_mesh(
         # process's non-addressable devices; cross-process meshes are
         # built explicitly via parallel.distributed.global_mesh
         devices = jax.local_devices()
-        if n_devices is not None and len(devices) < n_devices:
-            try:
-                cpu = jax.devices("cpu")
-                if len(cpu) >= n_devices:
-                    devices = cpu
-            except RuntimeError:
-                pass
     if n_devices is not None:
         if len(devices) < n_devices:
             raise ValueError(
-                f"requested {n_devices} devices, have {len(devices)}"
+                f"requested {n_devices} devices, the "
+                f"{devices[0].platform} backend has {len(devices)}"
             )
         devices = devices[:n_devices]
     shape = _factor_shape(len(devices), len(axis_names))
@@ -97,19 +93,8 @@ def get_engine_mesh() -> tuple[Any, str] | None:
         _engine_mesh_resolved = True
         n = os.environ.get("PATHWAY_ENGINE_SHARDS", "")
         if n.isdigit() and int(n) > 1:
-            try:
-                _engine_mesh = (make_mesh(int(n)), "data")
-            except (ValueError, RuntimeError) as exc:
-                # not enough devices on this host (e.g. the launcher didn't
-                # set xla_force_host_platform_device_count) — run unsharded
-                # rather than crash the pipeline at graph build
-                import logging
-
-                logging.getLogger("pathway_tpu").warning(
-                    "PATHWAY_ENGINE_SHARDS=%s but no %s-device mesh is "
-                    "available (%s); engine sharding disabled",
-                    n,
-                    n,
-                    exc,
-                )
+            # too few devices raises: sharding that was asked for is
+            # never quietly dropped (on a CPU host the launcher widens
+            # the device pool with xla_force_host_platform_device_count)
+            _engine_mesh = (make_mesh(int(n)), "data")
     return _engine_mesh

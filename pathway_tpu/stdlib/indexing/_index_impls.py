@@ -19,7 +19,12 @@ from typing import Any, Sequence
 
 import numpy as np
 
-from pathway_tpu.ops.knn import DeviceCorpus, dense_topk, sharded_topk
+from pathway_tpu.engine.index_node import QUERY_DATA_ERRORS
+from pathway_tpu.ops.knn import (
+    DeviceCorpus,
+    dense_topk_prepared,
+    sharded_topk,
+)
 from pathway_tpu.stdlib.indexing._filters import compile_filter
 
 
@@ -179,10 +184,54 @@ class TpuDenseKnnIndex:
             for key, slot in cs["slot_of"].items():
                 c.upsert(key, cs["host"][slot])
 
+    def _device_topk(self, qmat: np.ndarray, eff_k: int):
+        """The device half of a search: (scores, slot indices) of the
+        top ``eff_k`` rows per query.  The kernel is picked from the
+        configuration and the shapes; a kernel the compiler refuses
+        raises — nothing here retries on another implementation."""
+        if self.mesh is not None:
+            corpus_arr, valid = self.corpus.device_arrays()
+            return sharded_topk(
+                qmat,
+                corpus_arr,
+                valid,
+                eff_k,
+                mesh=self.mesh,
+                axis=self.axis,
+                metric=self.metric,
+            )
+        # float32 rows — but on a TPU a float32 matmul at default
+        # precision multiplies in bf16, in XLA and in the Pallas kernel
+        # alike (measured on a v5e, PR 21: score error 3.5e-4, recall@10
+        # 0.986 vs exact float32 on gaussian rows; "highest" precision
+        # gives 7.5e-8 / 1.0). The ids are the contract; scores carry
+        # about three digits there.
+        prep, c2, valid = self.corpus.prepared_arrays(
+            self.metric, bf16=False
+        )
+        if self.kernel == "pallas" and self.metric in ("cosine", "dot"):
+            from pathway_tpu.ops import pallas_topk as pt
+
+            if pt.supported(prep.shape[0], eff_k):
+                return pt.pallas_dense_topk(
+                    qmat, prep, valid, eff_k, metric=self.metric
+                )
+        return dense_topk_prepared(
+            qmat, prep, c2, valid, eff_k, metric=self.metric, bf16=False
+        )
+
     def search(self, queries: Sequence[tuple[Any, int, Any]]):
         if self.corpus is None or len(self.corpus) == 0 or not queries:
             return [() for _ in queries]
+        # host-side validation: everything a malformed query can break is
+        # checked here, before any device work (engine/index_node.py
+        # QUERY_DATA_ERRORS — recorded, answered empty)
         qmat = np.stack([_as_vector(q) for q, _k, _f in queries])
+        if qmat.ndim != 2 or qmat.shape[1] != self.corpus.dim:
+            raise ValueError(
+                f"query vectors of shape {qmat.shape[1:]} against a "
+                f"{self.corpus.dim}-dimensional corpus"
+            )
         n_q = qmat.shape[0]
         bucket = n_q
         if self.shape_ladder:
@@ -203,47 +252,15 @@ class TpuDenseKnnIndex:
             len(self.corpus), max_k * 4 if has_filter else max_k
         )
         _rt0 = _time.perf_counter()
-        if self.mesh is not None:
-            corpus_arr, valid = self.corpus.device_arrays()
-            scores, idx = sharded_topk(
-                qmat,
-                corpus_arr,
-                valid,
-                eff_k,
-                mesh=self.mesh,
-                axis=self.axis,
-                metric=self.metric,
-            )
-        else:
-            from pathway_tpu.ops.knn import dense_topk_prepared
-
-            # f32 end to end: the inner-index path serves RAG retrieval on
-            # modest corpora where exact reference-parity scores matter;
-            # the bulk bench path keeps bf16 on the MXU
-            prep, c2, valid = self.corpus.prepared_arrays(
-                self.metric, bf16=False
-            )
-            scores = idx = None
-            if self.kernel == "pallas" and self.metric in ("cosine", "dot"):
-                from pathway_tpu.ops import pallas_topk as pt
-
-                if pt.supported(prep.shape[0], eff_k):
-                    import jax
-
-                    interpret = jax.devices()[0].platform == "cpu"
-                    scores, idx = pt.pallas_dense_topk(
-                        qmat,
-                        prep,
-                        valid,
-                        eff_k,
-                        metric=self.metric,
-                        interpret=interpret,
-                    )
-            if scores is None:
-                scores, idx = dense_topk_prepared(
-                    qmat, prep, c2, valid, eff_k, metric=self.metric,
-                    bf16=False,
-                )
+        try:
+            scores, idx = self._device_topk(qmat, eff_k)
+        except QUERY_DATA_ERRORS as exc:
+            # the inputs passed the host checks above: a shape or
+            # lowering complaint from here on is the device program's,
+            # and must fail the tick instead of answering empty
+            raise RuntimeError(
+                f"device top-k failed to lower or run: {exc}"
+            ) from exc
         scores = np.asarray(scores, dtype=np.float64)[:n_q]
         idx = np.asarray(idx)[:n_q]
         # Tick Scope roofline, family "topk": analytic FLOPs (the score

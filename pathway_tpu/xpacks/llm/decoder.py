@@ -135,7 +135,7 @@ def _ln(x: jax.Array, scale: jax.Array, bias: jax.Array) -> jax.Array:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "kernel", "interpret"),
+    static_argnames=("cfg", "kernel"),
     donate_argnums=(3, 4),
 )
 def decode_step(
@@ -150,7 +150,6 @@ def decode_step(
     *,
     cfg: DecoderConfig,
     kernel: str = "ref",
-    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step over the paged KV cache: write this token's K/V,
     attend over each sequence's cached prefix (ragged), and return
@@ -178,9 +177,7 @@ def decode_step(
         k_pool = k_pool.at[li, page_ids, :, slots, :].set(k)
         v_pool = v_pool.at[li, page_ids, :, slots, :].set(v)
         attend = (
-            functools.partial(paged_attention, interpret=interpret)
-            if kernel == "pallas"
-            else paged_attention_ref
+            paged_attention if kernel == "pallas" else paged_attention_ref
         )
         att = attend(
             q, k_pool[li], v_pool[li], page_tables, seq_lens,

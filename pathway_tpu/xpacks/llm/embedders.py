@@ -185,14 +185,9 @@ class SentenceTransformerEmbedder(BaseEmbedder):
                 min(1.0, len(texts) / pad_bucket)
             )
             if not chips:
-                # forward_ids just used the backend, so counting devices
-                # cannot trigger a fresh (possibly hanging) backend init
-                try:
-                    import jax
+                import jax
 
-                    chips.append(max(1, jax.local_device_count()))
-                except Exception:
-                    chips.append(1)
+                chips.append(max(1, jax.local_device_count()))
             if dt > 0:
                 m_rate.set(len(texts) / dt / chips[0])
             return [out[i] for i in range(len(texts))]

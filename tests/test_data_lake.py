@@ -1,4 +1,4 @@
-"""Data-lake writer depth (VERDICT r3 item 8; reference:
+"""Data-lake writer depth (reference:
 src/connectors/data_lake/{delta,iceberg,writer}.rs): transactional
 append/overwrite, schema-evolution guards, object storage, compaction,
 round-trip write->read for both formats."""

@@ -1,4 +1,4 @@
-"""Multi-process execution smoke tests (VERDICT r2 item 9): two real OS
+"""Multi-process execution smoke tests: two real OS
 processes join via jax.distributed (gloo CPU collectives) and run the
 corpus-sharded KNN with a true cross-process collective merge, asserting
 exact equality with a single-process reference. Pattern: reference
@@ -119,7 +119,7 @@ def test_process_env_defaults(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# DCN rung: cross-process host-row exchange (VERDICT r3 item 2)
+# DCN rung: cross-process host-row exchange
 
 _DCN_WORDCOUNT = textwrap.dedent(
     """

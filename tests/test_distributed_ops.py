@@ -1,5 +1,5 @@
-"""Cross-process exchange coverage for EVERY stateful operator type
-(VERDICT r4 item 2): a 2-process group and a 1-process run execute the
+"""Cross-process exchange coverage for EVERY stateful operator type:
+a 2-process group and a 1-process run execute the
 same pipelines over the same (sharded) inputs; the union of per-process
 outputs must equal the single-process result exactly — keys included,
 since row keys are value hashes and identical on every process

@@ -1,5 +1,4 @@
-"""answer_with_geometric_rag_strategy_from_index (VERDICT r3 item 9;
-reference: xpacks/llm/question_answering.py:162-215) — fake-LLM test of
+"""answer_with_geometric_rag_strategy_from_index (reference: xpacks/llm/question_answering.py:162-215) — fake-LLM test of
 the doc-count doubling loop."""
 
 import pathway_tpu as pw

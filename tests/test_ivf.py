@@ -1,4 +1,4 @@
-"""IVF scale-out index (VERDICT r3 item 10; design note: ops/ivf.py;
+"""IVF scale-out index (design note: ops/ivf.py;
 reference counterpart: usearch HNSW, usearch_integration.rs:20)."""
 
 import numpy as np

@@ -70,7 +70,7 @@ def test_prover_topk_family_lowers_pad_ladder():
     rep = L.prove_lowering(families=["pallas_topk"], include_live=False)
     assert not rep.findings, [f.message for f in rep.findings]
     lowered = rep.by_status("lowered")
-    # pad ladder incl. the BENCH_r02 crash shape k=10
+    # pad ladder incl. k=10 (not lane-aligned: forces the pad)
     assert {e["case"] for e in lowered} >= {"b8_d128_n2048_k10"}
     for e in lowered:
         assert len(e["stablehlo_sha256"]) == 64

@@ -89,8 +89,8 @@ def test_lane_pad_boundaries():
 
 
 def test_lowering_gate_rejects_unpadded_head_dim():
-    """The 8x128 rule statically: an UNpadded head_dim (the BENCH_r02
-    class of failure — interpret-green, crashes at Mosaic lowering)
+    """The 8x128 rule statically: an UNpadded head_dim (passes in interpret
+    mode, fails at Mosaic lowering)
     must be rejected by the gate even on the CPU backend."""
     from pathway_tpu.ops import paged_attention as pa
 
@@ -139,7 +139,6 @@ def test_decode_step_pallas_vs_ref_twin():
                 np.array([i + 1], np.int32),
                 cfg=cfg,
                 kernel=kernel,
-                interpret=True,
             )
             logits_seq.append(np.asarray(logits)[0])
         outs[kernel] = np.stack(logits_seq)

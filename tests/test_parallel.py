@@ -282,7 +282,7 @@ def test_cli_spawn_sets_engine_shards(tmp_path):
 def test_sharded_window_matches_single_shard():
     """Per-instance tumbling-window aggregation at 8 engine shards equals
     the unsharded result; the temporal buffer state is spread across
-    shards (VERDICT r3 item 6 — the reference centralizes postponed rows
+    shards (the reference centralizes postponed rows
     on one worker, time_column.rs:44-47)."""
     import pathway_tpu as pw
     from pathway_tpu.engine.sharded import ShardedBufferExec

@@ -1,4 +1,4 @@
-"""Native parser depth tests (VERDICT r4 item 8; reference strategies:
+"""Native parser depth tests (reference strategies:
 python/pathway/xpacks/llm/parsers.py:82-775 — chunking modes, table
 extraction, paged parsing, per-page vision parsing)."""
 

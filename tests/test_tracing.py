@@ -386,11 +386,20 @@ def test_rest_request_yields_one_stitched_trace():
         assert echoed is not None and echoed.trace_id == FIXED_TRACE
         assert echoed.span_id != FIXED_SPAN
 
-        names = {
-            r.name
-            for r in tracing.get_tracer().spans()
-            if r.trace_id == FIXED_TRACE
-        }
+        def trace_names():
+            return {
+                r.name
+                for r in tracing.get_tracer().spans()
+                if r.trace_id == FIXED_TRACE
+            }
+
+        # the response leaves from INSIDE the tick; a span is recorded
+        # when it closes, so engine.tick lands a moment after the client
+        # has its answer — wait for it instead of racing it
+        deadline = time.time() + 10
+        while "engine.tick" not in trace_names() and time.time() < deadline:
+            time.sleep(0.05)
+        names = trace_names()
         assert "http.request" in names
         assert "engine.tick" in names
         assert "embed.batch" in names
@@ -421,12 +430,26 @@ def test_rest_request_yields_one_stitched_trace():
         for metric in (
             "pathway_rest_request_seconds",
             "pathway_knn_query_seconds",
-            "pathway_embed_batch_seconds",
         ):
             assert any(
                 e["metric"] == metric and e["trace_id"] == FIXED_TRACE
                 for e in exemplars
             ), (metric, exemplars)
+        # a child keeps its LATEST exemplar, and the answered query's row
+        # is retracted a tick later and re-embedded under that tick's own
+        # trace: the embed exemplar names our trace or, once that tick
+        # has run, the trace of the later embed.batch span
+        embed_traces = {
+            r.trace_id
+            for r in tracing.get_tracer().spans()
+            if r.name == "embed.batch"
+        }
+        assert FIXED_TRACE in embed_traces
+        assert any(
+            e["metric"] == "pathway_embed_batch_seconds"
+            and e["trace_id"] in embed_traces
+            for e in exemplars
+        ), exemplars
 
         # /debug/trace round-trips through the schema validator
         mon = start_http_server(None, port=_free_port())

@@ -1,5 +1,5 @@
 """5M-row streaming wordcount with retractions through the engine
-(VERDICT r4 item 6; reference scale proxy:
+(reference scale proxy:
 integration_tests/wordcount/base.py — 5M-line wordcount CI run)."""
 
 from __future__ import annotations
