@@ -18,6 +18,7 @@ from pathway_tpu.observability.registry import (
     MetricsRegistry,
     sanitize_metric_name,
 )
+from pathway_tpu.observability.tracing import get_tracer
 
 _install_lock = threading.Lock()
 _installed_on: set[int] = set()
@@ -151,6 +152,18 @@ def _install_compile_hooks(registry: MetricsRegistry) -> None:
             if _is_compile(event):
                 compile_count.inc()
                 compile_seconds.inc(max(0.0, float(duration_secs)))
+            if "backend_compile" in event:
+                # Trace Weaver: one record per program built or loaded
+                # from the persistent cache (jax times every nested
+                # jaxpr trace too: those would bury it). The listener
+                # runs on the thread that compiled, so the record lands
+                # under the span open there, and /debug/trace and the
+                # slow-query log say which step compiled.
+                get_tracer().record_finished(
+                    "jax.compile",
+                    int(max(0.0, float(duration_secs)) * 1e9),
+                    event=key,
+                )
         except Exception:
             pass
 

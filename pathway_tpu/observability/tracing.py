@@ -31,7 +31,9 @@ from __future__ import annotations
 import contextvars
 import logging
 import os
+import random
 import re
+import sys
 import threading
 import time
 from collections import deque
@@ -108,17 +110,28 @@ def parse_traceparent(header: Any) -> SpanContext | None:
     return SpanContext(trace_id, span_id, int(flags, 16))
 
 
+# Ids have to be unique, not secret: a generator of the tracer's own
+# (seeded from the OS here and anew in a forked child; a caller's
+# random.seed() does not reach it) instead of a system call per id.
+# os.urandom took 6 us a call on a v5e host, half of what a span cost
+# there (PERF.md section 6, PR 26).
+_ids = random.Random()
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_ids.seed)
+
+
 def _new_trace_id() -> str:
-    return os.urandom(16).hex()
+    return "%032x" % _ids.getrandbits(128)
 
 
 def _new_span_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % _ids.getrandbits(64)
 
 
-@dataclass
+@dataclass(slots=True)
 class SpanRecord:
-    """One finished span in the ring buffer."""
+    """One finished span in the ring buffer (slotted: a full default
+    ring of 65,536 stays in the tens of MB)."""
 
     name: str
     trace_id: str
@@ -128,6 +141,10 @@ class SpanRecord:
     duration_ns: int
     thread: int
     attributes: dict[str, Any] = field(default_factory=dict)
+    # the raw perf_counter_ns read behind start_unix_ns: comparable with
+    # any other perf_counter read of this process (a benchmark harness's
+    # own spans) without going through the wall-clock anchor
+    start_perf_ns: int = 0
 
     def to_dict(self) -> dict:
         return {
@@ -136,6 +153,7 @@ class SpanRecord:
             "span_id": self.span_id,
             "parent_id": self.parent_id,
             "start_unix_ns": self.start_unix_ns,
+            "start_perf_ns": self.start_perf_ns,
             "duration_ns": self.duration_ns,
             "thread": self.thread,
             "attributes": dict(self.attributes),
@@ -169,6 +187,24 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+_profiler_annotation: Any = None  # jax.profiler.TraceAnnotation once jax is loaded
+
+
+def _annotation_class() -> Any:
+    """``jax.profiler.TraceAnnotation``, or None while this process has
+    not imported jax (a span never imports it: ``import pathway_tpu``
+    stays off jax). With it a live span shows on the host plane of a
+    profiler capture (``/debug/profile``) beside the XLA ops it
+    launched; with no capture running it costs one flag check."""
+    global _profiler_annotation
+    if _profiler_annotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _profiler_annotation = TraceAnnotation
+    return _profiler_annotation or None
+
 
 class Span:
     """A live span: context manager that records into the tracer's ring
@@ -186,6 +222,7 @@ class Span:
         "_token",
         "_otel_cm",
         "_otel_span",
+        "_annotation",
     )
 
     def __init__(
@@ -206,6 +243,7 @@ class Span:
         self._token: Any = None
         self._otel_cm: Any = None
         self._otel_span: Any = None
+        self._annotation: Any = None
 
     @property
     def trace_id(self) -> str:
@@ -221,6 +259,12 @@ class Span:
                 pass
 
     def __enter__(self) -> "Span":
+        annotation = _annotation_class()
+        if annotation is not None:
+            # entered before the clock is read and left after it, as the
+            # profiler's event has to enclose the recorded interval
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         self._start_perf = time.perf_counter_ns()
         self.start_unix_ns = _ANCHOR_NS + self._start_perf
         self._token = _current.set(self.context)
@@ -255,7 +299,14 @@ class Span:
                 pass
         _current.reset(self._token)
         self._tracer._record(self, duration_ns)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
+
+
+# A window of some seconds has to fit: a serving tick records about five
+# spans, so 8,192 held under two seconds of a thousand ticks a second.
+DEFAULT_CAPACITY = 65536
 
 
 class Tracer:
@@ -269,10 +320,16 @@ class Tracer:
         self.enabled = bool(enabled)
         if capacity is None:
             try:
-                capacity = int(os.environ.get("PATHWAY_TRACE_BUFFER", "8192"))
+                capacity = int(
+                    os.environ.get("PATHWAY_TRACE_BUFFER", DEFAULT_CAPACITY)
+                )
             except ValueError:
-                capacity = 8192
+                capacity = DEFAULT_CAPACITY
         self._spans: deque[SpanRecord] = deque(maxlen=max(1, capacity))
+        # records overwritten by newer ones since the start or the last
+        # clear(): a reader of a window checks it against the oldest
+        # record before it trusts that the window is whole
+        self.dropped = 0
         self._lock = threading.Lock()
         slow = os.environ.get("PATHWAY_TRACE_SLOW_MS", "")
         try:
@@ -337,6 +394,36 @@ class Tracer:
                 self._otel = None
         return self._otel
 
+    def record_finished(
+        self, name: str, duration_ns: int, **attributes: Any
+    ) -> None:
+        """Record a span that has just ended and whose duration someone
+        else measured (a ``jax.monitoring`` duration event), as a child
+        of whatever span is open on this thread."""
+        if not self.enabled:
+            return
+        parent = _current.get()
+        start_perf = time.perf_counter_ns() - int(duration_ns)
+        self._append(
+            SpanRecord(
+                name=name,
+                trace_id=parent.trace_id if parent else _new_trace_id(),
+                span_id=_new_span_id(),
+                parent_id=parent.span_id if parent else None,
+                start_unix_ns=_ANCHOR_NS + start_perf,
+                duration_ns=int(duration_ns),
+                thread=threading.get_ident(),
+                attributes=attributes,
+                start_perf_ns=start_perf,
+            )
+        )
+
+    def _append(self, rec: SpanRecord) -> None:
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
+            self._spans.append(rec)
+
     def _record(self, span: Span, duration_ns: int) -> None:
         rec = SpanRecord(
             name=span.name,
@@ -347,9 +434,9 @@ class Tracer:
             duration_ns=duration_ns,
             thread=threading.get_ident(),
             attributes=span.attributes,
+            start_perf_ns=span._start_perf,
         )
-        with self._lock:
-            self._spans.append(rec)
+        self._append(rec)
         slow = self.slow_ms
         if (
             slow is not None
@@ -388,6 +475,7 @@ class Tracer:
         """Test hook: drop every recorded span."""
         with self._lock:
             self._spans.clear()
+            self.dropped = 0
 
     def format_tree(
         self, trace_id: str, seconds: float | None = None

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pathway_tpu.observability.tracing import NOOP_SPAN, get_tracer
+
 
 @dataclass(frozen=True)
 class KnnParams:
@@ -32,6 +34,12 @@ class KnnParams:
     bf16: bool = True
 
 
+# The jax.named_scope names below are op metadata only (nothing computed
+# changes): a profiler capture shows the scan, the top-k and the corpus
+# preparation under these names instead of XLA's generated ones.
+
+
+@jax.named_scope("knn.scores")
 def _scores(
     queries: jax.Array, corpus: jax.Array, metric: str, bf16: bool
 ) -> jax.Array:
@@ -59,6 +67,7 @@ def _scores(
     return dots
 
 
+@jax.named_scope("knn.topk")
 def _masked_topk(s: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     """Exact top-k over [B, N] scores. For large N uses the two-stage
     block decomposition (top-k per 1024-column block, then top-k over the
@@ -105,6 +114,7 @@ def dense_topk(
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "bf16"))
+@jax.named_scope("corpus.prepare")
 def prepare_corpus(corpus: jax.Array, metric: str, bf16: bool = True):
     """Returns (prep [N,D], c2 [N]) — prep is normalized (cosine) and cast;
     c2 is the squared-norm column needed by l2sq."""
@@ -128,21 +138,29 @@ def dense_topk_prepared(
     metric: str = "cosine",
     bf16: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    if metric == "cosine":
-        q = queries / (jnp.linalg.norm(queries, axis=-1, keepdims=True) + 1e-30)
-    else:
-        q = queries
-    if bf16:
-        q = q.astype(jnp.bfloat16)
-    dots = jax.lax.dot_general(
-        q, prep, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    if metric == "l2sq":
-        q2 = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
-        s = -(q2 - 2.0 * dots + c2[None, :])
-    else:
-        s = dots
-    s = jnp.where(valid[None, :], s, -jnp.inf)
+    with jax.named_scope("knn.scores"):
+        if metric == "cosine":
+            q = queries / (
+                jnp.linalg.norm(queries, axis=-1, keepdims=True) + 1e-30
+            )
+        else:
+            q = queries
+        if bf16:
+            q = q.astype(jnp.bfloat16)
+        dots = jax.lax.dot_general(
+            q,
+            prep,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if metric == "l2sq":
+            q2 = jnp.sum(
+                queries.astype(jnp.float32) ** 2, axis=-1, keepdims=True
+            )
+            s = -(q2 - 2.0 * dots + c2[None, :])
+        else:
+            s = dots
+        s = jnp.where(valid[None, :], s, -jnp.inf)
     scores, idx = _masked_topk(s, k)
     idx = jnp.where(jnp.isfinite(scores), idx, -1)
     return scores, idx
@@ -209,6 +227,18 @@ def sharded_topk(
     return _sharded_topk_impl(
         queries, corpus, valid, jnp.asarray(base_idx), k, metric, bf16, mesh, axis
     )
+
+
+def _ready_if_live(span: Any, arrays: Any) -> None:
+    """A span around a transfer or a program is truthful only if it ends
+    when the device has the data (``jnp.asarray`` of 2.4 GB returns after
+    0.5 ms, the data arrive after 230), so a live span waits for what it
+    produced. The search that caused the refresh waits for the same arrays
+    before it can answer, but each wait is a round trip of its own: on a
+    v5e the two cost 6 ms of a 254 ms refresh (PERF.md section 6, PR 26).
+    A disabled tracer's shared no-op span syncs nothing."""
+    if span is not NOOP_SPAN:
+        jax.block_until_ready(arrays)
 
 
 class DeviceCorpus:
@@ -289,14 +319,20 @@ class DeviceCorpus:
 
     def device_arrays(self) -> tuple[jax.Array, jax.Array]:
         if self._dirty or self._device is None:
-            if self.sharding is not None:
-                self._device = jax.device_put(self.host, self.sharding)
-                self._device_valid = jax.device_put(
-                    self.valid_host, self.valid_sharding
-                )
-            else:
-                self._device = jnp.asarray(self.host)
-                self._device_valid = jnp.asarray(self.valid_host)
+            with get_tracer().span(
+                "corpus.upload",
+                bytes=self.host.nbytes + self.valid_host.nbytes,
+                rows=len(self),
+            ) as span:
+                if self.sharding is not None:
+                    self._device = jax.device_put(self.host, self.sharding)
+                    self._device_valid = jax.device_put(
+                        self.valid_host, self.valid_sharding
+                    )
+                else:
+                    self._device = jnp.asarray(self.host)
+                    self._device_valid = jnp.asarray(self.valid_host)
+                _ready_if_live(span, (self._device, self._device_valid))
             self._prepared.clear()
             self._dirty = False
         return self._device, self._device_valid
@@ -309,7 +345,11 @@ class DeviceCorpus:
         device, valid = self.device_arrays()
         key = (metric, bf16)
         if key not in self._prepared:
-            self._prepared[key] = prepare_corpus(device, metric, bf16)
+            with get_tracer().span(
+                "corpus.prepare", metric=metric, bf16=bf16, rows=len(self)
+            ) as span:
+                self._prepared[key] = prepare_corpus(device, metric, bf16)
+                _ready_if_live(span, self._prepared[key])
         prep, c2 = self._prepared[key]
         return prep, c2, valid
 

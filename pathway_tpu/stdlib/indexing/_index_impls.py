@@ -10,16 +10,17 @@ Replaces the reference's native index family (src/external_integration/):
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import re
-import time as _time
 from collections import defaultdict
 from typing import Any, Sequence
 
 import numpy as np
 
 from pathway_tpu.engine.index_node import QUERY_DATA_ERRORS
+from pathway_tpu.observability.tracing import get_tracer
 from pathway_tpu.ops.knn import (
     DeviceCorpus,
     dense_topk_prepared,
@@ -184,14 +185,23 @@ class TpuDenseKnnIndex:
             for key, slot in cs["slot_of"].items():
                 c.upsert(key, cs["host"][slot])
 
-    def _device_topk(self, qmat: np.ndarray, eff_k: int):
+    def _device_topk(
+        self, qmat: np.ndarray, eff_k: int
+    ) -> tuple[np.ndarray, np.ndarray]:
         """The device half of a search: (scores, slot indices) of the
-        top ``eff_k`` rows per query.  The kernel is picked from the
-        configuration and the shapes; a kernel the compiler refuses
-        raises — nothing here retries on another implementation."""
+        top ``eff_k`` rows per query, on the host.  The kernel is picked
+        from the configuration and the shapes; a kernel the compiler
+        refuses raises — nothing here retries on another implementation.
+
+        The corpus arrays are fetched first (a changed corpus is uploaded
+        and prepared there, under spans of its own), so the
+        ``index.topk`` span holds the kernel and the transfer of its
+        results and nothing of the refresh."""
         if self.mesh is not None:
             corpus_arr, valid = self.corpus.device_arrays()
-            return sharded_topk(
+            kernel = "sharded"
+            run = functools.partial(
+                sharded_topk,
                 qmat,
                 corpus_arr,
                 valid,
@@ -200,29 +210,54 @@ class TpuDenseKnnIndex:
                 axis=self.axis,
                 metric=self.metric,
             )
-        # float32 rows — but on a TPU a float32 matmul at default
-        # precision multiplies in bf16, in XLA and in the Pallas kernel
-        # alike (measured on a v5e, PR 21: score error 3.5e-4, recall@10
-        # 0.986 vs exact float32 on gaussian rows; "highest" precision
-        # gives 7.5e-8 / 1.0). The ids are the contract; scores carry
-        # about three digits there.
-        prep, c2, valid = self.corpus.prepared_arrays(
-            self.metric, bf16=False
-        )
-        if self.kernel == "pallas" and self.metric in ("cosine", "dot"):
-            from pathway_tpu.ops import pallas_topk as pt
+        else:
+            # float32 rows — but on a TPU a float32 matmul at default
+            # precision multiplies in bf16, in XLA and in the Pallas
+            # kernel alike (measured on a v5e, PR 21: score error 3.5e-4,
+            # recall@10 0.986 vs exact float32 on gaussian rows; "highest"
+            # precision gives 7.5e-8 / 1.0). The ids are the contract;
+            # scores carry about three digits there.
+            prep, c2, valid = self.corpus.prepared_arrays(
+                self.metric, bf16=False
+            )
+            kernel = "xla"
+            run = functools.partial(
+                dense_topk_prepared,
+                qmat,
+                prep,
+                c2,
+                valid,
+                eff_k,
+                metric=self.metric,
+                bf16=False,
+            )
+            if self.kernel == "pallas" and self.metric in ("cosine", "dot"):
+                from pathway_tpu.ops import pallas_topk as pt
 
-            if pt.supported(prep.shape[0], eff_k):
-                return pt.pallas_dense_topk(
-                    qmat, prep, valid, eff_k, metric=self.metric
-                )
-        return dense_topk_prepared(
-            qmat, prep, c2, valid, eff_k, metric=self.metric, bf16=False
-        )
+                if pt.supported(prep.shape[0], eff_k):
+                    kernel = "pallas"
+                    run = functools.partial(
+                        pt.pallas_dense_topk,
+                        qmat,
+                        prep,
+                        valid,
+                        eff_k,
+                        metric=self.metric,
+                    )
+        with get_tracer().span("index.topk", kernel=kernel):
+            scores, idx = run()
+            return np.asarray(scores), np.asarray(idx)
 
     def search(self, queries: Sequence[tuple[Any, int, Any]]):
         if self.corpus is None or len(self.corpus) == 0 or not queries:
             return [() for _ in queries]
+        with get_tracer().span(
+            "index.search", queries=len(queries), rows=len(self.corpus)
+        ) as span:
+            return self._search(queries, span)
+
+    def _search(self, queries: Sequence[tuple[Any, int, Any]], span: Any):
+        """``search`` over a corpus that has rows, inside its span."""
         # host-side validation: everything a malformed query can break is
         # checked here, before any device work (engine/index_node.py
         # QUERY_DATA_ERRORS — recorded, answered empty)
@@ -251,7 +286,8 @@ class TpuDenseKnnIndex:
         eff_k = min(
             len(self.corpus), max_k * 4 if has_filter else max_k
         )
-        _rt0 = _time.perf_counter()
+        span.set_attribute("bucket", bucket)
+        span.set_attribute("k", eff_k)
         try:
             scores, idx = self._device_topk(qmat, eff_k)
         except QUERY_DATA_ERRORS as exc:
@@ -261,28 +297,8 @@ class TpuDenseKnnIndex:
             raise RuntimeError(
                 f"device top-k failed to lower or run: {exc}"
             ) from exc
-        scores = np.asarray(scores, dtype=np.float64)[:n_q]
-        idx = np.asarray(idx)[:n_q]
-        # Tick Scope roofline, family "topk": analytic FLOPs (the score
-        # matmul dominates: 2*B*N*D per call) over measured wall with the
-        # host sync included. Registered analytically because the pallas
-        # kernel's interpret-mode lowering has no XLA cost model.
-        try:
-            from pathway_tpu.observability import tickscope as _ts
-
-            _n, _d = len(self.corpus), qmat.shape[1]
-            _key = f"topk_b{qmat.shape[0]}_n{_n}_d{_d}_k{eff_k}"
-            _rl = _ts.roofline()
-            if not _rl.known("topk", _key):
-                _rl.register(
-                    "topk",
-                    _key,
-                    2.0 * qmat.shape[0] * _n * _d,
-                    source="analytic",
-                )
-            _rl.observe("topk", _key, _time.perf_counter() - _rt0)
-        except Exception:  # pragma: no cover - defensive
-            pass
+        scores = scores[:n_q].astype(np.float64)
+        idx = idx[:n_q]
         if self.metric == "cosine":
             # reference USearch COS scores are -(1 - cos): negative
             # distances, not raw similarities

@@ -131,9 +131,8 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         )
         self.model = model
         self.kwargs = call_kwargs
-        # Flight Recorder: embed batch latency + the BASELINE.md
-        # docs/sec/chip figure, measured where the work happens instead of
-        # reconstructed by bench.py from the outside
+        # Flight Recorder: embed batch latency and documents embedded,
+        # measured where the work happens
         from pathway_tpu.observability import REGISTRY
 
         m_batch_seconds = REGISTRY.histogram(
@@ -146,15 +145,8 @@ class SentenceTransformerEmbedder(BaseEmbedder):
             "documents embedded",
             labelnames=("model",),
         ).labels(model)
-        m_rate = REGISTRY.gauge(
-            "pathway_embed_docs_per_sec_per_chip",
-            "throughput of the most recent embed batch, per local device",
-            labelnames=("model",),
-        ).labels(model)
-        chips: list[int] = []  # resolved after the first forward
         from pathway_tpu.observability.tracing import get_tracer
         from pathway_tpu.serving.metrics import occupancy_histogram
-        from pathway_tpu.xpacks.llm._encoder import _bucket_batch
 
         m_occupancy = occupancy_histogram()
         _tracer = get_tracer()
@@ -163,34 +155,43 @@ class SentenceTransformerEmbedder(BaseEmbedder):
             import time as _time
 
             # Trace Weaver: one child span per device batch (nested under
-            # the operator span of the tick that carried these rows)
-            with _tracer.span(
-                "embed.batch", model=model, docs=len(texts)
-            ) as sp:
+            # the operator span of the tick that carried these rows), split
+            # into its host half and its device half. The counts ride on
+            # the spans, so the useful share of the padded tokens can be
+            # cut to any part of a window.
+            docs = len(texts)
+            with _tracer.span("embed.batch", model=model, docs=docs) as sp:
                 t0 = _time.perf_counter()
-                ids, mask = self.tokenizer.encode_batch(
-                    # runtime.max_len is clamped to the checkpoint's
-                    # position table; exceeding it would silently clamp
-                    # position ids
-                    [str(t) for t in texts], self.runtime.max_len
-                )
-                out = self.runtime.forward_ids(ids, mask)
+                with _tracer.span("embed.tokenize", docs=docs) as tok:
+                    ids, mask = self.tokenizer.encode_batch(
+                        # runtime.max_len is clamped to the checkpoint's
+                        # position table; exceeding it would silently clamp
+                        # position ids
+                        [str(t) for t in texts], self.runtime.max_len
+                    )
+                    tokens_real = int(mask.sum())
+                    len_bucket = int(ids.shape[1])
+                    tok.set_attribute("tokens_real", tokens_real)
+                    tok.set_attribute("len_bucket", len_bucket)
+                pad_bucket = self.runtime.batch_bucket(docs)
+                with _tracer.span(
+                    "embed.forward",
+                    batch_bucket=pad_bucket,
+                    len_bucket=len_bucket,
+                    tokens_real=tokens_real,
+                    # what the program really forwards
+                    tokens_padded=pad_bucket * len_bucket,
+                ):
+                    out = self.runtime.forward_ids(ids, mask)
                 dt = _time.perf_counter() - t0
             m_batch_seconds.observe(dt, exemplar=sp.trace_id)
-            m_docs.inc(len(texts))
+            m_docs.inc(docs)
             # Surge Gate ladder visibility: how well realized batches
             # fill the encoder's pad bucket (the shape XLA compiled for)
-            pad_bucket = _bucket_batch(len(texts))
             m_occupancy.labels("embed", str(pad_bucket)).observe(
-                min(1.0, len(texts) / pad_bucket)
+                min(1.0, docs / pad_bucket)
             )
-            if not chips:
-                import jax
-
-                chips.append(max(1, jax.local_device_count()))
-            if dt > 0:
-                m_rate.set(len(texts) / dt / chips[0])
-            return [out[i] for i in range(len(texts))]
+            return [out[i] for i in range(docs)]
 
         self._embed_batch = embed_batch
         super().__init__(

@@ -575,3 +575,316 @@ def test_two_process_run_shares_one_trace_id(tmp_path):
             if line.startswith("DCN_SPANS ")
         )
         assert "dcn.exchange" in dcn, out
+
+
+# --- spans inside the two device layers -------------------------------------
+# (encoder: embed.batch > embed.tokenize, embed.forward; index:
+# index.search > corpus.upload, corpus.prepare, index.topk; all on the
+# process's perf_counter clock and on the host plane of a profiler capture)
+
+
+def _children(records, parent):
+    return [r for r in records if r.parent_id == parent.span_id]
+
+
+def _end(record):
+    return record.start_perf_ns + record.duration_ns
+
+
+def _layer_spans():
+    """What the ring holds, without the compile records: an earlier test of
+    the process may have installed the jax.monitoring listener."""
+    return [r for r in tracing.get_tracer().spans() if r.name != "jax.compile"]
+
+
+def _one(records, name):
+    found = [r for r in records if r.name == name]
+    assert len(found) == 1, (name, [r.name for r in records])
+    return found[0]
+
+
+def _toy_embedder():
+    from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
+
+    return SentenceTransformerEmbedder(dim=16, depth=1, heads=2, max_len=64)
+
+
+def _toy_index(rows=5, dim=8):
+    import numpy as np
+
+    from pathway_tpu.stdlib.indexing._index_impls import TpuDenseKnnIndex
+
+    index = TpuDenseKnnIndex(dim, "cosine", reserved_space=1024)
+    vectors = np.random.default_rng(7).normal(size=(rows, dim)).astype("float32")
+    for key, vector in enumerate(vectors):
+        index.upsert(key, vector, None)
+    return index, vectors
+
+
+def test_span_record_is_slotted_and_carries_the_perf_clock():
+    tracer = tracing.Tracer(capacity=4)
+    before = time.perf_counter_ns()
+    with tracer.span("timed"):
+        pass
+    after = time.perf_counter_ns()
+    (rec,) = tracer.spans()
+    assert before <= rec.start_perf_ns <= after
+    assert rec.start_perf_ns + rec.duration_ns <= after
+    # the anchored epoch and the raw read are one read, one anchor apart
+    assert rec.start_unix_ns - rec.start_perf_ns == tracing._ANCHOR_NS
+    assert rec.to_dict()["start_perf_ns"] == rec.start_perf_ns
+    assert not hasattr(rec, "__dict__")  # __slots__: a full ring stays small
+
+
+def test_default_ring_holds_a_window_and_counts_what_it_drops(monkeypatch):
+    monkeypatch.delenv("PATHWAY_TRACE_BUFFER", raising=False)
+    assert tracing.Tracer()._spans.maxlen == 65536
+    monkeypatch.setenv("PATHWAY_TRACE_BUFFER", "3")
+    tracer = tracing.Tracer()
+    for i in range(3):
+        with tracer.span(f"s{i}"):
+            pass
+    assert tracer.dropped == 0
+    for i in range(3, 8):
+        with tracer.span(f"s{i}"):
+            pass
+    assert tracer.dropped == 5 and [r.name for r in tracer.spans()] == ["s5", "s6", "s7"]
+    tracer.clear()
+    assert tracer.dropped == 0 and tracer.spans() == []
+
+
+def test_record_finished_lands_under_the_open_span():
+    tracer = tracing.Tracer(capacity=8)
+    with tracer.span("step") as step:
+        before = time.perf_counter_ns()
+        tracer.record_finished("measured.elsewhere", 2_000_000, what="x")
+    rec = _one(tracer.spans(), "measured.elsewhere")
+    assert rec.parent_id == step.context.span_id and rec.trace_id == step.trace_id
+    assert rec.duration_ns == 2_000_000 and rec.attributes == {"what": "x"}
+    # it ended when it was recorded
+    assert abs(rec.start_perf_ns + rec.duration_ns - before) < 50_000_000
+    tracer.record_finished("alone", 1)  # no span open: a root of its own
+    assert _one(tracer.spans(), "alone").parent_id is None
+    off = tracing.Tracer(capacity=8, enabled=False)
+    off.record_finished("never", 1)
+    assert off.spans() == []
+
+
+def test_embed_batch_splits_into_tokenize_and_forward():
+    embedder = _toy_embedder()
+    texts = ["one two three", "four", "five six seven eight nine ten eleven"]
+    embedder._embed_batch(texts)  # compile
+    tracing.get_tracer().clear()
+    vectors = embedder._embed_batch(texts)
+    assert len(vectors) == 3
+    records = _layer_spans()
+    batch = _one(records, "embed.batch")
+    tokenize, forward = _one(records, "embed.tokenize"), _one(records, "embed.forward")
+    assert {r.name for r in _children(records, batch)} == {"embed.tokenize", "embed.forward"}
+    assert batch.attributes["docs"] == 3 and tokenize.attributes["docs"] == 3
+    # one CLS and one id a word: 4 + 2 + 8 real tokens, in a 16-wide length
+    # bucket; 3 texts pad to the 8-row batch bucket, which is what is forwarded
+    assert tokenize.attributes == {"docs": 3, "tokens_real": 14, "len_bucket": 16}
+    assert forward.attributes == {
+        "batch_bucket": 8, "len_bucket": 16, "tokens_real": 14, "tokens_padded": 128,
+    }
+    assert forward.attributes["batch_bucket"] == embedder.runtime.batch_bucket(3)
+    assert forward.attributes["tokens_real"] <= forward.attributes["tokens_padded"]
+    # the parts lie inside the whole, in order, on one clock
+    assert batch.start_perf_ns <= tokenize.start_perf_ns
+    assert _end(tokenize) <= forward.start_perf_ns and _end(forward) <= _end(batch)
+    assert tokenize.duration_ns + forward.duration_ns <= batch.duration_ns
+
+
+def test_index_search_names_the_refresh_only_when_the_corpus_changed():
+    index, vectors = _toy_index()
+    tracer = tracing.get_tracer()
+    first = index.search([(vectors[0], 2, None), (vectors[1], 2, None), (vectors[2], 2, None)])
+    records = _layer_spans()
+    search = _one(records, "index.search")
+    assert [r.name for r in sorted(_children(records, search), key=lambda r: r.start_perf_ns)] == [
+        "corpus.upload", "corpus.prepare", "index.topk",
+    ]
+    assert search.attributes == {"queries": 3, "rows": 5, "bucket": 4, "k": 2}
+    upload, prepare = _one(records, "corpus.upload"), _one(records, "corpus.prepare")
+    assert upload.attributes == {"bytes": 1024 * 8 * 4 + 1024, "rows": 5}
+    assert prepare.attributes == {"metric": "cosine", "bf16": False, "rows": 5}
+    assert _one(records, "index.topk").attributes == {"kernel": "xla"}
+    # the four parts add up: self time is what the children leave
+    parts = sum(r.duration_ns for r in _children(records, search))
+    assert 0 < parts <= search.duration_ns
+
+    tracer.clear()
+    assert index.search([(vectors[0], 2, None)] * 3) == [first[0]] * 3
+    records = _layer_spans()  # an unchanged corpus: no refresh, no span for one
+    assert sorted(r.name for r in records) == ["index.search", "index.topk"]
+    assert _one(records, "index.topk").parent_id == _one(records, "index.search").span_id
+
+    tracer.clear()
+    index.upsert(99, vectors[0], None)  # a change: the next search pays, and says so
+    index.search([(vectors[0], 2, None)])
+    assert {r.name for r in _layer_spans()} == {
+        "index.search", "corpus.upload", "corpus.prepare", "index.topk",
+    }
+    tracer.clear()
+    empty = type(index)(8, "cosine")
+    assert empty.search([(vectors[0], 1, None)]) == [()]  # no corpus: no span
+    assert tracer.spans() == []
+
+
+def test_disabled_tracer_same_answers_no_record_and_no_sync(monkeypatch):
+    import jax
+
+    index, vectors = _toy_index()
+    embedder = _toy_embedder()
+    texts = ["alpha beta", "gamma"]
+    tracer = tracing.get_tracer()
+    want_hits = index.search([(vectors[3], 3, None)])
+    want_vectors = embedder._embed_batch(texts)
+    index.upsert(50, vectors[1], None)  # dirty: the next search refreshes
+
+    syncs = []
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: syncs.append(1) or x)
+    tracer.enabled = False
+    tracer.clear()
+    hits = index.search([(vectors[3], 3, None)])
+    got_vectors = embedder._embed_batch(texts)
+    assert tracer.spans() == [] and syncs == []
+    assert hits == want_hits
+    assert all((a == b).all() for a, b in zip(got_vectors, want_vectors))
+
+    tracer.enabled = True
+    index.upsert(51, vectors[2], None)
+    index.search([(vectors[3], 3, None)])
+    assert len(syncs) == 2  # the upload and the prepared copy, on the refresh path only
+    index.search([(vectors[3], 3, None)])
+    assert len(syncs) == 2
+
+
+def test_index_search_is_a_child_of_knn_search_in_the_engine():
+    import numpy as np
+
+    from pathway_tpu.stdlib.indexing import DataIndex, TpuKnn
+
+    schema = pw.schema_from_types(name=str, vec=np.ndarray)
+    rng = np.random.default_rng(0)
+    docs = pw.debug.table_from_rows(
+        schema, [(f"d{i}", rng.normal(size=4).astype(np.float32)) for i in range(8)]
+    )
+    queries = pw.debug.table_from_rows(schema, [("q", rng.normal(size=4).astype(np.float32))])
+    index = DataIndex(docs, TpuKnn(docs.vec, dimensions=4))
+    res = index.query_as_of_now(queries.vec, number_of_matches=2).select(names=pw.right.name)
+    _keys, cols = pw.debug.table_to_dicts(res)
+    assert len(list(cols["names"].values())[0]) == 2
+    records = tracing.get_tracer().spans()
+    by_id = {r.span_id: r for r in records}
+    searches = [r for r in records if r.name == "index.search"]
+    assert searches
+    for search in searches:
+        assert by_id[search.parent_id].name == "knn.search"
+        assert "index.topk" in {r.name for r in _children(records, search)}
+
+
+def test_a_span_shows_on_the_host_plane_of_a_profiler_capture(tmp_path):
+    import glob
+
+    import jax
+
+    index, vectors = _toy_index()
+    index.search([(vectors[0], 1, None)])  # compiled before the capture
+    index.upsert(7, vectors[1], None)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        index.search([(vectors[0], 1, None)])
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    host_events = {
+        e.name
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+    assert {"index.search", "corpus.upload", "corpus.prepare", "index.topk"} <= host_events
+
+
+def test_a_compile_is_recorded_under_the_span_that_forwarded_a_new_shape():
+    from pathway_tpu.observability import install_jax_metrics
+    from pathway_tpu.observability.registry import MetricsRegistry
+
+    install_jax_metrics(MetricsRegistry())
+    embedder = _toy_embedder()
+    tracer = tracing.get_tracer()
+    embedder._embed_batch(["a b c"])  # the 8 x 16 program
+    tracer.clear()
+    embedder._embed_batch(["a b c"])
+    assert not [r for r in tracer.spans() if r.name == "jax.compile"]
+    embedder._embed_batch([" ".join(["w"] * 20)])  # 21 tokens: the 8 x 32 program is new
+    records = tracer.spans()
+    compiles = [r for r in records if r.name == "jax.compile"]
+    assert compiles and all("backend_compile" in r.attributes["event"] for r in compiles)
+    forwards = {r.span_id: r for r in records if r.name == "embed.forward"}
+    parent = forwards[compiles[0].parent_id]
+    assert parent.attributes["len_bucket"] == 32
+    assert compiles[0].duration_ns <= parent.duration_ns
+    assert "jax.compile" in tracer.format_tree(parent.trace_id)
+
+
+def test_device_programs_carry_the_scope_names():
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import knn
+
+    q, c = jnp.ones((2, 8)), jnp.ones((1024, 8))
+    valid = jnp.ones((1024,), bool)
+    prepared = knn.prepare_corpus.lower(c, "cosine", False).as_text(debug_info=True)
+    assert "corpus.prepare" in prepared
+    topk = knn.dense_topk_prepared.lower(
+        q, c, jnp.ones((1024,)), valid, 2, "cosine", False
+    ).as_text(debug_info=True)
+    assert "knn.scores" in topk and "knn.topk" in topk
+    plain = knn.dense_topk.lower(q, c, valid, 2).as_text(debug_info=True)
+    assert "knn.scores" in plain and "knn.topk" in plain
+    runtime = _toy_embedder().runtime
+    ids, mask = jnp.zeros((8, 16), jnp.int32), jnp.ones((8, 16))
+    forward = jax.jit(runtime._fwd).lower(runtime.params, ids, mask).as_text(debug_info=True)
+    assert "encoder.forward" in forward
+
+
+def test_ids_are_the_tracers_own_draws():
+    import random
+    import subprocess
+    import sys
+
+    tracer = tracing.Tracer(capacity=8)
+    random.seed(1234)  # a caller that seeds the shared generator ...
+    with tracer.span("a") as a:
+        pass
+    random.seed(1234)  # ... twice: the ids do not repeat
+    with tracer.span("b") as b:
+        pass
+    assert a.context.span_id != b.context.span_id and a.trace_id != b.trace_id
+    assert tracing.parse_traceparent(a.context.traceparent()) == a.context
+    # a forked child draws from a generator seeded anew (a fresh process,
+    # so that nothing forks under the test runner's threads)
+    script = textwrap.dedent(
+        """
+        import os
+        from pathway_tpu.observability import tracing
+        read_end, write_end = os.pipe()
+        pid = os.fork()
+        if pid == 0:
+            os.write(write_end, tracing._new_span_id().encode())
+            os._exit(0)
+        os.waitpid(pid, 0)
+        assert os.read(read_end, 16).decode() != tracing._new_span_id()
+        print("distinct")
+        """
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True, timeout=120
+    )
+    assert done.returncode == 0 and "distinct" in done.stdout, done.stderr
