@@ -90,7 +90,7 @@ def _bench_knn(np, on_accel, errors, force_1m=False):
         corpus.slot_of[i] = i
         corpus.key_of[i] = i
     corpus.free = list(range(corpus.capacity - 1, n - 1, -1))
-    corpus._dirty = True
+    corpus.mirror_replaced()
 
     prep, c2, valid = corpus.prepared_arrays("cosine")
     queries = rng.normal(size=(n_queries, 1, dim)).astype(np.float32)
@@ -2239,11 +2239,11 @@ def _bench_serve_chaos(np):
                 "replicas never became ready: %r" % (hs,)
             )
 
-        # Corpus churn cadence: ONE doc per second.  Every upsert
-        # invalidates the replica's prepared device corpus (DeviceCorpus
-        # re-preps on the next search), so the churn rate sets how often
-        # queries pay that re-prep — 1/s amortizes it across the whole
-        # second of queries, the realistic live-index regime.  The tick
+        # Corpus churn cadence: ONE doc per second.  Every upsert makes
+        # the next search refresh the replica's device corpus
+        # (DeviceCorpus scatters the changed rows), so the churn rate sets
+        # how often queries pay that refresh — 1/s amortizes it across the
+        # whole second of queries, the realistic live-index regime.  The tick
         # cadence doubles as the deterministic clock for the Fault-Forge
         # replica kill (each trickled doc = one applied delta tick).
         trickle_i = [0]

@@ -7,6 +7,10 @@ Design:
 - corpus lives in HBM as a padded [capacity, D] array (+ validity mask) so
   shapes stay static across ticks — no recompilation as documents stream in;
   capacity grows by doubling (each size compiles once).
+- a change reaches the device as a scatter of the changed rows into the
+  resident arrays (raw rows, validity, every prepared copy), donated to one
+  jitted program of one shape; the whole mirror is uploaded only where
+  there is no device copy to patch, or an eighth of it or more changed.
 - scores = queries @ corpus.T runs in bfloat16 on the MXU with f32
   accumulation; invalid slots are masked to -inf before `lax.top_k`.
 - multi-chip: corpus rows are sharded over the mesh's 'data' axis via
@@ -233,21 +237,77 @@ def _ready_if_live(span: Any, arrays: Any) -> None:
     """A span around a transfer or a program is truthful only if it ends
     when the device has the data (``jnp.asarray`` of 2.4 GB returns after
     0.5 ms, the data arrive after 230), so a live span waits for what it
-    produced. The search that caused the refresh waits for the same arrays
-    before it can answer, but each wait is a round trip of its own: on a
-    v5e the two cost 6 ms of a 254 ms refresh (PERF.md section 6, PR 26).
-    A disabled tracer's shared no-op span syncs nothing."""
+    produced. Each wait is a round trip of its own, some 3 ms on a v5e
+    (PERF.md section 6, PR 26), so a refresh has one: the whole upload
+    waits for the mirror's copy, the whole ``prepare_corpus`` for a new
+    prepared copy, and a scatter refresh once, for the arrays its program
+    wrote (its ``corpus.upload`` span ends at the hand-over of the changed
+    rows). A disabled tracer's shared no-op span syncs nothing."""
     if span is not NOOP_SPAN:
         jax.block_until_ready(arrays)
+
+
+# Changed rows go to the device in chunks of this many, whatever their
+# number, so one scatter program serves a one-row change and a 256-row tick
+# alike (3.1 MB a chunk at dim 768). The last chunk is padded with a slot
+# past the capacity, which the scatter drops.
+SCATTER_ROWS = 1024
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("sharding", "valid_sharding"),
+    donate_argnames=("device", "valid", "prepared"),
+)
+def _scatter_rows(
+    device: jax.Array,  # [N, D] f32, donated
+    valid: jax.Array,  # [N] bool, donated
+    prepared: dict,  # (metric, bf16) -> (prep [N, D], c2 [N]), donated
+    slots: jax.Array,  # [SCATTER_ROWS] i32; >= N is dropped
+    rows: jax.Array,  # [SCATTER_ROWS, D] f32
+    row_valid: jax.Array,  # [SCATTER_ROWS] bool
+    sharding: Any = None,
+    valid_sharding: Any = None,
+):
+    """Writes the changed rows into the resident arrays: the raw row, its
+    validity bit and, for every prepared copy, what ``prepare_corpus``
+    makes of that row (it is row-wise). The outputs keep the corpus's
+    sharding where it has one."""
+
+    def put(array, updates, pin):
+        # no indices_are_sorted / unique_indices, true as they would be:
+        # with them XLA's TPU scatter passes over the whole operand, 16 ms
+        # at 786,432 x 768 against 1.2 without (PERF.md section 6, PR 27)
+        out = array.at[slots].set(updates, mode="drop")
+        if pin is not None:
+            out = jax.lax.with_sharding_constraint(out, pin)
+        return out
+
+    fresh = {}
+    for (metric, bf16), (prep, c2) in prepared.items():
+        prep_rows, c2_rows = prepare_corpus(rows, metric, bf16)
+        fresh[metric, bf16] = (
+            put(prep, prep_rows, sharding),
+            put(c2, c2_rows, valid_sharding),
+        )
+    return put(device, rows, sharding), put(valid, row_valid, valid_sharding), fresh
 
 
 class DeviceCorpus:
     """Growable padded corpus living on device.
 
-    Host keeps a float32 mirror; the device array is refreshed lazily per
-    tick (one host→device transfer per changed tick, amortized over all
-    queries in that tick). Capacity doubles ⇒ O(log N) distinct compiled
-    shapes."""
+    Host keeps a float32 mirror, which is the truth. The device holds a
+    copy of it, a validity mask and the prepared copies searches asked
+    for. ``upsert``/``remove`` write the mirror and note the slot; the
+    next ``device_arrays``/``prepared_arrays`` call brings the device up
+    to date by handing over the changed rows alone and scattering them
+    into the arrays it holds, which are donated to that program: whoever
+    took the arrays before a change must not use them after the refresh.
+    The whole mirror is uploaded only where there is no device copy to
+    patch (the first use, a new capacity, ``mirror_replaced``) or where
+    an eighth of it or more changed. Capacity doubles ⇒ O(log N) distinct
+    compiled shapes, and the scatter has one shape per capacity and set of
+    prepared copies."""
 
     def __init__(
         self,
@@ -257,6 +317,7 @@ class DeviceCorpus:
         valid_sharding: Any = None,
     ):
         self.valid_sharding = valid_sharding
+        self._replicated = None
         self.dim = dim
         # align capacity to lcm(1024, n_shards): multiple of 1024 so the
         # Pallas block kernel (ops/pallas_topk.py, BLK=1024) is always
@@ -266,8 +327,12 @@ class DeviceCorpus:
         if sharding is not None:
             import math
 
+            from jax.sharding import NamedSharding, PartitionSpec
+
             n_dev = int(np.prod(list(sharding.mesh.shape.values())))
             align = math.lcm(1024, max(1, n_dev))
+            # where a chunk of changed rows goes: whole, to every shard
+            self._replicated = NamedSharding(sharding.mesh, PartitionSpec())
         self._align = align
         self.capacity = -(-max(1024, capacity) // align) * align
         self.host = np.zeros((self.capacity, dim), dtype=np.float32)
@@ -275,10 +340,12 @@ class DeviceCorpus:
         self.free: list[int] = list(range(self.capacity - 1, -1, -1))
         self.slot_of: dict[int, int] = {}  # row key -> slot
         self.key_of: dict[int, int] = {}  # slot -> row key
-        self._dirty = True
+        # no device copy yet (None) means the next refresh uploads the
+        # whole mirror; with one, `_changed` holds the slots it lacks
         self._device: jax.Array | None = None
         self._device_valid: jax.Array | None = None
         self._prepared: dict[tuple[str, bool], tuple[jax.Array, jax.Array]] = {}
+        self._changed: set[int] = set()
         self.sharding = sharding
 
     def __len__(self) -> int:
@@ -294,7 +361,8 @@ class DeviceCorpus:
             self.key_of[slot] = key
         self.host[slot] = vector
         self.valid_host[slot] = True
-        self._dirty = True
+        if self._device is not None:
+            self._changed.add(slot)
 
     def remove(self, key: int) -> None:
         slot = self.slot_of.pop(key, None)
@@ -303,7 +371,8 @@ class DeviceCorpus:
         self.key_of.pop(slot, None)
         self.valid_host[slot] = False
         self.free.append(slot)
-        self._dirty = True
+        if self._device is not None:
+            self._changed.add(slot)
 
     def _grow(self) -> None:
         old_cap = self.capacity
@@ -315,33 +384,112 @@ class DeviceCorpus:
         valid[:old_cap] = self.valid_host
         self.valid_host = valid
         self.free.extend(range(self.capacity - 1, old_cap - 1, -1))
-        self._dirty = True
+        self.mirror_replaced()
+
+    def mirror_replaced(self) -> None:
+        """The host mirror is another array than the device copy was made
+        from (a new capacity, a restored state, rows written past
+        ``upsert``): the device copies are dropped and the next refresh
+        uploads the whole mirror."""
+        self._device = self._device_valid = None
+        self._prepared.clear()
+        self._changed.clear()
+
+    def _refresh(self) -> None:
+        """Brings the device copies up to date with the mirror."""
+        if self._device is not None and not self._changed:
+            return
+        # a row costs a scatter some 1.7 us (a 1,024-row chunk: gather,
+        # three transfers, one program) and the whole upload 0.15-0.3 us at
+        # 10 GB/s (v5e, dims 384-768; PERF.md section 6, PR 27)
+        if self._device is None or 8 * len(self._changed) >= self.capacity:
+            self._upload_all()
+            return
+        try:
+            self._scatter_changed()
+        except BaseException:
+            # the donated arrays may be gone: upload again next time
+            self.mirror_replaced()
+            raise
+
+    def _upload_all(self) -> None:
+        changed = len(self._changed)
+        self.mirror_replaced()  # the old copies go first: never two on the device
+        with get_tracer().span(
+            "corpus.upload",
+            bytes=self.host.nbytes + self.valid_host.nbytes,
+            rows=len(self),
+            changed_rows=changed,
+            full=1,
+        ) as span:
+            if self.sharding is not None:
+                self._device = jax.device_put(self.host, self.sharding)
+                self._device_valid = jax.device_put(
+                    self.valid_host, self.valid_sharding
+                )
+            else:
+                self._device = jnp.asarray(self.host)
+                self._device_valid = jnp.asarray(self.valid_host)
+            _ready_if_live(span, (self._device, self._device_valid))
+
+    def _hand_over(self, slots: np.ndarray) -> tuple[jax.Array, ...]:
+        """One chunk on its way to the device: at most ``SCATTER_ROWS``
+        slots, their rows and validity bits from the mirror, padded with a
+        slot past the end. (A buffer a chunk: gathering 100 MB into one
+        fresh array ran at 0.4 GB/s on the v5e's host, page by page.)"""
+        n = len(slots)
+        padded = np.full(SCATTER_ROWS, self.capacity, np.int32)
+        padded[:n] = slots
+        rows = np.zeros((SCATTER_ROWS, self.dim), np.float32)
+        rows[:n] = self.host[slots]
+        row_valid = np.zeros(SCATTER_ROWS, bool)
+        row_valid[:n] = self.valid_host[slots]
+        return jax.device_put((padded, rows, row_valid), self._replicated)
+
+    def _scatter_changed(self) -> None:
+        slots = np.fromiter(self._changed, np.int32, len(self._changed))
+        self._changed.clear()
+        tracer = get_tracer()
+        with tracer.span(
+            "corpus.upload", rows=len(self), changed_rows=len(slots), full=0
+        ) as span:
+            chunks = [
+                self._hand_over(slots[i : i + SCATTER_ROWS])
+                for i in range(0, len(slots), SCATTER_ROWS)
+            ]
+            span.set_attribute(
+                "bytes", sum(a.nbytes for chunk in chunks for a in chunk)
+            )
+        metrics = sorted({metric for metric, _bf16 in self._prepared})
+        with tracer.span(
+            "corpus.prepare",
+            metric=",".join(metrics),
+            bf16=any(bf16 for _metric, bf16 in self._prepared),
+            rows=len(self),
+        ) as span:
+            for chunk in chunks:
+                self._device, self._device_valid, self._prepared = _scatter_rows(
+                    self._device,
+                    self._device_valid,
+                    self._prepared,
+                    *chunk,
+                    sharding=self.sharding,
+                    valid_sharding=self.valid_sharding,
+                )
+            _ready_if_live(
+                span, (self._device, self._device_valid, self._prepared)
+            )
 
     def device_arrays(self) -> tuple[jax.Array, jax.Array]:
-        if self._dirty or self._device is None:
-            with get_tracer().span(
-                "corpus.upload",
-                bytes=self.host.nbytes + self.valid_host.nbytes,
-                rows=len(self),
-            ) as span:
-                if self.sharding is not None:
-                    self._device = jax.device_put(self.host, self.sharding)
-                    self._device_valid = jax.device_put(
-                        self.valid_host, self.valid_sharding
-                    )
-                else:
-                    self._device = jnp.asarray(self.host)
-                    self._device_valid = jnp.asarray(self.valid_host)
-                _ready_if_live(span, (self._device, self._device_valid))
-            self._prepared.clear()
-            self._dirty = False
+        self._refresh()
         return self._device, self._device_valid
 
     def prepared_arrays(
         self, metric: str, bf16: bool = True
     ) -> tuple[jax.Array, jax.Array, jax.Array]:
         """(prep, c2, valid) with normalization/cast amortized across
-        queries — refreshed only when the corpus changed."""
+        queries: made from the whole corpus the first time a (metric,
+        bf16) is asked for, kept in step row by row afterwards."""
         device, valid = self.device_arrays()
         key = (metric, bf16)
         if key not in self._prepared:

@@ -148,7 +148,7 @@ class TpuDenseKnnIndex:
         return int(c.host.nbytes + c.valid_host.nbytes)
 
     # --- operator-snapshot support (reference: operator_snapshot.rs) ------
-    # host-side content only; device arrays are re-uploaded lazily
+    # host-side content only; a restored mirror is uploaded whole, lazily
 
     def state_dict(self) -> dict:
         c = self.corpus
@@ -180,7 +180,7 @@ class TpuDenseKnnIndex:
             c.free = list(cs["free"])
             c.slot_of = dict(cs["slot_of"])
             c.key_of = dict(cs["key_of"])
-            c._dirty = True
+            c.mirror_replaced()
         else:  # capacity alignment changed between versions: re-upsert
             for key, slot in cs["slot_of"].items():
                 c.upsert(key, cs["host"][slot])
@@ -193,10 +193,11 @@ class TpuDenseKnnIndex:
         from the configuration and the shapes; a kernel the compiler
         refuses raises — nothing here retries on another implementation.
 
-        The corpus arrays are fetched first (a changed corpus is uploaded
-        and prepared there, under spans of its own), so the
+        The corpus arrays are fetched first (changed rows are handed over
+        and scattered into them there, under spans of its own), so the
         ``index.topk`` span holds the kernel and the transfer of its
-        results and nothing of the refresh."""
+        results and nothing of the refresh. The arrays are held for this
+        call only: the corpus donates them to its next refresh."""
         if self.mesh is not None:
             corpus_arr, valid = self.corpus.device_arrays()
             kernel = "sharded"

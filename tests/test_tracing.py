@@ -707,7 +707,10 @@ def test_index_search_names_the_refresh_only_when_the_corpus_changed():
     ]
     assert search.attributes == {"queries": 3, "rows": 5, "bucket": 4, "k": 2}
     upload, prepare = _one(records, "corpus.upload"), _one(records, "corpus.prepare")
-    assert upload.attributes == {"bytes": 1024 * 8 * 4 + 1024, "rows": 5}
+    # no device copy yet: the whole mirror, and nothing was noted as changed
+    assert upload.attributes == {
+        "bytes": 1024 * 8 * 4 + 1024, "rows": 5, "changed_rows": 0, "full": 1,
+    }
     assert prepare.attributes == {"metric": "cosine", "bf16": False, "rows": 5}
     assert _one(records, "index.topk").attributes == {"kernel": "xla"}
     # the four parts add up: self time is what the children leave
@@ -723,9 +726,19 @@ def test_index_search_names_the_refresh_only_when_the_corpus_changed():
     tracer.clear()
     index.upsert(99, vectors[0], None)  # a change: the next search pays, and says so
     index.search([(vectors[0], 2, None)])
-    assert {r.name for r in _layer_spans()} == {
-        "index.search", "corpus.upload", "corpus.prepare", "index.topk",
+    records = _layer_spans()
+    search = _one(records, "index.search")
+    assert [r.name for r in sorted(_children(records, search), key=lambda r: r.start_perf_ns)] == [
+        "corpus.upload", "corpus.prepare", "index.topk",
+    ]
+    # one changed row, handed over in the one chunk every change is cut
+    # into (its slots, rows and validity bits) and scattered, not uploaded
+    upload, prepare = _one(records, "corpus.upload"), _one(records, "corpus.prepare")
+    assert upload.attributes == {
+        "bytes": 1024 * (4 + 8 * 4 + 1), "rows": 6, "changed_rows": 1, "full": 0,
     }
+    assert prepare.attributes == {"metric": "cosine", "bf16": False, "rows": 6}
+    assert _end(upload) <= prepare.start_perf_ns and _end(prepare) <= _end(search)
     tracer.clear()
     empty = type(index)(8, "cosine")
     assert empty.search([(vectors[0], 1, None)]) == [()]  # no corpus: no span
@@ -741,7 +754,7 @@ def test_disabled_tracer_same_answers_no_record_and_no_sync(monkeypatch):
     tracer = tracing.get_tracer()
     want_hits = index.search([(vectors[3], 3, None)])
     want_vectors = embedder._embed_batch(texts)
-    index.upsert(50, vectors[1], None)  # dirty: the next search refreshes
+    index.upsert(50, vectors[1], None)  # a changed row: the next search refreshes
 
     syncs = []
     monkeypatch.setattr(jax, "block_until_ready", lambda x: syncs.append(1) or x)
@@ -756,9 +769,17 @@ def test_disabled_tracer_same_answers_no_record_and_no_sync(monkeypatch):
     tracer.enabled = True
     index.upsert(51, vectors[2], None)
     index.search([(vectors[3], 3, None)])
-    assert len(syncs) == 2  # the upload and the prepared copy, on the refresh path only
+    assert len(syncs) == 1  # a scatter refresh waits once, for the arrays it wrote
     index.search([(vectors[3], 3, None)])
-    assert len(syncs) == 2
+    assert len(syncs) == 1  # and an unchanged corpus for nothing
+    index.corpus.mirror_replaced()
+    index.search([(vectors[3], 3, None)])
+    assert len(syncs) == 3  # the whole upload and the whole prepared copy: one each
+    tracer.enabled = False
+    index.corpus.mirror_replaced()
+    index.upsert(52, vectors[2], None)
+    index.search([(vectors[3], 3, None)])
+    assert len(syncs) == 3  # neither path waits for a disabled tracer
 
 
 def test_index_search_is_a_child_of_knn_search_in_the_engine():
