@@ -151,7 +151,8 @@ class EncoderRuntime:
             bucket = ((bucket + n_dev - 1) // n_dev) * n_dev
         return bucket
 
-    def forward_ids(self, ids: np.ndarray, mask: np.ndarray) -> np.ndarray:
+    def forward(self, ids: np.ndarray, mask: np.ndarray) -> tuple[np.ndarray, dict]:
+        """Vectors [n, dim] and the shape that was really forwarded."""
         n = ids.shape[0]
         bucket = self.batch_bucket(n)
         if bucket != n:
@@ -163,4 +164,12 @@ class EncoderRuntime:
             ids_j = jax.device_put(ids_j, self._in_shard)
             mask_j = jax.device_put(mask_j, self._in_shard)
         out = self._fwd(self.params, ids_j, mask_j)
-        return np.asarray(out)[:n]
+        info = {
+            "batch_bucket": bucket,
+            "len_bucket": int(ids.shape[1]),
+            "tokens_padded": int(ids.size),
+        }
+        return np.asarray(out)[:n], info
+
+    def forward_ids(self, ids: np.ndarray, mask: np.ndarray) -> np.ndarray:
+        return self.forward(ids, mask)[0]

@@ -1,0 +1,646 @@
+"""A decoder-style trunk built from a per-layer table, run as an embedder.
+
+``TrunkConfig`` is read from a model's published ``config.json`` keys. Its
+``layer_table`` names, layer by layer, the kind of attention, of feed-forward
+and of residual path; ``ATTENTION``, ``FFN`` and ``RESIDUAL`` hold the blocks
+by kind, so another architecture adds kinds, not branches. Kinds so far:
+
+* attention ``mla``: multi-head latent attention in its expanded (prefill)
+  form, YaRN rotary on the decoupled rotary part, causal, no cache;
+* feed-forward ``dense`` (gated silu) and ``moe`` (``ops/moe.py``: sigmoid
+  router with bias-corrected top-k, dropless grouped experts, a shared expert);
+* residual ``mhc``: manifold-constrained hyper-connections (arXiv:2512.24880),
+  ``hc_mult`` residual streams mixed by a doubly stochastic matrix per token.
+
+``TrunkRuntime`` has ``EncoderRuntime``'s surface and is what
+``SentenceTransformerEmbedder(trunk=...)`` runs: the whole forward over a
+right-padded batch, causal, the final RMS norm of the summed streams at the
+last real token, float32, L2-normalised. Parameters and activations are
+bfloat16 (float32 accumulation; router scores, softmax, residual
+coefficients, norms and the pooled vector in float32), made on the device
+from a seed. Left out: multi-token prediction, the output head, decoding.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import json
+import math
+import os
+from typing import Any, Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from pathway_tpu.ops import moe
+from pathway_tpu.xpacks.llm._encoder import _bucket_batch
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN, as DeepSeek-V3's modelling code reads it."""
+
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    type: str = "yarn"
+
+
+@dataclasses.dataclass(frozen=True)
+class TrunkConfig:
+    """The published keys a trunk is built from (names as in ``config.json``)."""
+
+    name: str = "trunk"
+    vocab_size: int = 131072
+    hidden_size: int = 3584
+    num_hidden_layers: int = 40
+    num_attention_heads: int = 32
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    n_routed_experts: int = 64
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 4
+    first_k_dense_replace: int = 2
+    moe_layer_freq: int = 1
+    routed_scaling_factor: float = 2.0
+    norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    n_group: int = 1
+    topk_group: int = 1
+    hc_mult: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_scaling: RopeScaling | None = None
+    hidden_act: str = "silu"
+    attention_bias: bool = False
+    # the chip's share of the routed experts: (first, count); None holds all
+    experts_held: tuple[int, int] | None = None
+
+    def __post_init__(self):
+        unsupported = {
+            "scoring_func": self.scoring_func == "sigmoid",
+            "topk_method": self.topk_method == "noaux_tc",
+            "n_group": self.n_group == 1 and self.topk_group == 1,
+            "hidden_act": self.hidden_act == "silu",
+            "attention_bias": not self.attention_bias,
+        }
+        for key, ok in unsupported.items():
+            if not ok:
+                raise ValueError(f"trunk config: no block for this value of {key!r} yet")
+
+    @classmethod
+    def from_dict(cls, config: dict, **overrides: Any) -> "TrunkConfig":
+        """From a ``config.json``'s keys; keys that say nothing about the
+        trunk's shape are passed over."""
+        names = {f.name for f in dataclasses.fields(cls)}
+        picked = {k: v for k, v in config.items() if k in names}
+        scaling = picked.get("rope_scaling")
+        if isinstance(scaling, dict):
+            known = {f.name for f in dataclasses.fields(RopeScaling)}
+            picked["rope_scaling"] = RopeScaling(**{k: v for k, v in scaling.items() if k in known})
+        if picked.get("experts_held") is not None:
+            picked["experts_held"] = tuple(picked["experts_held"])
+        picked.update(overrides)
+        return cls(**picked)
+
+    @classmethod
+    def from_file(cls, path: str, **overrides: Any) -> "TrunkConfig":
+        with open(path, encoding="utf-8") as f:
+            return cls.from_dict(json.load(f), **overrides)
+
+    @classmethod
+    def coerce(cls, trunk: Any) -> "TrunkConfig":
+        """A ``TrunkConfig``, or the path of a ``config.json``."""
+        return trunk if isinstance(trunk, cls) else cls.from_file(os.fspath(trunk))
+
+    @property
+    def held(self) -> tuple[int, int]:
+        return self.experts_held or (0, self.n_routed_experts)
+
+    def layer_table(self) -> tuple["LayerKinds", ...]:
+        residual = "mhc" if self.hc_mult > 1 else "add"
+        table = []
+        for i in range(self.num_hidden_layers):
+            sparse = (
+                self.n_routed_experts > 0
+                and i >= self.first_k_dense_replace
+                and i % self.moe_layer_freq == 0
+            )
+            table.append(LayerKinds("mla", "moe" if sparse else "dense", residual))
+        return tuple(table)
+
+
+class LayerKinds(NamedTuple):
+    attention: str
+    ffn: str
+    residual: str
+
+
+class Block(NamedTuple):
+    """A kind of block: the shapes of its parameters, how it is applied, and
+    the ``jax.named_scope`` its device ops carry."""
+
+    shapes: Callable  # (config) -> {name: (shape, init kind)}, possibly nested
+    apply: Callable
+    scope: str
+
+
+def rms_norm(x, gain, eps: float):
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def _dot(x, w):
+    """``x [..., k] @ w [k, ...]`` in x's dtype, accumulated in float32."""
+    out = jax.lax.dot_general(
+        x, w.astype(x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return out.astype(x.dtype)
+
+
+# -- rotary (YaRN) -----------------------------------------------------------
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def rope_tables(config: TrunkConfig, length: int) -> tuple[np.ndarray, np.ndarray]:
+    """cos and sin [length, d_r] of positions 0..length-1."""
+    dim, base = config.qk_rope_head_dim, float(config.rope_theta)
+    exponent = np.arange(0, dim, 2, dtype=np.float64) / dim
+    inv_freq = 1.0 / base**exponent
+    attn_scale = 1.0
+    scaling = config.rope_scaling
+    if scaling is not None and scaling.type == "yarn":
+        def correction_dim(rotations):
+            return dim * math.log(
+                scaling.original_max_position_embeddings / (rotations * 2 * math.pi)
+            ) / (2 * math.log(base))
+
+        low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+        keep = 1.0 - ramp  # 1 where the frequency is left as trained
+        inv_freq = inv_freq / scaling.factor * (1 - keep) + inv_freq * keep
+        attn_scale = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim
+        )
+    angles = np.arange(length, dtype=np.float64)[:, None] * inv_freq[None, :]
+    angles = np.concatenate([angles, angles], axis=-1)
+    return (
+        (np.cos(angles) * attn_scale).astype(np.float32),
+        (np.sin(angles) * attn_scale).astype(np.float32),
+    )
+
+
+def softmax_scale(config: TrunkConfig) -> float:
+    scale = (config.qk_nope_head_dim + config.qk_rope_head_dim) ** -0.5
+    scaling = config.rope_scaling
+    if scaling is not None and scaling.mscale_all_dim:
+        scale *= yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x, cos, sin):
+    """``x [B, T, ..., d_r]``: pairs de-interleaved, then rotated by halves."""
+    x32 = x.astype(jnp.float32)
+    x32 = jnp.concatenate([x32[..., 0::2], x32[..., 1::2]], axis=-1)
+    half = x32.shape[-1] // 2
+    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    shape = (1, cos.shape[0]) + (1,) * (x.ndim - 3) + (cos.shape[1],)
+    return (x32 * cos.reshape(shape) + rotated * sin.reshape(shape)).astype(x.dtype)
+
+
+# -- attention kinds -----------------------------------------------------------
+
+
+def _mla_shapes(c: TrunkConfig) -> dict:
+    heads, qk = c.num_attention_heads, c.qk_nope_head_dim + c.qk_rope_head_dim
+    return {
+        "wq_a": ((c.hidden_size, c.q_lora_rank), "kernel"),
+        "q_norm": ((c.q_lora_rank,), "gain"),
+        "wq_b": ((c.q_lora_rank, heads, qk), "kernel"),
+        "wkv_a": ((c.hidden_size, c.kv_lora_rank + c.qk_rope_head_dim), "kernel"),
+        "kv_norm": ((c.kv_lora_rank,), "gain"),
+        "wkv_b": ((c.kv_lora_rank, heads, c.qk_nope_head_dim + c.v_head_dim), "kernel"),
+        "wo": ((heads, c.v_head_dim, c.hidden_size), "kernel_out"),
+    }
+
+
+def _mla(p, h, c: TrunkConfig, ctx: dict):
+    """Expanded form: every head's keys and values are rebuilt from the latent."""
+    d_n, eps = c.qk_nope_head_dim, c.rms_norm_eps
+    cos, sin = ctx["rope"]
+    q = _dot(rms_norm(_dot(h, p["wq_a"]), p["q_norm"], eps), p["wq_b"])  # [B, T, H, d_n + d_r]
+    q_n, q_r = q[..., :d_n], apply_rope(q[..., d_n:], cos, sin)
+    kv_a = _dot(h, p["wkv_a"])
+    c_kv, k_r = kv_a[..., : c.kv_lora_rank], kv_a[..., c.kv_lora_rank :]
+    k_r = apply_rope(k_r, cos, sin)  # one rotary key shared by the heads
+    kv = _dot(rms_norm(c_kv, p["kv_norm"], eps), p["wkv_b"])  # [B, T, H, d_n + d_v]
+    k_n, v = kv[..., :d_n], kv[..., d_n:]
+    mixed = causal_attention(q_n, q_r, k_n, k_r, v, softmax_scale(c))
+    out = jax.lax.dot_general(
+        mixed.astype(h.dtype), p["wo"].astype(h.dtype), (((2, 3), (0, 1)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return out.astype(h.dtype)
+
+
+def causal_attention(q_n, q_r, k_n, k_r, v, scale):
+    """Causal softmax attention, plain XLA: the [B, H, T, T] float32 logits
+    are materialised (jax's Pallas splash kernel ran this in 6.4 ms for 9.0 a
+    layer alone and moved the whole forward by nothing: PERF.md, PR 28).
+    ``q_n``, ``k_n`` [B, T, H, d_n]; ``q_r`` [B, T, H, d_r]; ``k_r``
+    [B, T, d_r], shared by the heads; ``v`` [B, T, H, d_v]."""
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q_n, k_n, preferred_element_type=jnp.float32)
+    logits += jnp.einsum("bqhd,bkd->bhqk", q_r, k_r, preferred_element_type=jnp.float32)
+    length = v.shape[1]
+    causal = jnp.tril(jnp.ones((length, length), bool))
+    logits = jnp.where(causal, logits * scale, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32)
+
+
+ATTENTION = {"mla": Block(_mla_shapes, _mla, "trunk.mla")}
+
+# -- feed-forward kinds --------------------------------------------------------
+
+
+def _gated_shapes(width_in: int, width: int) -> dict:
+    return {
+        "w_gate": ((width_in, width), "kernel"),
+        "w_up": ((width_in, width), "kernel"),
+        "w_down": ((width, width_in), "kernel"),
+    }
+
+
+def _gated_ffn(p, h):
+    gate = _dot(h, p["w_gate"]).astype(jnp.float32)
+    up = _dot(h, p["w_up"]).astype(jnp.float32)
+    return _dot((jax.nn.silu(gate) * up).astype(h.dtype), p["w_down"])
+
+
+def _dense_shapes(c: TrunkConfig) -> dict:
+    return _gated_shapes(c.hidden_size, c.intermediate_size)
+
+
+def _dense(p, h, c: TrunkConfig, ctx: dict):
+    return _gated_ffn(p, h)
+
+
+def _moe_shapes(c: TrunkConfig) -> dict:
+    d, f, held = c.hidden_size, c.moe_intermediate_size, c.held[1]
+    return {
+        "router": ((d, c.n_routed_experts), "kernel32"),
+        "bias": ((c.n_routed_experts,), "router_bias"),
+        "w_gate": ((held, d, f), "expert_kernel"),
+        "w_up": ((held, d, f), "expert_kernel"),
+        "w_down": ((held, f, d), "expert_kernel"),
+        "shared": _gated_shapes(d, f * c.n_shared_experts),
+    }
+
+
+def _moe(p, h, c: TrunkConfig, ctx: dict):
+    flat = h.reshape(-1, h.shape[-1])
+    routed, counts, choice = moe.expert_layer(
+        flat, ctx["valid"], p["router"], p["bias"], p["w_gate"], p["w_up"], p["w_down"],
+        top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor,
+        normalise=c.norm_topk_prob, experts_held=c.experts_held,
+    )
+    ctx["expert_counts"].append(counts)
+    ctx["expert_choice"].append(choice.reshape(h.shape[:-1] + choice.shape[-1:]))
+    with jax.named_scope("trunk.ffn"):
+        shared = _gated_ffn(p["shared"], flat)
+    return (routed + shared.astype(jnp.float32)).astype(h.dtype).reshape(h.shape)
+
+
+FFN = {
+    "dense": Block(_dense_shapes, _dense, "trunk.ffn"),
+    "moe": Block(_moe_shapes, _moe, "trunk.moe"),
+}
+
+# -- residual kinds ------------------------------------------------------------
+
+
+def _mhc_shapes(c: TrunkConfig) -> dict:
+    n = c.hc_mult
+    return {
+        "norm": ((n, c.hidden_size), "gain"),
+        "proj": ((n, c.hidden_size, n + n + n * n), "mhc_proj"),
+        "alpha": ((3,), "mhc_alpha"),  # a_pre, a_post, a_res
+        "bias": ((n + n + n * n,), "mhc_bias"),
+    }
+
+
+def sinkhorn(matrix, iters: int, eps: float):
+    """``matrix`` [n, n, ...] positive: ``iters`` rounds of a row then a
+    column normalisation, towards a doubly stochastic matrix per position."""
+    for _ in range(iters):
+        matrix = matrix / (matrix.sum(axis=1, keepdims=True) + eps)
+        matrix = matrix / (matrix.sum(axis=0, keepdims=True) + eps)
+    return matrix
+
+
+def mhc_coefficients(p, streams, c: TrunkConfig):
+    """H_pre [n, B, T], H_post [n, B, T] and H_res [n, n, B, T] of ``streams``
+    [n, B, T, d], in float32. The token axes are kept last: they fill the lanes.
+    Each pass reads the streams as they are stored and converts on the way:
+    no float32 copy of them is made."""
+    n = c.hc_mult
+    squares = sum(jnp.square(streams[i].astype(jnp.float32)).sum(axis=-1) for i in range(n))
+    scale = jax.lax.rsqrt(squares / (n * streams.shape[-1]) + c.rms_norm_eps)  # [B, T]
+    # x' P = rms_scale * (x (gain * P)): the gain goes into the small matrix,
+    # and the product is taken stream by stream, each read where it lies
+    proj = (p["norm"].astype(jnp.float32)[:, :, None] * p["proj"].astype(jnp.float32))
+    proj = proj.astype(streams.dtype)
+    raw = sum(
+        jnp.einsum("btd,dc->btc", streams[i], proj[i], preferred_element_type=jnp.float32)
+        for i in range(n)
+    )
+    raw = jnp.moveaxis(raw, -1, 0) * scale[None]
+    alpha, bias = p["alpha"].astype(jnp.float32), p["bias"].astype(jnp.float32)
+    pre = alpha[0] * raw[:n] + bias[:n, None, None]
+    post = alpha[1] * raw[n : 2 * n] + bias[n : 2 * n, None, None]
+    res = alpha[2] * raw[2 * n :] + bias[2 * n :, None, None]
+    res = jnp.clip(res, c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max)
+    res = sinkhorn(jnp.exp(res).reshape((n, n) + res.shape[1:]), c.hc_sinkhorn_iters, c.hc_eps)
+    return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), res
+
+
+def _mhc(p, streams, sublayer, c: TrunkConfig):
+    """``X <- H_res X + H_post^T F(u)`` with ``u = H_pre X``; ``sublayer`` is
+    F with its own norm in front."""
+    n = c.hc_mult
+    with jax.named_scope("trunk.mhc"):
+        h_pre, h_post, h_res = mhc_coefficients(p, streams, c)
+        mixed_in = sum(h_pre[i][..., None] * streams[i] for i in range(n)).astype(streams.dtype)
+    out = sublayer(mixed_in)
+    with jax.named_scope("trunk.mhc"):
+        return jnp.stack(
+            [
+                (
+                    sum(h_res[i, j][..., None] * streams[j] for j in range(n))
+                    + h_post[i][..., None] * out
+                ).astype(streams.dtype)
+                for i in range(n)
+            ]
+        )
+
+
+def _mhc_enter(x, c: TrunkConfig):
+    return jnp.broadcast_to(x[None], (c.hc_mult,) + x.shape)
+
+
+def _mhc_exit(streams):
+    return streams.astype(jnp.float32).sum(axis=0)
+
+
+class Residual(NamedTuple):
+    shapes: Callable
+    apply: Callable
+    enter: Callable
+    exit: Callable
+
+
+RESIDUAL = {"mhc": Residual(_mhc_shapes, _mhc, _mhc_enter, _mhc_exit)}
+
+
+def _block(registry: dict, kind: str, what: str):
+    try:
+        return registry[kind]
+    except KeyError:
+        raise NotImplementedError(
+            f"the layer table names the {what} kind {kind!r}; _trunk.py has {sorted(registry)}"
+        ) from None
+
+
+# -- parameters ---------------------------------------------------------------
+
+
+def param_shapes(config: TrunkConfig) -> dict:
+    """The parameter tree as {name: (shape, init kind)}."""
+    d = config.hidden_size
+    layers = []
+    for kinds in config.layer_table():
+        residual = _block(RESIDUAL, kinds.residual, "residual")
+        layers.append(
+            {
+                "attn_res": residual.shapes(config),
+                "attn_norm": ((d,), "gain"),
+                "attn": _block(ATTENTION, kinds.attention, "attention").shapes(config),
+                "ffn_res": residual.shapes(config),
+                "ffn_norm": ((d,), "gain"),
+                "ffn": _block(FFN, kinds.ffn, "feed-forward").shapes(config),
+            }
+        )
+    return {
+        "embed": ((config.vocab_size, d), "embedding"),
+        "layers": layers,
+        "final_norm": ((d,), "gain"),
+    }
+
+
+def _is_leaf(node) -> bool:
+    return isinstance(node, tuple) and len(node) == 2 and isinstance(node[1], str)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "kind", "dtype", "streams"))
+def _init_leaf(key, shape, kind, dtype, streams):
+    """One parameter, made where it will live. Small ones stay float32."""
+    normal = functools.partial(jax.random.normal, key, shape, jnp.float32)
+    if kind == "gain":
+        return 1.0 + 0.1 * normal()
+    if kind == "embedding":
+        return normal().astype(dtype)
+    if kind == "kernel":
+        return (normal() / math.sqrt(shape[0])).astype(dtype)
+    if kind == "kernel32":
+        return normal() / math.sqrt(shape[0])
+    if kind == "kernel_out":  # [heads, d_v, out]
+        return (normal() / math.sqrt(shape[0] * shape[1])).astype(dtype)
+    if kind == "expert_kernel":  # [experts, in, out]
+        return (normal() / math.sqrt(shape[1])).astype(dtype)
+    if kind == "router_bias":
+        return 0.01 * normal()
+    if kind == "mhc_proj":  # [n, d, n + n + n * n]: fan-in is all the streams
+        return (normal() / math.sqrt(shape[0] * shape[1])).astype(dtype)
+    if kind == "mhc_alpha":
+        return jnp.full(shape, 0.01, jnp.float32)
+    if kind == "mhc_bias":
+        # H_pre starts near 1/n a stream, H_post near 1, H_res near the identity
+        # (diagonal about 0.7: from further out, 20 Sinkhorn rounds do not
+        # bring the sums within 1e-4 of 1)
+        n = streams
+        centre = jnp.concatenate(
+            [
+                jnp.full((n,), -math.log(n - 1.0) if n > 1 else 0.0),
+                jnp.zeros((n,)),
+                2.0 * jnp.eye(n).reshape(-1),
+            ]
+        )
+        spread = jnp.concatenate([jnp.full((2 * n,), 0.1), jnp.full((n * n,), 0.3)])
+        return centre + spread * normal()
+    raise ValueError(f"unknown parameter kind {kind!r}")
+
+
+def init_params(config: TrunkConfig, key, dtype=jnp.bfloat16):
+    """Seeded gaussian parameters, leaf by leaf on the default device, so that
+    the whole tree never stands anywhere in float32. ``key`` is a PRNG key or
+    a whole number."""
+    if isinstance(key, int):
+        key = jax.random.PRNGKey(key)
+    counter = iter(range(1 << 30))
+
+    def build(node):
+        if _is_leaf(node):
+            shape, kind = node
+            return _init_leaf(
+                jax.random.fold_in(key, next(counter)), shape, kind, jnp.dtype(dtype), config.hc_mult
+            )
+        if isinstance(node, dict):
+            return {name: build(child) for name, child in node.items()}
+        return [build(child) for child in node]
+
+    return build(param_shapes(config))
+
+
+# -- the forward ----------------------------------------------------------------
+
+
+def forward(params, ids, mask, *, config: TrunkConfig):
+    """``ids``, ``mask`` [B, T], right-padded. Returns unit vectors [B, d]
+    float32, the tokens per expert [expert layers, E] int32 (real tokens, as
+    the router sent them) and the router's choice [expert layers, B, T, k]
+    int32 (-1 at a padding position)."""
+    table = config.layer_table()
+    eps = config.rms_norm_eps
+    ctx = {
+        "rope": rope_tables(config, ids.shape[1]),
+        "valid": mask.reshape(-1) > 0,
+        "expert_counts": [],
+        "expert_choice": [],
+    }
+    x = params["embed"][ids]
+    streams = _block(RESIDUAL, table[0].residual, "residual").enter(x, config)
+    for kinds, p in zip(table, params["layers"]):
+        residual = _block(RESIDUAL, kinds.residual, "residual")
+        attention = _block(ATTENTION, kinds.attention, "attention")
+        ffn = _block(FFN, kinds.ffn, "feed-forward")
+
+        def attend(h, p=p, attention=attention):
+            with jax.named_scope(attention.scope):
+                return attention.apply(p["attn"], rms_norm(h, p["attn_norm"], eps), config, ctx)
+
+        def feed(h, p=p, ffn=ffn):
+            with jax.named_scope(ffn.scope):
+                return ffn.apply(p["ffn"], rms_norm(h, p["ffn_norm"], eps), config, ctx)
+
+        streams = residual.apply(p["attn_res"], streams, attend, config)
+        streams = residual.apply(p["ffn_res"], streams, feed, config)
+    # pool before the last norm: only the last real position of each row is kept
+    last = jnp.maximum(mask.sum(axis=1).astype(jnp.int32) - 1, 0)
+    picked = jnp.take_along_axis(streams, last[None, :, None, None], axis=2)[:, :, 0]  # [n, B, d]
+    summed = _block(RESIDUAL, table[-1].residual, "residual").exit(picked)
+    pooled = rms_norm(summed, params["final_norm"], eps)
+    vectors = pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-12)
+    if not ctx["expert_counts"]:  # no expert layer in the table
+        top_k, experts = max(config.num_experts_per_tok, 1), max(config.n_routed_experts, 1)
+        return vectors, jnp.zeros((0, experts), jnp.int32), jnp.zeros((0,) + ids.shape + (top_k,), jnp.int32)
+    return vectors, jnp.stack(ctx["expert_counts"]), jnp.stack(ctx["expert_choice"])
+
+
+class TrunkRuntime:
+    """``EncoderRuntime``'s surface over a ``TrunkConfig``: owns the
+    parameters and one jitted forward per (batch bucket, length bucket).
+    ``params`` is made from ``seed`` when first read, unless it was set."""
+
+    def __init__(
+        self,
+        config: TrunkConfig,
+        max_len: int = 512,
+        seed: int = 0,
+        mesh: Any = None,
+        dtype: Any = jnp.bfloat16,
+    ):
+        if mesh is not None:
+            raise NotImplementedError(
+                "a trunk runs on one chip: its experts have no exchange across a mesh yet"
+            )
+        self.config = config
+        self.dim = config.hidden_size
+        self.max_len = max_len
+        self.pretrained = False
+        self.dtype = dtype
+        self._seed = seed
+        self._params = None
+        self._fwd = jax.jit(functools.partial(forward, config=config))
+
+    @property
+    def params(self):
+        if self._params is None:
+            self._params = init_params(self.config, self._seed, self.dtype)
+        return self._params
+
+    @params.setter
+    def params(self, tree) -> None:
+        self._params = tree
+
+    def batch_bucket(self, n: int) -> int:
+        return _bucket_batch(n)
+
+    def forward(
+        self, ids: np.ndarray, mask: np.ndarray, routing: bool = False
+    ) -> tuple[np.ndarray, dict]:
+        """Vectors [n, dim] and what was really forwarded: the padded shape
+        and the expert layers' row counts. ``routing=True`` adds
+        ``expert_choice`` [expert layers, n, T, k], the experts each token
+        went to (-1: nowhere); it stays on the device unless asked for."""
+        n = ids.shape[0]
+        bucket = self.batch_bucket(n)
+        if bucket != n:
+            ids = np.pad(ids, ((0, bucket - n), (0, 0)))
+            mask = np.pad(mask, ((0, bucket - n), (0, 0)))
+        out, counts, choice = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))
+        info = {
+            "batch_bucket": bucket,
+            "len_bucket": int(ids.shape[1]),
+            "tokens_padded": int(ids.size),
+            "trunk": self.config.name,
+        }
+        counts = np.asarray(counts)
+        if counts.size:  # the trunk has expert layers
+            first, held = self.config.held
+            info.update(
+                expert_rows_useful=int(counts[:, first : first + held].sum()),
+                expert_rows_computed=sum(
+                    moe.rows_computed(layer[first : first + held]) for layer in counts
+                ),
+                expert_tokens_max=int(counts.max()),
+                expert_tokens_mean=float(counts.mean()),
+            )
+        if routing:
+            info["expert_choice"] = np.asarray(choice)[:, :n]
+        return np.asarray(out)[:n], info
+
+    def forward_ids(self, ids: np.ndarray, mask: np.ndarray) -> np.ndarray:
+        return self.forward(ids, mask)[0]

@@ -38,7 +38,13 @@ class SentenceTransformerEmbedder(BaseEmbedder):
     (reference name: xpacks/llm/embedders.py:270 — there torch
     sentence-transformers; here the flax encoder; pass a model name of a
     locally-cached HF tokenizer to reuse its vocab, otherwise a hashing
-    tokenizer is used)."""
+    tokenizer is used).
+
+    ``trunk=`` (a ``TrunkConfig``, or the path of a model's published
+    ``config.json``) runs a decoder-style trunk from ``_trunk.py``'s layer
+    table in the encoder's place: sparse experts, latent attention, a
+    multi-stream residual; causal, pooled at the last real token. Same ``embed_batch``, tokenizer resolution, pad ladder
+    and spans; ``dim``/``depth``/``heads`` are then the config's."""
 
     def __init__(
         self,
@@ -52,6 +58,7 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         max_len: int = 512,
         mesh: Any = None,
         batch_size: int = 1024,
+        trunk: Any = None,
         **init_kwargs,
     ):
         import os
@@ -68,6 +75,11 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         # random-init flax trunk + hashing tokenizer remain the offline
         # fallback (reference loads sentence-transformers checkpoints,
         # embedders.py:270)
+        trunk_config = None
+        if trunk is not None:
+            from pathway_tpu.xpacks.llm._trunk import TrunkConfig
+
+            trunk_config = TrunkConfig.coerce(trunk)
         model_dir = _find_model_dir(model)
         model_path = None
         if model_dir is not None and os.path.exists(
@@ -104,7 +116,11 @@ class SentenceTransformerEmbedder(BaseEmbedder):
                 vocab_txt, lowercase=lowercase
             )
         if self.tokenizer is None:
-            self.tokenizer = HashingTokenizer()
+            self.tokenizer = (
+                HashingTokenizer()
+                if trunk_config is None
+                else HashingTokenizer(trunk_config.vocab_size)
+            )
         vocab_size = self.tokenizer.vocab_size
         if model_path is not None and isinstance(
             self.tokenizer, HashingTokenizer
@@ -120,15 +136,25 @@ class SentenceTransformerEmbedder(BaseEmbedder):
                 model,
             )
             model_path = None
-        self.runtime = EncoderRuntime(
-            vocab_size=vocab_size,
-            dim=dim,
-            depth=depth,
-            heads=heads,
-            max_len=max_len,
-            mesh=mesh,
-            model_path=model_path,
-        )
+        if trunk_config is not None:
+            from pathway_tpu.xpacks.llm._trunk import TrunkRuntime
+
+            if vocab_size > trunk_config.vocab_size:
+                raise ValueError(
+                    f"the tokenizer has {vocab_size} ids, the trunk's embedding "
+                    f"{trunk_config.vocab_size} rows"
+                )
+            self.runtime: Any = TrunkRuntime(trunk_config, max_len=max_len, mesh=mesh)
+        else:
+            self.runtime = EncoderRuntime(
+                vocab_size=vocab_size,
+                dim=dim,
+                depth=depth,
+                heads=heads,
+                max_len=max_len,
+                mesh=mesh,
+                model_path=model_path,
+            )
         self.model = model
         self.kwargs = call_kwargs
         # Flight Recorder: embed batch latency and documents embedded,
@@ -173,16 +199,14 @@ class SentenceTransformerEmbedder(BaseEmbedder):
                     len_bucket = int(ids.shape[1])
                     tok.set_attribute("tokens_real", tokens_real)
                     tok.set_attribute("len_bucket", len_bucket)
-                pad_bucket = self.runtime.batch_bucket(docs)
-                with _tracer.span(
-                    "embed.forward",
-                    batch_bucket=pad_bucket,
-                    len_bucket=len_bucket,
-                    tokens_real=tokens_real,
-                    # what the program really forwards
-                    tokens_padded=pad_bucket * len_bucket,
-                ):
-                    out = self.runtime.forward_ids(ids, mask)
+                with _tracer.span("embed.forward") as fwd:
+                    out, forwarded = self.runtime.forward(ids, mask)
+                    # what the runtime really forwarded (the padded shape,
+                    # a trunk's expert rows), as the runtime itself counts it
+                    fwd.set_attribute("tokens_real", tokens_real)
+                    for key, value in forwarded.items():
+                        fwd.set_attribute(key, value)
+                    pad_bucket = forwarded["batch_bucket"]
                 dt = _time.perf_counter() - t0
             m_batch_seconds.observe(dt, exemplar=sp.trace_id)
             m_docs.inc(docs)
