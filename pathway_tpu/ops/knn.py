@@ -12,7 +12,13 @@ Design:
   jitted program of one shape; the whole mirror is uploaded only where
   there is no device copy to patch, or an eighth of it or more changed.
 - scores = queries @ corpus.T runs in bfloat16 on the MXU with f32
-  accumulation; invalid slots are masked to -inf before `lax.top_k`.
+  accumulation; invalid slots are masked to -inf, and the exact top-k of
+  the [B, N] scores is taken by selection where N is large beside k
+  (`topk_stage1`): the maximum of every 128-column block, the k blocks
+  with the largest maxima, then `lax.top_k` over those blocks' k x 128
+  scores. Exact: a block whose maximum lies above the k-th best score
+  holds one of the at most k - 1 scores above it, so the k best blocks
+  hold every such score and enough equal to it to fill the k places.
 - multi-chip: corpus rows are sharded over the mesh's 'data' axis via
   shard_map — each device computes a local top-k, candidates are
   all-gathered over ICI and merged with a final top-k (the TPU-KNN
@@ -22,6 +28,7 @@ Design:
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Any
 
@@ -71,14 +78,64 @@ def _scores(
     return dots
 
 
+# Columns in a block of the selecting first stage: one row of a vector
+# register's tile, so a block is one contiguous 512 B slice of the scores.
+SELECT_BLOCK = 128
+
+
+def topk_stage1(n: int, k: int) -> str:
+    """How ``_masked_topk`` starts on k of n columns, from the shapes alone:
+    "blockmax" selects (the k blocks with the largest maxima; no sort of
+    size n), "sort" is ``lax.top_k`` as it was (over 1,024-column blocks
+    first where n is large). Selecting pays while its ``k * SELECT_BLOCK``
+    candidates are few beside n; large k or small n keep the old path (on
+    a v5e the two cost the same at the edge, PERF.md section 6, PR 29)."""
+    return "blockmax" if 8 * k * SELECT_BLOCK <= n else "sort"
+
+
+def _blockmax_topk(s: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """Top-k of [B, N] scores by selection. The k blocks of
+    ``SELECT_BLOCK`` adjacent columns with the largest maxima hold the
+    answer: with t the k-th best score, a block with a maximum above t
+    holds one of the at most k - 1 scores above t, so fewer than k blocks
+    have one, and the rest of the k places go to blocks whose maximum is
+    t. Blocks are taken and candidates laid out in ascending row order, so
+    a tie goes where ``lax.top_k`` sends it: to the lowest row wherever its
+    lowering keeps tied values in order (the CPU's does; the TPU's swapped
+    one tied pair among 131,072 candidates at k = 1,024).
+
+    The scores are viewed as [B/8, N/128, 8, 128], which on the TPU is how
+    a [B, N] float32 array is tiled anyway: the block maxima and the
+    gather of the winning blocks read it in place, so the scores are
+    written once and read twice."""
+    b, n = s.shape
+    w = SELECT_BLOCK
+    pad = -n % w
+    if pad:
+        s = jnp.pad(s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+    nblk = (n + pad) // w
+    r = math.gcd(b, 8)
+    tiles = s.reshape(b // r, r, nblk, w).transpose(0, 2, 1, 3)
+    maxima = tiles.max(-1).transpose(0, 2, 1).reshape(b, nblk)
+    _, blocks = jax.lax.top_k(maxima, k)
+    blocks = jnp.sort(blocks, axis=-1)  # [B, k], ascending
+    q = jnp.arange(b)[:, None]
+    cand = tiles[q // r, blocks, q % r].reshape(b, k * w)
+    scores, pos = jax.lax.top_k(cand, k)
+    idx = jnp.take_along_axis(blocks, pos // w, axis=1) * w + pos % w
+    return scores, idx
+
+
 @jax.named_scope("knn.topk")
 def _masked_topk(s: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """Exact top-k over [B, N] scores. For large N uses the two-stage
-    block decomposition (top-k per 1024-column block, then top-k over the
-    block winners) — exact because every global top-k element is within
-    the top-k of its own block, and much friendlier to the TPU than one
-    monolithic 1M-wide TopK."""
+    """Exact top-k over [B, N] scores; ``topk_stage1`` picks the first
+    stage. The old one takes the top-k of every 1,024-column block and
+    merges the winners (every global top-k element is within the top-k of
+    its own block); XLA's TPU backend lowers it to a full sort of each
+    block."""
     n = s.shape[-1]
+    if topk_stage1(n, k) == "blockmax":
+        return _blockmax_topk(s, k)
     blk = 1024
     if n >= 64 * blk and k <= blk:
         nblk = (n + blk - 1) // blk

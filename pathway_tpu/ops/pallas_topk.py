@@ -6,9 +6,11 @@ mapped onto the MXU): for each grid step one [BLK, D] corpus tile is
 staged in VMEM, scored against the [B, D] queries on the MXU, masked, and
 reduced to the tile's top-k (k max/argmax/suppress passes on the VPU) —
 so only [B, nblk*KP] candidates ever return to HBM instead of the full
-[B, N] score matrix. A final lax.top_k merges block winners (exact, same
-argument as ops/knn._masked_topk). Runs in interpreter mode off-TPU so
-tests cover it on the CPU backend.
+[B, N] score matrix. A final lax.top_k merges block winners (exact: every
+global top-k element is within the top-k of its own block, the merge that
+the 1,024-block path of ops/knn._masked_topk shares; its selecting first
+stage, _blockmax_topk, is another method). Runs in interpreter mode
+off-TPU so tests cover it on the CPU backend.
 
 TPU lowering constraint: the last two dims of every
 block must be divisible by (8, 128) or equal the overall array dims. The

@@ -25,6 +25,7 @@ from pathway_tpu.ops.knn import (
     DeviceCorpus,
     dense_topk_prepared,
     sharded_topk,
+    topk_stage1,
 )
 from pathway_tpu.stdlib.indexing._filters import compile_filter
 
@@ -196,11 +197,18 @@ class TpuDenseKnnIndex:
         The corpus arrays are fetched first (changed rows are handed over
         and scattered into them there, under spans of its own), so the
         ``index.topk`` span holds the kernel and the transfer of its
-        results and nothing of the refresh. The arrays are held for this
-        call only: the corpus donates them to its next refresh."""
+        results and nothing of the refresh; its ``stage1`` says how the
+        XLA top-k starts at this call's shapes (``ops/knn.topk_stage1`` of
+        the rows a device scans and ``eff_k``; the Pallas kernel has a
+        first stage of its own). The arrays are held for this call only:
+        the corpus donates them to its next refresh."""
         if self.mesh is not None:
             corpus_arr, valid = self.corpus.device_arrays()
-            kernel = "sharded"
+            local_rows = corpus_arr.shape[0] // self.mesh.shape[self.axis]
+            attrs = {
+                "kernel": "sharded",
+                "stage1": topk_stage1(local_rows, min(eff_k, local_rows)),
+            }
             run = functools.partial(
                 sharded_topk,
                 qmat,
@@ -221,7 +229,10 @@ class TpuDenseKnnIndex:
             prep, c2, valid = self.corpus.prepared_arrays(
                 self.metric, bf16=False
             )
-            kernel = "xla"
+            attrs = {
+                "kernel": "xla",
+                "stage1": topk_stage1(prep.shape[0], eff_k),
+            }
             run = functools.partial(
                 dense_topk_prepared,
                 qmat,
@@ -236,7 +247,7 @@ class TpuDenseKnnIndex:
                 from pathway_tpu.ops import pallas_topk as pt
 
                 if pt.supported(prep.shape[0], eff_k):
-                    kernel = "pallas"
+                    attrs = {"kernel": "pallas"}
                     run = functools.partial(
                         pt.pallas_dense_topk,
                         qmat,
@@ -245,7 +256,7 @@ class TpuDenseKnnIndex:
                         eff_k,
                         metric=self.metric,
                     )
-        with get_tracer().span("index.topk", kernel=kernel):
+        with get_tracer().span("index.topk", **attrs):
             scores, idx = run()
             return np.asarray(scores), np.asarray(idx)
 

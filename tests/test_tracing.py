@@ -609,12 +609,12 @@ def _toy_embedder():
     return SentenceTransformerEmbedder(dim=16, depth=1, heads=2, max_len=64)
 
 
-def _toy_index(rows=5, dim=8):
+def _toy_index(rows=5, dim=8, reserved=1024):
     import numpy as np
 
     from pathway_tpu.stdlib.indexing._index_impls import TpuDenseKnnIndex
 
-    index = TpuDenseKnnIndex(dim, "cosine", reserved_space=1024)
+    index = TpuDenseKnnIndex(dim, "cosine", reserved_space=reserved)
     vectors = np.random.default_rng(7).normal(size=(rows, dim)).astype("float32")
     for key, vector in enumerate(vectors):
         index.upsert(key, vector, None)
@@ -696,7 +696,23 @@ def test_embed_batch_splits_into_tokenize_and_forward():
     assert tokenize.duration_ns + forward.duration_ns <= batch.duration_ns
 
 
+@pytest.mark.parametrize(
+    "reserved, k, stage1", [(1024, 2, "sort"), (4096, 2, "blockmax"), (4096, 5, "sort")]
+)
+def test_index_topk_span_says_how_the_topk_starts(reserved, k, stage1):
+    from pathway_tpu.ops.knn import topk_stage1
+
+    index, vectors = _toy_index(reserved=reserved)
+    tracing.get_tracer().clear()
+    index.search([(vectors[0], k, None)])
+    topk = _one(_layer_spans(), "index.topk")
+    assert topk.attributes == {"kernel": "xla", "stage1": stage1}
+    assert stage1 == topk_stage1(index.corpus.capacity, k)
+
+
 def test_index_search_names_the_refresh_only_when_the_corpus_changed():
+    from pathway_tpu.ops.knn import topk_stage1
+
     index, vectors = _toy_index()
     tracer = tracing.get_tracer()
     first = index.search([(vectors[0], 2, None), (vectors[1], 2, None), (vectors[2], 2, None)])
@@ -712,7 +728,12 @@ def test_index_search_names_the_refresh_only_when_the_corpus_changed():
         "bytes": 1024 * 8 * 4 + 1024, "rows": 5, "changed_rows": 0, "full": 1,
     }
     assert prepare.attributes == {"metric": "cosine", "bf16": False, "rows": 5}
-    assert _one(records, "index.topk").attributes == {"kernel": "xla"}
+    # how the top-k starts is read from the program's shapes: the columns
+    # it scans (the corpus's capacity, not its 5 rows) and the k it keeps
+    assert _one(records, "index.topk").attributes == {
+        "kernel": "xla",
+        "stage1": topk_stage1(index.corpus.capacity, 2),
+    }
     # the four parts add up: self time is what the children leave
     parts = sum(r.duration_ns for r in _children(records, search))
     assert 0 < parts <= search.duration_ns

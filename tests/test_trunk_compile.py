@@ -1,14 +1,18 @@
-"""The expert layer's kernels compiled at the published widths for a v5e that
+"""Programs of the main paths compiled at the cells' own sizes for a v5e that
 is described, not attached: what the chip's compiler would refuse (a block
-off the tiling, too much VMEM) fails here, at no chip time. Nothing runs."""
+off the tiling, too much VMEM) fails here, and what it makes of a program
+(a kernel, a sort) can be read, at no chip time. Nothing runs. One file for
+all of them: the worker that is given it holds the TPU's library."""
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from pathway_tpu.ops import moe
+from pathway_tpu.ops import knn, moe
 
 TOKENS, D, F, EXPERTS, TOP_K = 16384, 3584, 1024, 64, 4  # Xing4.0-29B-A4B, a 32 x 512 tick
 
@@ -58,3 +62,31 @@ def test_expert_layer_compiles_for_a_v5e(one_chip, no_cache, monkeypatch):
     assert text.count("tpu_custom_call") >= 3 and moe.GMM_KERNEL_NAME in text
     # the rows in and out, not a [rows, experts, width] expansion
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def _largest_shape(hlo: str) -> int:
+    """Elements of the largest array shape written in a piece of HLO text."""
+    return max(math.prod(map(int, dims.split(","))) for dims in re.findall(r"\[([\d,]+)\]", hlo))
+
+
+@pytest.mark.parametrize("bucket", [1, 8, 32])
+def test_search_program_sorts_nothing_of_the_corpus_size(one_chip, no_cache, bucket):
+    # `minilm-l6-384.retrieve`: 2,097,152 x 384 float32 rows, k = 10
+    rows, dim, k = 2_097_152, 384, 10
+    assert knn.topk_stage1(rows, k) == "blockmax"
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = knn.dense_topk_prepared.lower(
+        shape((bucket, dim), jnp.float32), shape((rows, dim), jnp.float32),
+        shape((rows,), jnp.float32), shape((rows,), jnp.bool_),
+        k=k, metric="cosine", bf16=False,
+    ).compile()
+    # what is sorted is the k winning blocks' ids; the old first stage sorted all b x N scores
+    sorted_sizes = [_largest_shape(line.split(" sort(")[0]) for line in compiled.as_text().splitlines() if " sort(" in line]
+    assert all(n <= bucket * rows // 64 for n in sorted_sizes), sorted_sizes
+    # the [b, N] scores exist once: written by the scan, read in place by
+    # the block maxima and the gather (nothing of their size is a temporary
+    # beside them)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * bucket * rows * 4 + 2**20
