@@ -97,9 +97,8 @@ def main(argv: list[str] | None = None) -> int:
         dest="prove_shapes",
         metavar="FAMILY:k=v,...",
         help="prove one extra kernel shape in --plane mode, e.g. "
-        "paged_attention:head_dim=129 or pallas_topk:k=10,pad=0 "
-        "(repeatable); shapes the shared gate rejects become ERROR "
-        "findings",
+        "paged_attention:head_dim=129 (repeatable); shapes the shared "
+        "gate rejects become ERROR findings",
     )
     args = parser.parse_args(argv)
 

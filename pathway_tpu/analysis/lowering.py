@@ -1,15 +1,15 @@
 """The Lowering Ledger: device-free TPU compilability proofs.
 
-Every bench since r02 has run on the CPU backend, so the TPU-shaped
-codepaths (ops/pallas_topk.py, ops/paged_attention.py, Tick Forge's
-jitted segments) are exercised by the tests in interpret mode — and a
-kernel that passes in interpret mode has not thereby lowered for a TPU.
+The tests run on the CPU backend, so the TPU-shaped codepaths
+(ops/paged_attention.py, Tick Forge's jitted segments) are exercised
+there in interpret mode — and a kernel that passes in interpret mode
+has not thereby lowered for a TPU.
 This module turns "will it compile for TPU" into a static, hardware-free
 proof with three layers:
 
 1. **Shared static gate** — ``check_tpu_block_rules`` / ``lane_pad`` /
    ``check_block_specs``, the single source of truth for the Mosaic
-   (8, 128) tiling rules that both Pallas kernels previously duplicated.
+   (8, 128) tiling rules a Pallas kernel's layout is held to.
    Violations raise :class:`LoweringRuleViolation`, a ``ValueError``
    carrying the violated rule's id.
 2. **AOT prover** — :func:`prove_lowering` runs every registered kernel
@@ -17,8 +17,7 @@ proof with three layers:
    ``jax.export.export(jax.jit(fn), platforms=["tpu"])`` against
    abstract ``ShapeDtypeStruct`` args: compile-only, zero device access,
    works under ``JAX_PLATFORMS=cpu``. Families cover the pow2 pad
-   ladder plus the known crash shapes (k=10 lane pad, head_dim
-   1/32/128/129); VMEM footprints are estimated statically from the
+   ladder plus the known crash shapes (head_dim 1/32/128/129); VMEM footprints are estimated statically from the
    BlockSpecs and checked against the per-core budget.
 3. **Content-addressed manifest** — :func:`write_manifest` emits
    ``LOWERING_r16.json`` with a sha256 per case over the serialized
@@ -77,9 +76,9 @@ class LoweringRuleViolation(ValueError):
 
 
 def lane_pad(d: int) -> int:
-    """``d`` padded up to the TPU lane width (multiple of 128) — the one
-    rule both kernels apply to their minor output dims (pallas_topk's
-    ``_kpad`` k-tiles, paged_attention's head_dim pool width)."""
+    """``d`` padded up to the TPU lane width (multiple of 128) — the
+    rule a kernel applies to its minor output dim (paged_attention's
+    head_dim pool width)."""
     return -(-int(d) // LANE) * LANE
 
 
@@ -222,87 +221,6 @@ def case_for_shape(family: str, shape: dict) -> LoweringCase:
     # refusing it is an ERROR finding, never an expected rejection
     case.expect = "lower"
     return case
-
-
-# --- pallas_topk -----------------------------------------------------------
-
-
-def _topk_case(b: int, d: int, n: int, k: int, pad: bool = True):
-    from pathway_tpu.ops import pallas_topk as pt
-
-    if pad:
-
-        def static_check():
-            pt.validate_lowering(b, d, n, k)
-
-        def build():
-            import functools
-
-            import jax
-            import jax.numpy as jnp
-
-            fn = functools.partial(
-                pt.pallas_block_topk.__wrapped__, k=k, interpret=False
-            )
-            args = (
-                jax.ShapeDtypeStruct((b, d), jnp.float32),
-                jax.ShapeDtypeStruct((n, d), jnp.float32),
-                jax.ShapeDtypeStruct((n,), jnp.bool_),
-            )
-            return fn, args
-
-        def vmem():
-            _g, ins, outs, _sh, _nb, _kp = pt._specs(b, d, n, k)
-            return estimate_vmem_bytes(ins + outs)
-
-        return LoweringCase(
-            "pallas_topk",
-            f"b{b}_d{d}_n{n}_k{k}",
-            {"b": b, "d": d, "n": n, "k": k},
-            build=build,
-            static_check=static_check,
-            vmem=vmem,
-        )
-
-    # raw un-lane-padded k tile: a (1, 1, k) block breaks the 8x128
-    # rule, and the shared gate must keep rejecting it
-    nblk = max(n // pt.BLK, 1)
-
-    def bad_static():
-        check_tpu_block_rules((b, k), (b, nblk * k))
-
-    return LoweringCase(
-        "pallas_topk",
-        f"unpadded_b{b}_k{k}_tile",
-        {"b": b, "k": k, "nblk": nblk, "pad": 0},
-        static_check=bad_static,
-        expect="reject",
-    )
-
-
-@kernel_family("pallas_topk")
-def _topk_cases() -> list[LoweringCase]:
-    cases = [
-        # k=10 is not lane-aligned: it forces the 128-lane pad
-        _topk_case(8, 128, 2048, 10),
-        _topk_case(8, 128, 2048, 1),
-        _topk_case(8, 64, 1024, 100),
-        _topk_case(16, 256, 4096, 128),
-    ]
-    # and the un-padded tile it replaced stays rejected
-    cases.append(_topk_case(8, 128, 2048, 10, pad=False))
-    return cases
-
-
-@family_shape("pallas_topk")
-def _topk_shape(shape: dict) -> LoweringCase:
-    return _topk_case(
-        shape.pop("b", 8),
-        shape.pop("d", 128),
-        shape.pop("n", 2048),
-        shape.pop("k", 10),
-        pad=bool(shape.pop("pad", 1)),
-    )
 
 
 # --- paged_attention -------------------------------------------------------

@@ -2,7 +2,7 @@
 
 Every process entry that will own a device calls
 :func:`configure_compile_cache` once before it compiles (``pw.run``,
-``serving/replica.py main``, ``bench.py``, ``chip_smoke.py``).  The
+``serving/replica.py main``, ``chip_smoke.py``, ``benchmarks/``).  The
 directory is placed from OUTSIDE the program: when
 ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it by itself and nothing
 is set in code; otherwise the cache lives at one fixed path inside the

@@ -7,18 +7,11 @@ and the per-row expression interpreter's heavy numeric ops. Everything here is
 pure jax — jit once, run per microbatch tick.
 """
 
-from pathway_tpu.ops.knn import (
-    KnnParams,
-    cosine_topk,
-    dense_topk,
-    sharded_topk,
-)
+from pathway_tpu.ops.knn import dense_topk, sharded_topk
 from pathway_tpu.ops.segment import segment_count, segment_mean, segment_sum
 
 __all__ = [
-    "KnnParams",
     "dense_topk",
-    "cosine_topk",
     "sharded_topk",
     "segment_sum",
     "segment_count",

@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import functools
 import math
-from dataclasses import dataclass
 from typing import Any
 
 import jax
@@ -37,12 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from pathway_tpu.observability.tracing import NOOP_SPAN, get_tracer
-
-
-@dataclass(frozen=True)
-class KnnParams:
-    metric: str = "cosine"  # cosine | dot | l2sq
-    bf16: bool = True
 
 
 # The jax.named_scope names below are op metadata only (nothing computed
@@ -227,10 +220,6 @@ def dense_topk_prepared(
     return scores, idx
 
 
-def cosine_topk(queries, corpus, valid, k):
-    return dense_topk(queries, corpus, valid, k, metric="cosine")
-
-
 def shard_base_indices(n: int, n_shards: int) -> np.ndarray:
     """Per-row base offset of its shard (local->global index mapping in the
     sharded merge); single source for sharded_topk and the multi-process
@@ -350,6 +339,14 @@ def _scatter_rows(
     return put(device, rows, sharding), put(valid, row_valid, valid_sharding), fresh
 
 
+# What the one-chip search scans: a prepared copy of float32 rows. On a TPU
+# a float32 matmul at default precision multiplies in bf16 all the same
+# (measured on a v5e, PR 21: score error 3.5e-4, recall@10 0.986 vs exact
+# float32 on gaussian rows; "highest" precision gives 7.5e-8 / 1.0). The
+# ids are the contract; scores carry about three digits there.
+SCAN_BF16 = False
+
+
 class DeviceCorpus:
     """Growable padded corpus living on device.
 
@@ -376,10 +373,10 @@ class DeviceCorpus:
         self.valid_sharding = valid_sharding
         self._replicated = None
         self.dim = dim
-        # align capacity to lcm(1024, n_shards): multiple of 1024 so the
-        # Pallas block kernel (ops/pallas_topk.py, BLK=1024) is always
-        # applicable, AND divisible by the mesh shard count so sharded_topk
-        # can split rows evenly; padding is masked by `valid`
+        # align capacity to lcm(1024, n_shards): a multiple of 1024 (the
+        # shapes every program here was compiled and measured at), AND
+        # divisible by the mesh shard count so sharded_topk can split rows
+        # evenly; padding is masked by `valid`
         align = 1024
         if sharding is not None:
             import math
@@ -557,6 +554,57 @@ class DeviceCorpus:
                 _ready_if_live(span, self._prepared[key])
         prep, c2 = self._prepared[key]
         return prep, c2, valid
+
+    def topk(
+        self, queries: np.ndarray, k: int, metric: str
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """The device half of a search: (scores [B, k], slots [B, k]) of
+        the top ``k`` rows per query, on the host. The program follows from
+        where the corpus lives and ``topk_stage1`` from the shapes; one the
+        compiler refuses raises, nothing here retries on another.
+
+        The arrays are fetched first (changed rows are handed over and
+        scattered into them there, under spans of their own), so the
+        ``index.topk`` span holds the program and the transfer of its
+        results and nothing of the refresh; its ``stage1`` is
+        ``topk_stage1`` of the rows one device scans and ``k``. The arrays
+        are held for this call only: they are donated to the next refresh."""
+        if self.sharding is not None:
+            # kept as it was, not repaired: a mesh scores the raw rows per
+            # query, at sharded_topk's default dtype (ROADMAP D16)
+            corpus, valid = self.device_arrays()
+            mesh, axis = self.sharding.mesh, self.sharding.spec[0]
+            local_rows = corpus.shape[0] // mesh.shape[axis]
+            attrs = {
+                "kernel": "sharded",
+                "stage1": topk_stage1(local_rows, min(k, local_rows)),
+            }
+            run = functools.partial(
+                sharded_topk,
+                queries,
+                corpus,
+                valid,
+                k,
+                mesh=mesh,
+                axis=axis,
+                metric=metric,
+            )
+        else:
+            prep, c2, valid = self.prepared_arrays(metric, bf16=SCAN_BF16)
+            attrs = {"kernel": "xla", "stage1": topk_stage1(prep.shape[0], k)}
+            run = functools.partial(
+                dense_topk_prepared,
+                queries,
+                prep,
+                c2,
+                valid,
+                k,
+                metric=metric,
+                bf16=SCAN_BF16,
+            )
+        with get_tracer().span("index.topk", **attrs):
+            scores, slots = run()
+            return np.asarray(scores), np.asarray(slots)
 
     def keys_for_slots(self, slots: np.ndarray) -> list[int | None]:
         return [

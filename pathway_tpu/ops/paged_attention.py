@@ -12,8 +12,8 @@ the KV block index_map reads it, so grid step (b, j) stages sequence
 b's j-th logical page (one [H, P, Dp] tile) into VMEM without ever
 materializing a gathered [B, L, H, Dp] tensor in HBM.
 
-Layout honors the Mosaic (8, 128) tiling rule the same way pallas_topk
-does (a kernel that passes in interpret mode has not thereby lowered):
+Layout honors the Mosaic (8, 128) tiling rule (a kernel that passes in
+interpret mode has not thereby lowered):
 
 * pools are ``[n_pages, H, P, Dp]`` with ``Dp = head_dim`` padded up to
   a 128-lane multiple (``lane_pad``); the padded tail lanes are zero in
@@ -105,7 +105,7 @@ def validate_lowering(
     b: int, h: int, p: int, dp: int, n_pages: int, max_pages: int
 ) -> None:
     """Assert every block spec the kernel will use satisfies the Mosaic
-    TPU rule — the compiled-mode test gate (pallas_topk precedent)."""
+    TPU rule — the compiled-mode test gate."""
     if dp % 128 != 0:
         raise LoweringRuleViolation(
             RULE_LANE_PAD,

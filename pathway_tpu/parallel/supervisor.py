@@ -22,7 +22,7 @@ Usage::
     python -m pathway_tpu.parallel.supervisor -n 2 -- python job.py
     pathway-tpu spawn -n 2 --supervise -- python job.py
 
-or programmatically (tests, bench.py chaos_recovery)::
+or programmatically (the chaos tests)::
 
     sup = GroupSupervisor(["python", "job.py"], n=2, env=extra_env)
     rc = sup.run()

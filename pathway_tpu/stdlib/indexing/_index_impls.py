@@ -10,9 +10,7 @@ Replaces the reference's native index family (src/external_integration/):
 
 from __future__ import annotations
 
-import functools
 import math
-import os
 import re
 from collections import defaultdict
 from typing import Any, Sequence
@@ -21,12 +19,7 @@ import numpy as np
 
 from pathway_tpu.engine.index_node import QUERY_DATA_ERRORS
 from pathway_tpu.observability.tracing import get_tracer
-from pathway_tpu.ops.knn import (
-    DeviceCorpus,
-    dense_topk_prepared,
-    sharded_topk,
-    topk_stage1,
-)
+from pathway_tpu.ops.knn import DeviceCorpus
 from pathway_tpu.stdlib.indexing._filters import compile_filter
 
 
@@ -46,7 +39,6 @@ class TpuDenseKnnIndex:
         reserved_space: int = 1024,
         mesh: Any = None,
         axis: str = "data",
-        kernel: str = "auto",
     ):
         self.dim = dimensions
         self.metric = metric
@@ -55,25 +47,6 @@ class TpuDenseKnnIndex:
         self.axis = axis
         self.corpus: DeviceCorpus | None = None
         self.metadata: dict[int, Any] = {}
-        # scoring kernel: "xla" = dense_topk_prepared; "pallas" = the fused
-        # Pallas block-top-k (ops/pallas_topk.py — only [B, nblk*k]
-        # candidates return to HBM instead of the [B, N] score matrix).
-        # "auto" follows PATHWAY_KNN_KERNEL, defaulting to xla.
-        if kernel == "auto":
-            kernel = os.environ.get("PATHWAY_KNN_KERNEL", "xla")
-        if kernel not in ("xla", "pallas"):
-            raise ValueError(f"unknown KNN kernel {kernel!r}")
-        self.kernel = kernel
-        # Surge Gate shape ladder: pad the query-batch dim to the next
-        # power of two so the jitted top-k compiles once per bucket
-        # instead of once per distinct concurrent-query count (the same
-        # contract the encoder applies to embed batches).
-        # PATHWAY_SERVING_SHAPE_LADDER=0 restores the seed's exact-shape
-        # behavior (bench.py sets it, pre-build, for its unbatched
-        # baseline phase). Resolved here — search() is the hot path.
-        self.shape_ladder = (
-            os.environ.get("PATHWAY_SERVING_SHAPE_LADDER", "1") != "0"
-        )
         self._m_occupancy: dict[int, Any] = {}  # labeled child per bucket
 
     def _ensure(self, dim: int) -> DeviceCorpus:
@@ -126,8 +99,6 @@ class TpuDenseKnnIndex:
             self.metadata = {k: v for k, v in self.metadata.items() if pred(k)}
             return
         kept = [(k, s) for k, s in c.slot_of.items() if pred(k)]
-        from pathway_tpu.ops.knn import DeviceCorpus
-
         fresh = DeviceCorpus(
             c.dim,
             max(len(kept), 1),
@@ -186,80 +157,6 @@ class TpuDenseKnnIndex:
             for key, slot in cs["slot_of"].items():
                 c.upsert(key, cs["host"][slot])
 
-    def _device_topk(
-        self, qmat: np.ndarray, eff_k: int
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """The device half of a search: (scores, slot indices) of the
-        top ``eff_k`` rows per query, on the host.  The kernel is picked
-        from the configuration and the shapes; a kernel the compiler
-        refuses raises — nothing here retries on another implementation.
-
-        The corpus arrays are fetched first (changed rows are handed over
-        and scattered into them there, under spans of its own), so the
-        ``index.topk`` span holds the kernel and the transfer of its
-        results and nothing of the refresh; its ``stage1`` says how the
-        XLA top-k starts at this call's shapes (``ops/knn.topk_stage1`` of
-        the rows a device scans and ``eff_k``; the Pallas kernel has a
-        first stage of its own). The arrays are held for this call only:
-        the corpus donates them to its next refresh."""
-        if self.mesh is not None:
-            corpus_arr, valid = self.corpus.device_arrays()
-            local_rows = corpus_arr.shape[0] // self.mesh.shape[self.axis]
-            attrs = {
-                "kernel": "sharded",
-                "stage1": topk_stage1(local_rows, min(eff_k, local_rows)),
-            }
-            run = functools.partial(
-                sharded_topk,
-                qmat,
-                corpus_arr,
-                valid,
-                eff_k,
-                mesh=self.mesh,
-                axis=self.axis,
-                metric=self.metric,
-            )
-        else:
-            # float32 rows — but on a TPU a float32 matmul at default
-            # precision multiplies in bf16, in XLA and in the Pallas
-            # kernel alike (measured on a v5e, PR 21: score error 3.5e-4,
-            # recall@10 0.986 vs exact float32 on gaussian rows; "highest"
-            # precision gives 7.5e-8 / 1.0). The ids are the contract;
-            # scores carry about three digits there.
-            prep, c2, valid = self.corpus.prepared_arrays(
-                self.metric, bf16=False
-            )
-            attrs = {
-                "kernel": "xla",
-                "stage1": topk_stage1(prep.shape[0], eff_k),
-            }
-            run = functools.partial(
-                dense_topk_prepared,
-                qmat,
-                prep,
-                c2,
-                valid,
-                eff_k,
-                metric=self.metric,
-                bf16=False,
-            )
-            if self.kernel == "pallas" and self.metric in ("cosine", "dot"):
-                from pathway_tpu.ops import pallas_topk as pt
-
-                if pt.supported(prep.shape[0], eff_k):
-                    attrs = {"kernel": "pallas"}
-                    run = functools.partial(
-                        pt.pallas_dense_topk,
-                        qmat,
-                        prep,
-                        valid,
-                        eff_k,
-                        metric=self.metric,
-                    )
-        with get_tracer().span("index.topk", **attrs):
-            scores, idx = run()
-            return np.asarray(scores), np.asarray(idx)
-
     def search(self, queries: Sequence[tuple[Any, int, Any]]):
         if self.corpus is None or len(self.corpus) == 0 or not queries:
             return [() for _ in queries]
@@ -279,19 +176,21 @@ class TpuDenseKnnIndex:
                 f"query vectors of shape {qmat.shape[1:]} against a "
                 f"{self.corpus.dim}-dimensional corpus"
             )
+        # the query batch is padded to the next power of two, so the
+        # jitted top-k compiles once per bucket and not once per distinct
+        # number of concurrent queries (the encoder does the same to its
+        # batches)
         n_q = qmat.shape[0]
-        bucket = n_q
-        if self.shape_ladder:
-            bucket = 1 << max(0, n_q - 1).bit_length()
-            if bucket != n_q:
-                qmat = np.pad(qmat, ((0, bucket - n_q), (0, 0)))
-            child = self._m_occupancy.get(bucket)
-            if child is None:
-                from pathway_tpu.serving.metrics import occupancy_histogram
+        bucket = 1 << max(0, n_q - 1).bit_length()
+        if bucket != n_q:
+            qmat = np.pad(qmat, ((0, bucket - n_q), (0, 0)))
+        child = self._m_occupancy.get(bucket)
+        if child is None:
+            from pathway_tpu.serving.metrics import occupancy_histogram
 
-                child = occupancy_histogram().labels("knn", str(bucket))
-                self._m_occupancy[bucket] = child
-            child.observe(n_q / bucket)
+            child = occupancy_histogram().labels("knn", str(bucket))
+            self._m_occupancy[bucket] = child
+        child.observe(n_q / bucket)
         max_k = max(int(k) for _q, k, _f in queries)
         has_filter = any(f is not None for _q, _k, f in queries)
         # oversample when filtering so post-filter still fills k
@@ -301,7 +200,7 @@ class TpuDenseKnnIndex:
         span.set_attribute("bucket", bucket)
         span.set_attribute("k", eff_k)
         try:
-            scores, idx = self._device_topk(qmat, eff_k)
+            scores, idx = self.corpus.topk(qmat, eff_k, self.metric)
         except QUERY_DATA_ERRORS as exc:
             # the inputs passed the host checks above: a shape or
             # lowering complaint from here on is the device program's,

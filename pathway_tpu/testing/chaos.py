@@ -1,12 +1,12 @@
-"""Shared helpers for chaos/recovery harnesses (tests + bench).
+"""Shared helpers for the chaos/recovery tests.
 
-The kill/restart matrix in ``tests/test_distributed.py`` and the
-``bench.py chaos_recovery`` tier drive the same shape of experiment: a
+The kill/restart matrix in ``tests/test_distributed.py`` and the resize
+tests in ``tests/test_elastic.py`` drive the same shape of experiment: a
 multi-process DCN group writing jsonlines diff streams whose FOLDED
 state must converge on the uninterrupted run's totals.  The folding
 rules (``diff > 0`` installs a key's value, ``diff < 0`` removes it only
 when it matches — a rewound incarnation may re-emit retractions the fold
-must tolerate) and the mesh port probing are shared here so the two
+must tolerate) and the mesh port probing are shared here so the
 harnesses cannot drift.
 """
 
@@ -17,9 +17,8 @@ import random
 import socket
 import textwrap
 
-# Replica Shield writer role, shared by the test chaos matrix
-# (tests/test_distributed.py) and the `bench.py serve_chaos` tier so the
-# two harnesses drive the SAME pipeline: streaming jsonlines docs ->
+# Replica Shield writer role of the test chaos matrix
+# (tests/test_distributed.py): streaming jsonlines docs ->
 # deterministic pseudo-embedding -> TpuKnn external index (+ an empty
 # query stream), persistence snapshots, and the PATHWAY_REPL_PORT delta
 # publisher.  Env contract: PW_WRITER_DIR (base dir with docs/ and q/
@@ -98,9 +97,8 @@ REPL_WRITER_SCRIPT = textwrap.dedent(
 )
 
 
-# Shard Flux mesh-resize worker, shared by tests/test_elastic.py and
-# the `bench.py reshard_live` tier: a supervised jsonlines→groupby rank
-# with a per-rank input dir + per-rank store, per-tick snapshots (so a
+# Shard Flux mesh-resize worker of tests/test_elastic.py: a supervised
+# jsonlines→groupby rank with a per-rank input dir + per-rank store, per-tick snapshots (so a
 # resize cut is always snapshot-covered once input quiesces), and a
 # REPLAYED line on exit — the zero-replay evidence the resize
 # acceptance reads.  Env contract: PW_TEST_DIR (holds in<pid>/ dirs; a

@@ -2,9 +2,8 @@
 
 The reference exercises its persistence/recovery guarantees with
 integration tests that kill whole worker groups mid-run (reference:
-integration_tests/wordcount); Fault Forge makes that style of test (and
-the ``bench.py chaos_recovery`` tier) deterministic and scriptable: a
-single ``PATHWAY_FAULTS`` spec arms a small set of hooks baked into the
+integration_tests/wordcount); Fault Forge makes that style of test
+deterministic and scriptable: a single ``PATHWAY_FAULTS`` spec arms a small set of hooks baked into the
 hot paths, each of which is a no-op (one cached ``None`` check) when the
 variable is unset.
 

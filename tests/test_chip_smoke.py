@@ -221,13 +221,13 @@ def test_malformed_query_is_a_recorded_data_error():
 
 
 def test_device_search_failure_fails_the_tick(monkeypatch):
-    from pathway_tpu.stdlib.indexing._index_impls import TpuDenseKnnIndex
+    from pathway_tpu.ops.knn import DeviceCorpus
 
-    def refused(self, qmat, eff_k):
-        # what a block-spec complaint from the Pallas lowering looks like
+    def refused(self, queries, k, metric):
+        # what a shape complaint from the device program's lowering looks like
         raise ValueError("block shape is not divisible by (8, 128)")
 
-    monkeypatch.setattr(TpuDenseKnnIndex, "_device_topk", refused)
+    monkeypatch.setattr(DeviceCorpus, "topk", refused)
     with pytest.raises(RuntimeError, match="device top-k failed"):
         _knn_pipeline(query_dim=4)
 

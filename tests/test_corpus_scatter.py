@@ -219,17 +219,20 @@ def _mesh():
     return Mesh(np.array(jax.devices()), ("data",))
 
 
-def test_sharded_corpus_keeps_its_sharding_and_contents_after_a_scatter():
+def _shardings(mesh):
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    mesh = _mesh()
-    assert mesh.shape["data"] == 8
-    shardings = dict(
+    return dict(
         sharding=NamedSharding(mesh, P("data", None)), valid_sharding=NamedSharding(mesh, P("data"))
     )
+
+
+def test_sharded_corpus_keeps_its_sharding_and_contents_after_a_scatter():
+    mesh = _mesh()
+    assert mesh.shape["data"] == 8
     rng = np.random.default_rng(9)
-    corpus = _loaded(rng, **shardings)
+    corpus = _loaded(rng, **_shardings(mesh))
     corpus.prepared_arrays("cosine", False)
     tracing.get_tracer().clear()
     new_keys(corpus, rng)
@@ -242,6 +245,52 @@ def test_sharded_corpus_keeps_its_sharding_and_contents_after_a_scatter():
     np.testing.assert_array_equal(np.asarray(device), corpus.host)
     np.testing.assert_array_equal(np.asarray(valid), corpus.valid_host)
     _assert_agrees_with_whole_upload(corpus, [("cosine", False)])
+
+
+# --- the corpus's own search -------------------------------------------------
+
+
+def _exact_scores(queries, corpus, metric):
+    """Float32 numpy over the mirror, bigger is closer; free slots never win."""
+    rows = corpus.host
+    if metric == "cosine":
+        queries = queries / np.linalg.norm(queries, axis=1, keepdims=True)
+        rows = rows / (np.linalg.norm(rows, axis=1, keepdims=True) + 1e-30)
+    scores = queries @ rows.T
+    if metric == "l2sq":
+        scores = -((queries**2).sum(1)[:, None] - 2.0 * scores + (rows**2).sum(1)[None, :])
+    scores[:, ~corpus.valid_host] = -np.inf
+    return scores
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "sharded"])
+@pytest.mark.parametrize("metric", ["cosine", "dot", "l2sq"])
+def test_corpus_topk_is_the_brute_force_topk_of_the_mirror(metric, mesh):
+    rng = np.random.default_rng(12)
+    corpus = _loaded(rng, **(_shardings(_mesh()) if mesh else {}))
+    queries, k = _rows(rng, 4), 5
+    corpus.topk(queries, k, metric)  # the first search uploads the whole mirror
+    tracing.get_tracer().clear()
+    new_keys(corpus, rng)
+    remove(corpus, rng)
+    scores, slots = corpus.topk(queries, k, metric)
+    assert [a["full"] for a in _upload_spans()] == [0]  # the refresh ran inside the call
+    assert scores.shape == slots.shape == (4, k)
+    exact = _exact_scores(queries, corpus, metric)
+    want_slots = np.argsort(-exact, axis=1, kind="stable")[:, :k]
+    want_scores = np.take_along_axis(exact, want_slots, axis=1)
+    # float32 on one CPU device; a mesh multiplies in bf16 (8 bits of mantissa a factor)
+    scale = 1.0 if metric == "cosine" else float(
+        np.linalg.norm(queries, axis=1).max() * np.linalg.norm(corpus.host, axis=1).max()
+    )
+    tol = (2.0**-6 if mesh else 1e-5) * scale
+    np.testing.assert_allclose(scores, want_scores, rtol=0, atol=tol)
+    # the rows returned are the best k to that precision, and exactly those at float32
+    np.testing.assert_allclose(
+        np.take_along_axis(exact, slots, axis=1), want_scores, rtol=0, atol=tol
+    )
+    if not mesh:
+        np.testing.assert_array_equal(slots, want_slots)
 
 
 # --- through the index: read your writes -----------------------------------
@@ -296,3 +345,32 @@ def test_load_state_uploads_the_restored_mirror_whole_and_scatters_after():
     assert _ids(restored, vectors[2], k=2)[0] == 2 and 500 not in _ids(restored, vectors[2], k=64)
     assert _upload_spans()[-1]["full"] == 0
     _assert_agrees_with_whole_upload(restored.corpus, [("cosine", False)])
+
+
+def test_the_index_takes_no_kernel_argument():
+    with pytest.raises(TypeError):
+        TpuDenseKnnIndex(DIM, "cosine", kernel="xla")
+
+
+def test_three_queries_reach_the_device_as_four_whatever_the_environment(monkeypatch):
+    """The two variables that once chose the program and switched the pad
+    ladder off are dead, not defaulted (spelled in halves: a grep for
+    them finds nothing in the tree)."""
+    monkeypatch.setenv("PATHWAY_KNN_" + "KERNEL", "pallas")
+    monkeypatch.setenv("PATHWAY_SERVING_SHAPE_" + "LADDER", "0")
+    seen = []
+    dense_topk_prepared = knn.dense_topk_prepared
+
+    def spy(queries, *args, **kwargs):
+        seen.append(queries.shape)
+        return dense_topk_prepared(queries, *args, **kwargs)
+
+    monkeypatch.setattr(knn, "dense_topk_prepared", spy)
+    rng = np.random.default_rng(13)
+    index, vectors = _index(rng)
+    tracing.get_tracer().clear()
+    found = index.search([(vector, 1, None) for vector in vectors[:3]])
+    assert [matches[0][0] for matches in found] == [0, 1, 2]
+    assert seen == [(4, DIM)]
+    (topk,) = [r for r in tracing.get_tracer().spans() if r.name == "index.topk"]
+    assert topk.attributes["kernel"] == "xla"

@@ -1043,7 +1043,7 @@ def test_cli_plane_proves_all_families_and_writes_manifest(tmp_path):
     assert doc["findings"] == []
     cases = doc["lowering"]["cases"]
     families = {c["family"] for c in cases}
-    assert families >= {"pallas_topk", "paged_attention", "tick_forge"}
+    assert families >= {"paged_attention", "tick_forge"}
     assert all(
         c["status"] in ("lowered", "rejected") for c in cases
     ), cases
@@ -1076,22 +1076,6 @@ def test_cli_plane_fails_suite_on_unpadded_shape(tmp_path):
     assert finding["data"]["family"] == "paged_attention"
     assert finding["data"]["shape"]["head_dim"] == 129
     assert finding["data"]["rule"] == "lane-pad"
-
-    # same for an un-lane-padded raw top-k tile
-    res = _run_cli_plane(
-        "--plane",
-        "--json",
-        "--manifest",
-        "none",
-        "--prove-shape",
-        "pallas_topk:k=10,pad=0",
-    )
-    assert res.returncode == 1, res.stdout + res.stderr
-    doc = json.loads(res.stdout)
-    assert any(
-        f["data"].get("rule") == "mosaic-8x128"
-        for f in doc["findings"]
-    )
 
 
 def test_cli_plane_env_findings_and_knob_snapshot(tmp_path):
@@ -1132,8 +1116,8 @@ def test_cli_plane_with_script_runs_both_scopes():
     assert "dead-node" in rules_hit or "dead-column" in rules_hit
     assert doc["lowering"] is not None
     assert {c["family"] for c in doc["lowering"]["cases"]} >= {
-        "pallas_topk",
         "paged_attention",
+        "tick_forge",
     }
 
 

@@ -22,9 +22,19 @@ def test_lane_pad_ladder():
     assert L.lane_pad(256) == 256
 
 
-def test_block_rule_violation_carries_rule_id():
+@pytest.mark.parametrize(
+    "block, array",
+    [
+        ((8, 10), (8, 20)),
+        # an unpadded k tile: middle dim 1 vs 977
+        ((1, 1, 10), (1, 977, 10)),
+        # a lane dim neither 128-aligned nor equal to the array's
+        ((8, 10), (8, 2048)),
+    ],
+)
+def test_block_rule_violation_carries_rule_id(block, array):
     with pytest.raises(L.LoweringRuleViolation) as ei:
-        L.check_tpu_block_rules((8, 10), (8, 20))
+        L.check_tpu_block_rules(block, array)
     assert ei.value.rule == L.RULE_8X128
     # stays a ValueError so pre-existing gates keep working
     assert isinstance(ei.value, ValueError)
@@ -34,12 +44,10 @@ def test_block_rule_violation_carries_rule_id():
 
 def test_gate_is_single_source_of_truth():
     from pathway_tpu.ops import paged_attention as pa
-    from pathway_tpu.ops import pallas_topk as pt
 
-    assert pt.check_tpu_block_rules is L.check_tpu_block_rules
     assert pa.check_tpu_block_rules is L.check_tpu_block_rules
+    assert pa.check_block_specs is L.check_block_specs
     assert pa.lane_pad is L.lane_pad
-    assert pt._kpad(10) == L.lane_pad(10)
 
 
 def test_estimate_vmem_double_buffers_blocks():
@@ -54,7 +62,7 @@ def test_parse_shape_spec():
     fam, shape = L.parse_shape_spec("paged_attention:head_dim=129,b=4")
     assert fam == "paged_attention"
     assert shape == {"head_dim": 129, "b": 4}
-    assert L.parse_shape_spec("pallas_topk") == ("pallas_topk", {})
+    assert L.parse_shape_spec("paged_attention") == ("paged_attention", {})
     with pytest.raises(ValueError):
         L.parse_shape_spec("fam:k")
     with pytest.raises(ValueError):
@@ -66,19 +74,24 @@ def test_parse_shape_spec():
 # --- the prover ------------------------------------------------------------
 
 
-def test_prover_topk_family_lowers_pad_ladder():
-    rep = L.prove_lowering(families=["pallas_topk"], include_live=False)
+def test_prover_paged_attention_family_lowers_pad_ladder():
+    rep = L.prove_lowering(
+        families=["paged_attention"], include_live=False
+    )
     assert not rep.findings, [f.message for f in rep.findings]
     lowered = rep.by_status("lowered")
-    # pad ladder incl. k=10 (not lane-aligned: forces the pad)
-    assert {e["case"] for e in lowered} >= {"b8_d128_n2048_k10"}
+    # the lane-padded rungs of the head_dim ladder
+    assert {e["case"] for e in lowered} >= {
+        "b8_h4_p16_dp128",
+        "b4_h8_p8_dp256",
+    }
     for e in lowered:
         assert len(e["stablehlo_sha256"]) == 64
         assert e["mlir_bytes"] > 0
         assert 0 < e["vmem_frac"] <= 1
-    # and the raw un-lane-padded tile stays rejected by the gate
+    # and the un-lane-padded widths stay rejected by the gate
     rejected = rep.by_status("rejected")
-    assert rejected and rejected[0]["rule"] == L.RULE_8X128
+    assert rejected and rejected[0]["rule"] == L.RULE_LANE_PAD
 
 
 def test_prover_paged_attention_rejects_bad_head_dims():
@@ -160,8 +173,12 @@ def test_unknown_family_raises():
 
 
 def test_manifest_is_content_addressed(tmp_path):
-    rep1 = L.prove_lowering(families=["pallas_topk"], include_live=False)
-    rep2 = L.prove_lowering(families=["pallas_topk"], include_live=False)
+    rep1 = L.prove_lowering(
+        families=["paged_attention"], include_live=False
+    )
+    rep2 = L.prove_lowering(
+        families=["paged_attention"], include_live=False
+    )
     m1, m2 = rep1.to_manifest(), rep2.to_manifest()
     # deterministic: same cases -> same content hash
     assert m1["content_sha256"] == m2["content_sha256"]

@@ -1,5 +1,5 @@
 """Ragged paged-attention kernel: interpret-mode shape pins + the
-decode-vs-pure-JAX-twin differential (the pallas_topk k-pad pattern
+decode-vs-pure-JAX-twin differential (the lane-pad pattern
 applied to the generation plane's kernel — interpret-green is not
 lowerable-green, so the static 8x128 gate runs on every shape the
 decoder will emit)."""
@@ -78,8 +78,7 @@ def test_ragged_boundary_lengths():
 
 
 def test_lane_pad_boundaries():
-    """The lane ladder's edges (the pallas_topk _kpad pins, applied to
-    head_dim)."""
+    """The lane ladder's edges, applied to head_dim."""
     from pathway_tpu.ops.paged_attention import lane_pad
 
     assert lane_pad(1) == 128
@@ -101,7 +100,7 @@ def test_lowering_gate_rejects_unpadded_head_dim():
     with pytest.raises(ValueError, match="lane-padded"):
         pa.validate_lowering(8, 4, 16, 32, 64, 16)
     # and the shared rule checker still rejects a bad block outright
-    from pathway_tpu.ops.pallas_topk import check_tpu_block_rules
+    from pathway_tpu.analysis.lowering import check_tpu_block_rules
 
     with pytest.raises(ValueError):
         check_tpu_block_rules((1, 4, 7, 128), (16, 4, 16, 128))

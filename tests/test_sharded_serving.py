@@ -13,8 +13,7 @@ Covers the acceptance bars in-process and fast (tier-1):
 * writer-kill → standby takeover handoff with incarnation fencing of a
   zombie primary.
 
-The heavy multi-process legs live in ``bench.py serve_chaos`` (shard ×
-replica sweep + SIGKILL takeover, SERVE_r11.json).
+The multi-process kill/restart matrix is ``tests/test_distributed.py``'s.
 """
 
 import json
