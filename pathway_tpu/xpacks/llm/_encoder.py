@@ -8,7 +8,7 @@ pad-bucket, batch-sharded over the mesh 'data' axis for multi-chip DP).
 from __future__ import annotations
 
 import functools
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -73,9 +73,12 @@ def _bucket_batch(n: int) -> int:
 
 
 class EncoderRuntime:
-    """Owns params + jitted forwards; pads batches to power-of-two buckets so
-    each (batch, seq) bucket compiles once. Optional mesh → batch-dim DP
-    sharding (multi-chip embedding throughput)."""
+    """Owns params + one jitted forward, which compiles once per (batch,
+    seq) shape it is handed. ``dispatch`` pads the batch dimension to a
+    power-of-two bucket and forwards the sequence dimension as given: which
+    rows ride together at which length is the caller's plan
+    (``embedders.length_groups``). Optional mesh → batch-dim DP sharding
+    (multi-chip embedding throughput)."""
 
     def __init__(
         self,
@@ -151,8 +154,13 @@ class EncoderRuntime:
             bucket = ((bucket + n_dev - 1) // n_dev) * n_dev
         return bucket
 
-    def forward(self, ids: np.ndarray, mask: np.ndarray) -> tuple[np.ndarray, dict]:
-        """Vectors [n, dim] and the shape that was really forwarded."""
+    def dispatch(
+        self, ids: np.ndarray, mask: np.ndarray
+    ) -> Callable[[], tuple[np.ndarray, dict]]:
+        """Starts the forward of one padded batch and returns the call that
+        waits for it: vectors [n, dim] and the shape that was really
+        forwarded. Several batches dispatched before the first is fetched
+        queue on the device and cost one wait, not one each."""
         n = ids.shape[0]
         bucket = self.batch_bucket(n)
         if bucket != n:
@@ -164,12 +172,17 @@ class EncoderRuntime:
             ids_j = jax.device_put(ids_j, self._in_shard)
             mask_j = jax.device_put(mask_j, self._in_shard)
         out = self._fwd(self.params, ids_j, mask_j)
+        out.copy_to_host_async()
         info = {
             "batch_bucket": bucket,
             "len_bucket": int(ids.shape[1]),
             "tokens_padded": int(ids.size),
         }
-        return np.asarray(out)[:n], info
+        return lambda: (np.asarray(out)[:n], info)
+
+    def forward(self, ids: np.ndarray, mask: np.ndarray) -> tuple[np.ndarray, dict]:
+        """``dispatch``, waited for."""
+        return self.dispatch(ids, mask)()
 
     def forward_ids(self, ids: np.ndarray, mask: np.ndarray) -> np.ndarray:
         return self.forward(ids, mask)[0]

@@ -43,8 +43,12 @@ class HashingTokenizer:
     def encode_batch(
         self, texts: Sequence[str], max_len: int
     ) -> tuple[np.ndarray, np.ndarray]:
-        """Returns (ids [B, L], mask [B, L]) padded to the smallest
-        power-of-two-ish bucket ≥ longest sequence (static shapes for jit)."""
+        """Returns (ids [B, L], mask [B, L]) padded to the smallest rung of
+        the length ladder (``_bucket_len``) ≥ the longest sequence. That is
+        the widest shape a forward of these texts needs, not the shape every
+        text is forwarded at: the embedder reads the real lengths off the
+        mask and may forward column slices ``[:, :rung]`` of length-sorted
+        groups (``embedders.length_groups``)."""
         encoded = [self.encode(t, max_len) for t in texts]
         longest = max((len(e) for e in encoded), default=1)
         bucket = _bucket_len(longest, max_len)

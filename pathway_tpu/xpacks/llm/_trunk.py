@@ -608,39 +608,52 @@ class TrunkRuntime:
     def batch_bucket(self, n: int) -> int:
         return _bucket_batch(n)
 
-    def forward(
+    def dispatch(
         self, ids: np.ndarray, mask: np.ndarray, routing: bool = False
-    ) -> tuple[np.ndarray, dict]:
-        """Vectors [n, dim] and what was really forwarded: the padded shape
-        and the expert layers' row counts. ``routing=True`` adds
-        ``expert_choice`` [expert layers, n, T, k], the experts each token
-        went to (-1: nowhere); it stays on the device unless asked for."""
+    ) -> Callable[[], tuple[np.ndarray, dict]]:
+        """Starts the forward of one padded batch and returns the call that
+        waits for it (``EncoderRuntime.dispatch``): vectors [n, dim] and what
+        was really forwarded, the padded shape and the expert layers' row
+        counts. ``routing=True`` adds ``expert_choice`` [expert layers, n, T,
+        k], the experts each token went to (-1: nowhere); it stays on the
+        device unless asked for."""
         n = ids.shape[0]
         bucket = self.batch_bucket(n)
         if bucket != n:
             ids = np.pad(ids, ((0, bucket - n), (0, 0)))
             mask = np.pad(mask, ((0, bucket - n), (0, 0)))
         out, counts, choice = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))
+        out.copy_to_host_async()
         info = {
             "batch_bucket": bucket,
             "len_bucket": int(ids.shape[1]),
             "tokens_padded": int(ids.size),
             "trunk": self.config.name,
         }
-        counts = np.asarray(counts)
-        if counts.size:  # the trunk has expert layers
-            first, held = self.config.held
-            info.update(
-                expert_rows_useful=int(counts[:, first : first + held].sum()),
-                expert_rows_computed=sum(
-                    moe.rows_computed(layer[first : first + held]) for layer in counts
-                ),
-                expert_tokens_max=int(counts.max()),
-                expert_tokens_mean=float(counts.mean()),
-            )
-        if routing:
-            info["expert_choice"] = np.asarray(choice)[:, :n]
-        return np.asarray(out)[:n], info
+
+        def fetch() -> tuple[np.ndarray, dict]:
+            rows = np.asarray(counts)
+            if rows.size:  # the trunk has expert layers
+                first, held = self.config.held
+                info.update(
+                    expert_rows_useful=int(rows[:, first : first + held].sum()),
+                    expert_rows_computed=sum(
+                        moe.rows_computed(layer[first : first + held]) for layer in rows
+                    ),
+                    expert_tokens_max=int(rows.max()),
+                    expert_tokens_mean=float(rows.mean()),
+                )
+            if routing:
+                info["expert_choice"] = np.asarray(choice)[:, :n]
+            return np.asarray(out)[:n], info
+
+        return fetch
+
+    def forward(
+        self, ids: np.ndarray, mask: np.ndarray, routing: bool = False
+    ) -> tuple[np.ndarray, dict]:
+        """``dispatch``, waited for."""
+        return self.dispatch(ids, mask, routing)()
 
     def forward_ids(self, ids: np.ndarray, mask: np.ndarray) -> np.ndarray:
         return self.forward(ids, mask)[0]

@@ -2,9 +2,10 @@
 OpenAIEmbedder:85, LiteLLMEmbedder:180, SentenceTransformerEmbedder:270,
 GeminiEmbedder:330).
 
-The local embedder is TPU-native: a flax encoder jitted per pad-bucket
+The local embedder is TPU-native: a flax encoder jitted per padded shape
 (`pathway_tpu/xpacks/llm/_encoder.py`), fed whole ticks at once through the
-engine's batched-UDF path — this is the BASELINE.md "embed docs/sec/chip"
+engine's batched-UDF path and forwarding each in length-sorted groups where
+the lengths differ — this is the BASELINE.md "embed docs/sec/chip"
 configuration. API embedders (OpenAI/LiteLLM/Gemini) keep the reference
 surface and degrade with a clear error when the client lib / network is
 unavailable."""
@@ -18,6 +19,85 @@ import numpy as np
 
 from pathway_tpu.internals import expression as expr_mod
 from pathway_tpu.internals.udfs import UDF
+from pathway_tpu.xpacks.llm._tokenizer import _bucket_len
+
+# Texts a length-sorted group of one embed batch holds; a multiple of the
+# batch ladder's floor. Fixed from chip runs at 16, 32 and 64 (PERF.md
+# section 6, PR 31): the code decides from the lengths it is handed whether
+# to group at all, so nobody has to tune this.
+_GROUP = 32
+
+# what a batch's forwards report that adds up over them; the rest (bucket
+# sizes, an expert's largest load) is the largest forwarded
+_ADDED = ("tokens_padded", "expert_rows_useful", "expert_rows_computed")
+
+
+def length_groups(lengths, width: int, max_len: int, batch_bucket):
+    """The plan of one embed batch: which rows ride together, at which rung.
+
+    ``lengths`` are the texts' real token counts, ``width`` the rung the whole
+    batch was padded to (its longest text's), ``batch_bucket`` the runtime's
+    padding of a count. The rows, sorted by length, are cut into groups of
+    ``_GROUP`` (a remainder rides at the short end, where its padding rows
+    cost least), each on the rung of its own longest member: ``[(rows,
+    rung), ...]``. ``None`` says forward the batch whole: it is one group
+    anyway, or the plan's padded positions are not under three quarters of
+    the whole batch's, and a split would pay per forward (a launch, a read
+    of the weights) for nothing."""
+    n = len(lengths)
+    if n <= _GROUP:
+        return None
+    order = np.argsort(lengths, kind="stable")
+    groups, start = [], 0
+    for end in range(n % _GROUP or _GROUP, n + 1, _GROUP):
+        rows = order[start:end]
+        groups.append((rows, min(_bucket_len(int(lengths[rows[-1]]), max_len), width)))
+        start = end
+    planned = batch_bucket(_GROUP) * sum(rung for _, rung in groups)
+    if 4 * planned >= 3 * batch_bucket(n) * width:
+        return None
+    return groups
+
+
+def _forward_groups(runtime, ids, mask, plan, built: set[int]) -> tuple[np.ndarray, list[tuple[int, dict]]]:
+    """Forwards a planned batch group by group, every forward dispatched
+    before the first result is fetched: vectors in the rows' own order, and
+    each group's (texts, what the runtime forwarded).
+
+    Every group rides at batch shape ``_GROUP``, so the shapes are ``_GROUP``
+    x the length ladder. Which rungs a batch's groups land on differs from
+    batch to batch, so the first call that reaches a rung builds every rung
+    below it as well (``built``): a later batch compiles nothing."""
+    top = max(rung for _, rung in plan)
+    if top not in built:
+        ladder = {min(_bucket_len(t, runtime.max_len), top) for t in range(1, top + 1)}
+        for rung in sorted(ladder - built):
+            runtime.dispatch(np.zeros((_GROUP, rung), np.int32), np.ones((_GROUP, rung), np.float32))
+            built.add(rung)
+    pending = []
+    for rows, rung in plan:
+        group_ids, group_mask = ids[rows, :rung], mask[rows, :rung]
+        if len(rows) < _GROUP:  # the remainder: padded, or its shape would be a new one
+            pad = ((0, _GROUP - len(rows)), (0, 0))
+            group_ids, group_mask = np.pad(group_ids, pad), np.pad(group_mask, pad)
+        pending.append(runtime.dispatch(group_ids, group_mask))
+    results = [fetch() for fetch in pending]
+    first = results[0][0]
+    out = np.empty((len(ids),) + first.shape[1:], first.dtype)
+    for (rows, _), (vectors, _info) in zip(plan, results):
+        out[rows] = vectors[: len(rows)]
+    return out, [(len(rows), info) for (rows, _), (_, info) in zip(plan, results)]
+
+
+def _summed(infos: list[dict]) -> dict:
+    """What one call forwarded, from what each of its forwards reports:
+    ``_ADDED`` add up, the rest is the largest (a trunk's name is the same
+    in each)."""
+    total = dict(infos[0])
+    for info in infos[1:]:
+        for key, value in info.items():
+            total[key] = total[key] + value if key in _ADDED else max(total[key], value)
+    return total
 
 
 class BaseEmbedder(UDF):
@@ -44,7 +124,14 @@ class SentenceTransformerEmbedder(BaseEmbedder):
     ``config.json``) runs a decoder-style trunk from ``_trunk.py``'s layer
     table in the encoder's place: sparse experts, latent attention, a
     multi-stream residual; causal, pooled at the last real token. Same ``embed_batch``, tokenizer resolution, pad ladder
-    and spans; ``dim``/``depth``/``heads`` are then the config's."""
+    and spans; ``dim``/``depth``/``heads`` are then the config's.
+
+    The pad ladder: a batch is padded to its longest text's length rung
+    (16, 32, 64, ... ``max_len``) and its count to a power of two from 8.
+    A batch of more than ``_GROUP`` texts whose lengths differ enough is
+    forwarded in length-sorted groups of ``_GROUP`` instead, each on its
+    own longest member's rung (``length_groups``); the vectors are the same
+    and come back in the caller's order."""
 
     def __init__(
         self,
@@ -176,6 +263,7 @@ class SentenceTransformerEmbedder(BaseEmbedder):
 
         m_occupancy = occupancy_histogram()
         _tracer = get_tracer()
+        built: set[int] = set()  # the group rungs this embedder's forward is compiled for
 
         def embed_batch(texts: Sequence[str]) -> list[np.ndarray]:
             import time as _time
@@ -195,26 +283,36 @@ class SentenceTransformerEmbedder(BaseEmbedder):
                         # position ids
                         [str(t) for t in texts], self.runtime.max_len
                     )
-                    tokens_real = int(mask.sum())
+                    lengths = mask.sum(axis=1).astype(np.int64)
+                    tokens_real = int(lengths.sum())
                     len_bucket = int(ids.shape[1])
                     tok.set_attribute("tokens_real", tokens_real)
                     tok.set_attribute("len_bucket", len_bucket)
                 with _tracer.span("embed.forward") as fwd:
-                    out, forwarded = self.runtime.forward(ids, mask)
-                    # what the runtime really forwarded (the padded shape,
+                    plan = length_groups(
+                        lengths, len_bucket, self.runtime.max_len, self.runtime.batch_bucket
+                    )
+                    if plan is None:
+                        out, whole = self.runtime.forward(ids, mask)
+                        parts = [(docs, whole)]
+                    else:
+                        out, parts = _forward_groups(self.runtime, ids, mask, plan, built)
+                    # what the runtime really forwarded (the padded shapes,
                     # a trunk's expert rows), as the runtime itself counts it
                     fwd.set_attribute("tokens_real", tokens_real)
-                    for key, value in forwarded.items():
+                    fwd.set_attribute("groups", len(parts))
+                    for key, value in _summed([info for _, info in parts]).items():
                         fwd.set_attribute(key, value)
-                    pad_bucket = forwarded["batch_bucket"]
                 dt = _time.perf_counter() - t0
             m_batch_seconds.observe(dt, exemplar=sp.trace_id)
             m_docs.inc(docs)
             # Surge Gate ladder visibility: how well realized batches
-            # fill the encoder's pad bucket (the shape XLA compiled for)
-            m_occupancy.labels("embed", str(pad_bucket)).observe(
-                min(1.0, docs / pad_bucket)
-            )
+            # fill the encoder's pad bucket (the shape XLA compiled for),
+            # one observation per batch forwarded
+            for count, info in parts:
+                m_occupancy.labels("embed", str(info["batch_bucket"])).observe(
+                    min(1.0, count / info["batch_bucket"])
+                )
             return [out[i] for i in range(docs)]
 
         self._embed_batch = embed_batch

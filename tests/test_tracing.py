@@ -686,7 +686,7 @@ def test_embed_batch_splits_into_tokenize_and_forward():
     # bucket; 3 texts pad to the 8-row batch bucket, which is what is forwarded
     assert tokenize.attributes == {"docs": 3, "tokens_real": 14, "len_bucket": 16}
     assert forward.attributes == {
-        "batch_bucket": 8, "len_bucket": 16, "tokens_real": 14, "tokens_padded": 128,
+        "groups": 1, "batch_bucket": 8, "len_bucket": 16, "tokens_real": 14, "tokens_padded": 128,
     }
     assert forward.attributes["batch_bucket"] == embedder.runtime.batch_bucket(3)
     assert forward.attributes["tokens_real"] <= forward.attributes["tokens_padded"]

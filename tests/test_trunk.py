@@ -297,7 +297,7 @@ def test_embedder_with_trunk_runs_the_same_embed_batch(toy):
     assert {"embed.batch", "embed.tokenize", "embed.forward"} <= names
     moe_layers = 2
     assert forward.attributes == {
-        "batch_bucket": 8, "len_bucket": 16, "tokens_real": 14, "tokens_padded": 128,
+        "groups": 1, "batch_bucket": 8, "len_bucket": 16, "tokens_real": 14, "tokens_padded": 128,
         "trunk": "toy", "expert_rows_useful": 14 * 2 * moe_layers,
         "expert_rows_computed": forward.attributes["expert_rows_computed"],
         "expert_tokens_max": forward.attributes["expert_tokens_max"],
