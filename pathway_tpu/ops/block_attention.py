@@ -1,0 +1,181 @@
+"""Causal grouped-query attention over a right-padded batch, block by block.
+
+``attention(q, k, v)`` takes the query heads grouped by the key-value head
+they read (``q`` [B, H_kv, G, T, d]; ``k``, ``v`` [B, H_kv, T, d]: query head
+``j`` of the model is ``q[:, j // G, j % G]``) and returns ``softmax(q k^T
+scale | allowed) v`` in ``q``'s layout. ``allowed(t, s)`` is ``s <= t``, and
+with ``window=W`` also ``t - s < W`` (the window counts the token itself).
+
+One Pallas kernel, flash-style: a grid step holds the ``G`` query heads of
+one key-value head over ``block_q`` positions (``G * block_q`` rows) against
+one block of ``block_k`` keys; the running maximum, the normaliser and the
+float32 accumulator live in VMEM scratch, so no logit ever reaches HBM and a
+key-value head is read once for its ``G`` query heads, not expanded to them.
+A query block visits only the key blocks that hold an allowed pair
+(``kv_range``): the grid's last axis is as long as the widest such range, and
+its steps past a block's own range are skipped and mapped onto the last block
+they used, so nothing is moved for them. Blocks that lie wholly inside the
+mask skip the mask arithmetic too.
+
+Right padding needs no mask of its own: a padding key lies after every real
+query, so the causal mask already hides it; the rows of padding queries hold
+finite numbers nobody reads. Multiplies in the operands' dtype, logits,
+softmax and accumulation in float32. Its device ops are called
+``ATTN_KERNEL_NAME`` in a trace; ``ops/backend.py`` decides interpret mode.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from pathway_tpu.ops.backend import pallas_interpret
+
+ATTN_KERNEL_NAME = "block_causal_attention"
+BLOCK_Q = 128  # query positions a step; times G heads they are the rows of both matmuls
+BLOCK_K = 512  # keys a step
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)  # a row's first blocks may be all masked: see _step
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def blocks(length: int, block_q: int | None = None, block_k: int | None = None) -> tuple[int, int]:
+    """The block sizes ``attention`` uses at ``length`` positions."""
+    return min(block_q or BLOCK_Q, length), min(block_k or BLOCK_K, length)
+
+
+def kv_range(qi, block_q: int, block_k: int, window: int | None):
+    """First and last key block that hold a pair allowed to query block ``qi``."""
+    first_query, last_query = qi * block_q, qi * block_q + block_q - 1
+    largest = max if isinstance(qi, int) else jnp.maximum
+    lo = 0 if window is None else largest(first_query - (window - 1), 0) // block_k
+    return lo, last_query // block_k
+
+
+def visited_steps(length: int, window: int | None, block_q: int, block_k: int) -> list[int]:
+    """Key blocks each query block of a ``length``-position row visits."""
+    steps = []
+    for qi in range(-(-length // block_q)):
+        lo, hi = kv_range(qi, block_q, block_k, window)
+        steps.append(hi - lo + 1)
+    return steps
+
+
+def pairs_visited(length: int, window: int | None = None, block_q=None, block_k=None) -> int:
+    """Query-key pairs of the blocks the kernel visits for one row of
+    ``length`` positions (one head): what it computes, padding and the
+    masked corners of its edge blocks included."""
+    block_q, block_k = blocks(length, block_q, block_k)
+    return sum(visited_steps(length, window, block_q, block_k)) * block_q * block_k
+
+
+def pairs_allowed(tokens: int, window: int | None = None) -> int:
+    """Query-key pairs inside the mask for a row of ``tokens`` real tokens."""
+    if window is None or tokens <= window:
+        return tokens * (tokens + 1) // 2
+    return window * (window + 1) // 2 + (tokens - window) * window
+
+
+def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, window, block_q, block_k):
+    qi, j = pl.program_id(2), pl.program_id(3)
+    heads, _, width = q_ref.shape[2:]
+    rows = heads * block_q
+    lo, hi = kv_range(qi, block_q, block_k, window)
+    kb = lo + j
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def _step(masked: bool):
+        q = q_ref[0, 0].reshape(rows, width)
+        k, v = k_ref[0, 0], v_ref[0, 0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [rows, block_k]
+        if masked:
+            # row g * block_q + i is position qi * block_q + i of head g
+            s = s.reshape(heads, block_q, block_k)
+            t = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            at = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            allowed = at <= t
+            if window is not None:
+                allowed &= t - at < window
+            s = jnp.where(allowed, s, _MASKED).reshape(rows, block_k)
+        # A row whose pairs in this block are all masked takes the mask value
+        # as its maximum and counts every key once; its own diagonal block
+        # comes later, and exp(_MASKED - a real maximum) = 0 wipes that out.
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        m_ref[...] = m_new
+
+    # wholly inside the mask: the block's last key is no later than the query
+    # block's first position, and its first key within the window of the last
+    inside = kb * block_k + block_k - 1 <= qi * block_q
+    if window is not None:
+        inside &= qi * block_q + block_q - 1 - kb * block_k < window
+    visited = kb <= hi
+    pl.when(visited & inside)(functools.partial(_step, False))
+    pl.when(visited & jnp.logical_not(inside))(functools.partial(_step, True))
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        out = acc_ref[...] / l_ref[...]
+        o_ref[0, 0] = out.reshape(heads, block_q, width).astype(o_ref.dtype)
+
+
+def attention(q, k, v, *, scale: float, window: int | None = None, block_q=None, block_k=None):
+    """``q`` [B, H_kv, G, T, d]; ``k``, ``v`` [B, H_kv, T, d] -> [B, H_kv, G, T, d]."""
+    batch, kv_heads, group, length, width = q.shape
+    block_q, block_k = blocks(length, block_q, block_k)
+    pad = -length % max(block_q, block_k)
+    if pad:  # a length off the ladder: padding keys lie after every real query
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0)))
+        k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in (k, v))
+    padded = length + pad
+    steps = max(visited_steps(padded, window, block_q, block_k))
+
+    def q_map(b, h, qi, j):
+        return (b, h, 0, qi, 0)
+
+    def kv_map(b, h, qi, j):
+        lo, hi = kv_range(qi, block_q, block_k, window)
+        return (b, h, jnp.minimum(lo + j, hi), 0)
+
+    rows = group * block_q
+    out = pl.pallas_call(
+        functools.partial(
+            _kernel, scale=scale, window=window, block_q=block_q, block_k=block_k
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(batch, kv_heads, padded // block_q, steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, group, block_q, width), q_map),
+            pl.BlockSpec((1, 1, block_k, width), kv_map),
+            pl.BlockSpec((1, 1, block_k, width), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, 1, group, block_q, width), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, width), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=pallas_interpret(),
+        name=ATTN_KERNEL_NAME,
+    )(q, k, v)
+    return out[:, :, :, :length] if pad else out

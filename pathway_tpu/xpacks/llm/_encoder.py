@@ -65,8 +65,16 @@ class CrossEncoderHead(nn.Module):
         return nn.Dense(1, dtype=jnp.float32)(emb)[:, 0]
 
 
-def _bucket_batch(n: int) -> int:
-    b = 8
+# The count's floor of 8 rows holds up to the 512 rung: a batch is never
+# padded past 8 x 512 positions for its floor's sake, so a lone text of
+# 2,048 tokens rides at 2 rows and one of 4,096 or more alone.
+_FLOOR_POSITIONS = 8 * 512
+
+
+def _bucket_batch(n: int, width: int = 0) -> int:
+    """The rows a batch of ``n`` is padded to at ``width`` positions: a power
+    of two from the floor."""
+    b = max(1, min(8, _FLOOR_POSITIONS // max(width, 1)))
     while b < n:
         b *= 2
     return b
@@ -145,9 +153,9 @@ class EncoderRuntime:
 
         self._fwd = fwd
 
-    def batch_bucket(self, n: int) -> int:
-        """The batch dimension a batch of ``n`` is padded to."""
-        bucket = _bucket_batch(n)
+    def batch_bucket(self, n: int, width: int = 0) -> int:
+        """The batch dimension a batch of ``n`` is padded to at ``width`` positions."""
+        bucket = _bucket_batch(n, width)
         if self.mesh is not None:
             n_dev = self.mesh.shape[self.axis]
             bucket = max(bucket, n_dev)
@@ -162,7 +170,7 @@ class EncoderRuntime:
         forwarded. Several batches dispatched before the first is fetched
         queue on the device and cost one wait, not one each."""
         n = ids.shape[0]
-        bucket = self.batch_bucket(n)
+        bucket = self.batch_bucket(n, ids.shape[1])
         if bucket != n:
             ids = np.pad(ids, ((0, bucket - n), (0, 0)))
             mask = np.pad(mask, ((0, bucket - n), (0, 0)))

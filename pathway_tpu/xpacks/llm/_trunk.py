@@ -7,14 +7,27 @@ by kind, so another architecture adds kinds, not branches. Kinds so far:
 
 * attention ``mla``: multi-head latent attention in its expanded (prefill)
   form, YaRN rotary on the decoupled rotary part, causal, no cache;
+  ``gqa_window`` and ``gqa_full``: grouped-query attention through
+  ``ops/block_attention.py`` (no logits in HBM, blocks outside the mask
+  skipped, each key-value head read once for its query heads), the first
+  inside a sliding window with rotary over interleaved pairs, the second
+  causal over the whole row and unrotated; a model's ``layer_types`` names
+  them layer by layer;
 * feed-forward ``dense`` (gated silu) and ``moe`` (``ops/moe.py``: sigmoid
-  router with bias-corrected top-k, dropless grouped experts, a shared expert);
+  router, its top-k bias-corrected and scaled where the config says so,
+  dropless grouped experts, shared experts summed or averaged);
 * residual ``mhc``: manifold-constrained hyper-connections (arXiv:2512.24880),
-  ``hc_mult`` residual streams mixed by a doubly stochastic matrix per token.
+  ``hc_mult`` residual streams mixed by a doubly stochastic matrix per token;
+  ``add``: ``x + attn(norm x)``, then ``x + ffn(norm x)``; ``parallel``: one
+  norm a layer, ``x + attn(h) + ffn(h)``;
+* norm ``rms`` and ``layer`` (mean-centred, a gain and no bias).
+
+``TrunkConfig.from_dict`` reads the key names of the ``model_type`` it is
+given (``cohere2_moe``'s beside the default ones) onto the same fields.
 
 ``TrunkRuntime`` has ``EncoderRuntime``'s surface and is what
 ``SentenceTransformerEmbedder(trunk=...)`` runs: the whole forward over a
-right-padded batch, causal, the final RMS norm of the summed streams at the
+right-padded batch, causal, the final norm of the (summed) stream at the
 last real token, float32, L2-normalised. Parameters and activations are
 bfloat16 (float32 accumulation; router scores, softmax, residual
 coefficients, norms and the pooled vector in float32), made on the device
@@ -34,8 +47,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pathway_tpu.ops import moe
+from pathway_tpu.ops import block_attention, moe
 from pathway_tpu.xpacks.llm._encoder import _bucket_batch
+
+# ``cohere2_moe``'s names for what the fields below hold, and what its
+# modelling code fixes without a key: no correction bias, no scaling, one stream
+_COHERE2_MOE_KEYS = {
+    "num_experts": "n_routed_experts",
+    "num_shared_experts": "n_shared_experts",
+    "expert_selection_fn": "scoring_func",
+    "intermediate_size": "moe_intermediate_size",  # no key of its own for one expert's width
+}
+_COHERE2_MOE_FIXED = {"topk_method": "greedy", "routed_scaling_factor": 1.0, "hc_mult": 1}
+_LAYER_TYPES = {"sliding_attention": "gqa_window", "full_attention": "gqa_full"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,16 +112,35 @@ class TrunkConfig:
     rope_scaling: RopeScaling | None = None
     hidden_act: str = "silu"
     attention_bias: bool = False
+    # grouped-query layers: ``layer_types`` names each layer's attention
+    model_type: str = ""
+    layer_types: tuple[str, ...] | None = None
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    sliding_window: int = 0
+    position_embedding_type: str = "rope_gptj"
+    rotary_pct: float = 1.0
+    use_qk_norm: bool = False
+    use_parallel_block: bool = False
+    use_gated_activation: bool = True
+    layer_norm_eps: float | None = None  # given: the norms are layer norms
+    shared_expert_combination_strategy: str = "sum"
     # the chip's share of the routed experts: (first, count); None holds all
     experts_held: tuple[int, int] | None = None
 
     def __post_init__(self):
         unsupported = {
             "scoring_func": self.scoring_func == "sigmoid",
-            "topk_method": self.topk_method == "noaux_tc",
+            "topk_method": self.topk_method in ("noaux_tc", "greedy"),
             "n_group": self.n_group == 1 and self.topk_group == 1,
             "hidden_act": self.hidden_act == "silu",
             "attention_bias": not self.attention_bias,
+            "position_embedding_type": self.position_embedding_type == "rope_gptj",
+            "rotary_pct": self.rotary_pct == 1,
+            "use_qk_norm": not self.use_qk_norm,
+            "use_gated_activation": self.use_gated_activation,
+            "shared_expert_combination_strategy": self.shared_expert_combination_strategy
+            in ("sum", "average"),
         }
         for key, ok in unsupported.items():
             if not ok:
@@ -106,9 +149,22 @@ class TrunkConfig:
     @classmethod
     def from_dict(cls, config: dict, **overrides: Any) -> "TrunkConfig":
         """From a ``config.json``'s keys; keys that say nothing about the
-        trunk's shape are passed over."""
+        trunk's shape are passed over. ``model_type`` ``cohere2_moe`` has
+        names of its own for some fields. A file cut to one chip's share
+        (``experts_held``) counts the experts held under the published key
+        and states the published count, the router's width, under
+        ``published``."""
+        if config.get("model_type") == "cohere2_moe":
+            config = {_COHERE2_MOE_KEYS.get(k, k): v for k, v in config.items()}
+            config = {**_COHERE2_MOE_FIXED, **config}
+            if config.get("experts_held") is not None:
+                config["n_routed_experts"] = config.get("published", {}).get(
+                    "num_experts", config["n_routed_experts"]
+                )
         names = {f.name for f in dataclasses.fields(cls)}
         picked = {k: v for k, v in config.items() if k in names}
+        if picked.get("layer_types") is not None:
+            picked["layer_types"] = tuple(picked["layer_types"])
         scaling = picked.get("rope_scaling")
         if isinstance(scaling, dict):
             known = {f.name for f in dataclasses.fields(RopeScaling)}
@@ -132,8 +188,23 @@ class TrunkConfig:
     def held(self) -> tuple[int, int]:
         return self.experts_held or (0, self.n_routed_experts)
 
+    @property
+    def norm_kind(self) -> str:
+        return "rms" if self.layer_norm_eps is None else "layer"
+
+    @property
+    def norm_eps(self) -> float:
+        return self.rms_norm_eps if self.layer_norm_eps is None else self.layer_norm_eps
+
     def layer_table(self) -> tuple["LayerKinds", ...]:
-        residual = "mhc" if self.hc_mult > 1 else "add"
+        if self.use_parallel_block:
+            residual = "parallel"
+        else:
+            residual = "mhc" if self.hc_mult > 1 else "add"
+        if self.layer_types is not None and len(self.layer_types) < self.num_hidden_layers:
+            raise ValueError(
+                f"trunk config: {self.num_hidden_layers} layers, layer_types names {len(self.layer_types)}"
+            )
         table = []
         for i in range(self.num_hidden_layers):
             sparse = (
@@ -141,7 +212,12 @@ class TrunkConfig:
                 and i >= self.first_k_dense_replace
                 and i % self.moe_layer_freq == 0
             )
-            table.append(LayerKinds("mla", "moe" if sparse else "dense", residual))
+            # a type this file has no kind for keeps its own name, and the registry refuses it
+            attention = (
+                "mla" if self.layer_types is None
+                else _LAYER_TYPES.get(self.layer_types[i], self.layer_types[i])
+            )
+            table.append(LayerKinds(attention, "moe" if sparse else "dense", residual))
         return tuple(table)
 
 
@@ -164,6 +240,22 @@ def rms_norm(x, gain, eps: float):
     x32 = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (x32 * scale * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x, gain, eps: float):
+    """Mean-centred, a gain and no bias, in float32."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(centred * centred, axis=-1, keepdims=True) + eps)
+    return (centred * scale * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+NORM = {"rms": rms_norm, "layer": layer_norm}
+
+
+def norm(x, gain, config: "TrunkConfig"):
+    """The config's kind of norm, in front of a block and before pooling."""
+    return NORM[config.norm_kind](x, gain, config.norm_eps)
 
 
 def _dot(x, w):
@@ -281,7 +373,72 @@ def causal_attention(q_n, q_r, k_n, k_r, v, scale):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32)
 
 
-ATTENTION = {"mla": Block(_mla_shapes, _mla, "trunk.mla")}
+def _gqa_shapes(c: TrunkConfig) -> dict:
+    heads, kv_heads, width = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    return {
+        "wq": ((c.hidden_size, heads, width), "kernel"),
+        "wk": ((c.hidden_size, kv_heads, width), "kernel"),
+        "wv": ((c.hidden_size, kv_heads, width), "kernel"),
+        "wo": ((heads, width, c.hidden_size), "kernel_out"),
+    }
+
+
+def interleaved_rope_tables(config: TrunkConfig, length: int) -> tuple[np.ndarray, np.ndarray]:
+    """cos and sin [length, head_dim / 2] of positions 0..length-1: pair i,
+    dims (2i, 2i + 1), turns by position x theta^(-2i / head_dim)."""
+    width = config.head_dim
+    inv_freq = 1.0 / float(config.rope_theta) ** (np.arange(0, width, 2, dtype=np.float64) / width)
+    angles = np.arange(length, dtype=np.float64)[:, None] * inv_freq[None, :]
+    return np.cos(angles).astype(np.float32), np.sin(angles).astype(np.float32)
+
+
+def _evens_first(w):
+    """A projection's head dims reordered evens first: its output comes out
+    de-interleaved. The same order on queries and keys leaves q . k as it was."""
+    return jnp.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
+
+
+def _rotate_pairs(x, cos, sin):
+    """``x`` [B, H, T, d] de-interleaved (first halves a, second halves b of
+    the pairs): (a cos - b sin, b cos + a sin)."""
+    half = x.shape[-1] // 2
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+def _heads(h, w):
+    """``h [B, T, d] @ w [d, H, e]`` laid out [B, H, T, e]."""
+    out = jnp.einsum("btd,dhe->bhte", h, w.astype(h.dtype), preferred_element_type=jnp.float32)
+    return out.astype(h.dtype)
+
+
+def _gqa(p, h, c: TrunkConfig, ctx: dict, *, window: bool):
+    """Grouped-query attention: query head j reads key-value head j // G. A
+    window layer turns queries and keys by their positions and sees the last
+    ``sliding_window`` tokens, itself included; a full layer turns nothing
+    and sees the whole row before it."""
+    kv_heads, width = c.num_key_value_heads, c.head_dim
+    wq, wk = p["wq"], p["wk"]
+    if window:
+        wq, wk = _evens_first(wq), _evens_first(wk)
+    q, k, v = _heads(h, wq), _heads(h, wk), _heads(h, p["wv"])
+    if window:
+        cos, sin = ctx["rope_pairs"]
+        q, k = _rotate_pairs(q, cos, sin), _rotate_pairs(k, cos, sin)
+    batch, heads, length, _ = q.shape
+    mixed = block_attention.attention(
+        q.reshape(batch, kv_heads, heads // kv_heads, length, width), k, v,
+        scale=width**-0.5, window=c.sliding_window if window else None,
+    ).reshape(q.shape)
+    out = jnp.einsum("bhte,hed->btd", mixed, p["wo"].astype(h.dtype), preferred_element_type=jnp.float32)
+    return out.astype(h.dtype)
+
+
+ATTENTION = {
+    "mla": Block(_mla_shapes, _mla, "trunk.mla"),
+    "gqa_window": Block(_gqa_shapes, functools.partial(_gqa, window=True), "trunk.gqa_window"),
+    "gqa_full": Block(_gqa_shapes, functools.partial(_gqa, window=False), "trunk.gqa_full"),
+}
 
 # -- feed-forward kinds --------------------------------------------------------
 
@@ -310,28 +467,34 @@ def _dense(p, h, c: TrunkConfig, ctx: dict):
 
 def _moe_shapes(c: TrunkConfig) -> dict:
     d, f, held = c.hidden_size, c.moe_intermediate_size, c.held[1]
-    return {
+    shapes = {
         "router": ((d, c.n_routed_experts), "kernel32"),
         "bias": ((c.n_routed_experts,), "router_bias"),
         "w_gate": ((held, d, f), "expert_kernel"),
         "w_up": ((held, d, f), "expert_kernel"),
         "w_down": ((held, f, d), "expert_kernel"),
+        # the shared experts side by side: one gated FFN whose output is their sum
         "shared": _gated_shapes(d, f * c.n_shared_experts),
     }
+    if c.topk_method != "noaux_tc":  # a plain top-k has no correction bias
+        del shapes["bias"]
+    return shapes
 
 
 def _moe(p, h, c: TrunkConfig, ctx: dict):
     flat = h.reshape(-1, h.shape[-1])
     routed, counts, choice = moe.expert_layer(
-        flat, ctx["valid"], p["router"], p["bias"], p["w_gate"], p["w_up"], p["w_down"],
+        flat, ctx["valid"], p["router"], p.get("bias"), p["w_gate"], p["w_up"], p["w_down"],
         top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor,
         normalise=c.norm_topk_prob, experts_held=c.experts_held,
     )
     ctx["expert_counts"].append(counts)
     ctx["expert_choice"].append(choice.reshape(h.shape[:-1] + choice.shape[-1:]))
     with jax.named_scope("trunk.ffn"):
-        shared = _gated_ffn(p["shared"], flat)
-    return (routed + shared.astype(jnp.float32)).astype(h.dtype).reshape(h.shape)
+        shared = _gated_ffn(p["shared"], flat).astype(jnp.float32)
+    if c.shared_expert_combination_strategy == "average":
+        shared = shared / c.n_shared_experts
+    return (routed + shared).astype(h.dtype).reshape(h.shape)
 
 
 FFN = {
@@ -407,22 +570,70 @@ def _mhc(p, streams, sublayer, c: TrunkConfig):
         )
 
 
+def _mhc_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
+    gain = ((c.hidden_size,), "gain")
+    return {
+        "attn_res": _mhc_shapes(c), "attn_norm": gain, "attn": attention,
+        "ffn_res": _mhc_shapes(c), "ffn_norm": gain, "ffn": ffn,
+    }
+
+
+def _mhc_layer(p, streams, attend, feed, c: TrunkConfig):
+    streams = _mhc(p["attn_res"], streams, lambda u: attend(p["attn"], norm(u, p["attn_norm"], c)), c)
+    return _mhc(p["ffn_res"], streams, lambda u: feed(p["ffn"], norm(u, p["ffn_norm"], c)), c)
+
+
 def _mhc_enter(x, c: TrunkConfig):
     return jnp.broadcast_to(x[None], (c.hc_mult,) + x.shape)
 
 
-def _mhc_exit(streams):
-    return streams.astype(jnp.float32).sum(axis=0)
+def _mhc_exit(streams, last):
+    """The summed streams at each row's position ``last``: [B, d] float32.
+    Picked before the sum: only that position of each row is kept."""
+    picked = jnp.take_along_axis(streams, last[None, :, None, None], axis=2)[:, :, 0]  # [n, B, d]
+    return picked.astype(jnp.float32).sum(axis=0)
+
+
+def _add_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
+    gain = ((c.hidden_size,), "gain")
+    return {"attn_norm": gain, "attn": attention, "ffn_norm": gain, "ffn": ffn}
+
+
+def _add_layer(p, x, attend, feed, c: TrunkConfig):
+    x = x + attend(p["attn"], norm(x, p["attn_norm"], c))
+    return x + feed(p["ffn"], norm(x, p["ffn_norm"], c))
+
+
+def _parallel_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
+    return {"norm": ((c.hidden_size,), "gain"), "attn": attention, "ffn": ffn}
+
+
+def _parallel_layer(p, x, attend, feed, c: TrunkConfig):
+    """Attention and feed-forward read the same normed input."""
+    h = norm(x, p["norm"], c)
+    return x + attend(p["attn"], h) + feed(p["ffn"], h)
+
+
+def _one_stream_exit(x, last):
+    return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0].astype(jnp.float32)
 
 
 class Residual(NamedTuple):
-    shapes: Callable
-    apply: Callable
-    enter: Callable
-    exit: Callable
+    """A kind of residual path: it lays out a layer's parameters around the
+    attention's and the feed-forward's, and runs the layer: ``attend`` and
+    ``feed`` take (their parameters, their normed input)."""
+
+    shapes: Callable  # (config, attention shapes, feed-forward shapes) -> one layer's tree
+    layer: Callable  # (parameters, state, attend, feed, config) -> state
+    enter: Callable  # (embedded tokens [B, T, d], config) -> state
+    exit: Callable  # (state, last [B]) -> [B, d] float32
 
 
-RESIDUAL = {"mhc": Residual(_mhc_shapes, _mhc, _mhc_enter, _mhc_exit)}
+RESIDUAL = {
+    "mhc": Residual(_mhc_shapes_of_a_layer, _mhc_layer, _mhc_enter, _mhc_exit),
+    "add": Residual(_add_shapes_of_a_layer, _add_layer, lambda x, c: x, _one_stream_exit),
+    "parallel": Residual(_parallel_shapes_of_a_layer, _parallel_layer, lambda x, c: x, _one_stream_exit),
+}
 
 
 def _block(registry: dict, kind: str, what: str):
@@ -442,16 +653,12 @@ def param_shapes(config: TrunkConfig) -> dict:
     d = config.hidden_size
     layers = []
     for kinds in config.layer_table():
-        residual = _block(RESIDUAL, kinds.residual, "residual")
         layers.append(
-            {
-                "attn_res": residual.shapes(config),
-                "attn_norm": ((d,), "gain"),
-                "attn": _block(ATTENTION, kinds.attention, "attention").shapes(config),
-                "ffn_res": residual.shapes(config),
-                "ffn_norm": ((d,), "gain"),
-                "ffn": _block(FFN, kinds.ffn, "feed-forward").shapes(config),
-            }
+            _block(RESIDUAL, kinds.residual, "residual").shapes(
+                config,
+                _block(ATTENTION, kinds.attention, "attention").shapes(config),
+                _block(FFN, kinds.ffn, "feed-forward").shapes(config),
+            )
         )
     return {
         "embed": ((config.vocab_size, d), "embedding"),
@@ -533,35 +740,30 @@ def forward(params, ids, mask, *, config: TrunkConfig):
     the router sent them) and the router's choice [expert layers, B, T, k]
     int32 (-1 at a padding position)."""
     table = config.layer_table()
-    eps = config.rms_norm_eps
-    ctx = {
-        "rope": rope_tables(config, ids.shape[1]),
-        "valid": mask.reshape(-1) > 0,
-        "expert_counts": [],
-        "expert_choice": [],
-    }
+    ctx = {"valid": mask.reshape(-1) > 0, "expert_counts": [], "expert_choice": []}
+    kinds_of_attention = {kinds.attention for kinds in table}
+    if "mla" in kinds_of_attention:
+        ctx["rope"] = rope_tables(config, ids.shape[1])
+    if "gqa_window" in kinds_of_attention:
+        ctx["rope_pairs"] = interleaved_rope_tables(config, ids.shape[1])
     x = params["embed"][ids]
-    streams = _block(RESIDUAL, table[0].residual, "residual").enter(x, config)
+    state = _block(RESIDUAL, table[0].residual, "residual").enter(x, config)
     for kinds, p in zip(table, params["layers"]):
-        residual = _block(RESIDUAL, kinds.residual, "residual")
         attention = _block(ATTENTION, kinds.attention, "attention")
         ffn = _block(FFN, kinds.ffn, "feed-forward")
 
-        def attend(h, p=p, attention=attention):
+        def attend(p, h, attention=attention):
             with jax.named_scope(attention.scope):
-                return attention.apply(p["attn"], rms_norm(h, p["attn_norm"], eps), config, ctx)
+                return attention.apply(p, h, config, ctx)
 
-        def feed(h, p=p, ffn=ffn):
+        def feed(p, h, ffn=ffn):
             with jax.named_scope(ffn.scope):
-                return ffn.apply(p["ffn"], rms_norm(h, p["ffn_norm"], eps), config, ctx)
+                return ffn.apply(p, h, config, ctx)
 
-        streams = residual.apply(p["attn_res"], streams, attend, config)
-        streams = residual.apply(p["ffn_res"], streams, feed, config)
+        state = _block(RESIDUAL, kinds.residual, "residual").layer(p, state, attend, feed, config)
     # pool before the last norm: only the last real position of each row is kept
     last = jnp.maximum(mask.sum(axis=1).astype(jnp.int32) - 1, 0)
-    picked = jnp.take_along_axis(streams, last[None, :, None, None], axis=2)[:, :, 0]  # [n, B, d]
-    summed = _block(RESIDUAL, table[-1].residual, "residual").exit(picked)
-    pooled = rms_norm(summed, params["final_norm"], eps)
+    pooled = norm(_block(RESIDUAL, table[-1].residual, "residual").exit(state, last), params["final_norm"], config)
     vectors = pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-12)
     if not ctx["expert_counts"]:  # no expert layer in the table
         top_k, experts = max(config.num_experts_per_tok, 1), max(config.n_routed_experts, 1)
@@ -594,6 +796,12 @@ class TrunkRuntime:
         self._seed = seed
         self._params = None
         self._fwd = jax.jit(functools.partial(forward, config=config))
+        # the window (None: the whole row) of each blocked attention layer
+        self._windows = [
+            config.sliding_window if kinds.attention == "gqa_window" else None
+            for kinds in config.layer_table()
+            if kinds.attention in ("gqa_window", "gqa_full")
+        ]
 
     @property
     def params(self):
@@ -605,8 +813,24 @@ class TrunkRuntime:
     def params(self, tree) -> None:
         self._params = tree
 
-    def batch_bucket(self, n: int) -> int:
-        return _bucket_batch(n)
+    def batch_bucket(self, n: int, width: int = 0) -> int:
+        return _bucket_batch(n, width)
+
+    def _attention_pairs(self, lengths: np.ndarray, rows: int, width: int) -> dict:
+        """What the blocked attention layers of one forward are asked for and
+        what their kernel visits, in query-key pairs a head: the pairs inside
+        the masks over each row's real tokens, and the pairs of the blocks
+        visited at the forwarded shape (``rows`` x ``width``, padding rows and
+        positions included). Nothing for a table without such a layer."""
+        windows = self._windows
+        if not windows:
+            return {}
+        return {
+            "attn_pairs_allowed": sum(
+                block_attention.pairs_allowed(int(t), w) for w in windows for t in lengths
+            ),
+            "attn_pairs_visited": rows * sum(block_attention.pairs_visited(width, w) for w in windows),
+        }
 
     def dispatch(
         self, ids: np.ndarray, mask: np.ndarray, routing: bool = False
@@ -614,11 +838,13 @@ class TrunkRuntime:
         """Starts the forward of one padded batch and returns the call that
         waits for it (``EncoderRuntime.dispatch``): vectors [n, dim] and what
         was really forwarded, the padded shape and the expert layers' row
-        counts. ``routing=True`` adds ``expert_choice`` [expert layers, n, T,
-        k], the experts each token went to (-1: nowhere); it stays on the
-        device unless asked for."""
+        counts, and where the table has blocked attention layers their
+        ``attn_pairs_allowed`` and ``attn_pairs_visited``. ``routing=True``
+        adds ``expert_choice`` [expert layers, n, T, k], the experts each
+        token went to (-1: nowhere); it stays on the device unless asked for."""
         n = ids.shape[0]
-        bucket = self.batch_bucket(n)
+        bucket = self.batch_bucket(n, ids.shape[1])
+        lengths = np.asarray(mask).sum(axis=1)
         if bucket != n:
             ids = np.pad(ids, ((0, bucket - n), (0, 0)))
             mask = np.pad(mask, ((0, bucket - n), (0, 0)))
@@ -629,6 +855,7 @@ class TrunkRuntime:
             "len_bucket": int(ids.shape[1]),
             "tokens_padded": int(ids.size),
             "trunk": self.config.name,
+            **self._attention_pairs(lengths, bucket, int(ids.shape[1])),
         }
 
         def fetch() -> tuple[np.ndarray, dict]:

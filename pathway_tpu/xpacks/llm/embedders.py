@@ -27,9 +27,24 @@ from pathway_tpu.xpacks.llm._tokenizer import _bucket_len
 # to group at all, so nobody has to tune this.
 _GROUP = 32
 
+# Padded positions a group holds at most: a full group on the 512 rung, the
+# largest group any forward held before documents longer than a chunk came.
+# Above that rung a group closes by positions before it closes by rows.
+_GROUP_POSITIONS = _GROUP * 512
+
 # what a batch's forwards report that adds up over them; the rest (bucket
 # sizes, an expert's largest load) is the largest forwarded
-_ADDED = ("tokens_padded", "expert_rows_useful", "expert_rows_computed")
+_ADDED = (
+    "tokens_padded", "expert_rows_useful", "expert_rows_computed",
+    "attn_pairs_allowed", "attn_pairs_visited",
+)
+
+
+def _group_rows(rung: int) -> int:
+    """Rows a group on ``rung`` holds at most: ``_GROUP``, or the power of
+    two that fits ``_GROUP_POSITIONS`` (16 on the 1,024 rung, 1 on the
+    16,384 one)."""
+    return min(_GROUP, 1 << (max(1, _GROUP_POSITIONS // rung).bit_length() - 1))
 
 
 def length_groups(lengths, width: int, max_len: int, batch_bucket):
@@ -37,39 +52,65 @@ def length_groups(lengths, width: int, max_len: int, batch_bucket):
 
     ``lengths`` are the texts' real token counts, ``width`` the rung the whole
     batch was padded to (its longest text's), ``batch_bucket`` the runtime's
-    padding of a count. The rows, sorted by length, are cut into groups of
-    ``_GROUP`` (a remainder rides at the short end, where its padding rows
-    cost least), each on the rung of its own longest member: ``[(rows,
-    rung), ...]``. ``None`` says forward the batch whole: it is one group
-    anyway, or the plan's padded positions are not under three quarters of
-    the whole batch's, and a split would pay per forward (a launch, a read
-    of the weights) for nothing."""
+    padding of a count at a width. The rows, sorted by length, are cut into
+    groups from the long end, each on the rung of its own longest member and
+    closed at ``_GROUP`` rows or at ``_GROUP_POSITIONS`` padded positions,
+    whichever comes first (``_group_rows``): ``[(rows, rung), ...]``,
+    shortest first. Up to the 512 rung a group closes by rows, and the
+    remainder rides at the short end, where its padding rows cost least.
+    Above it a group closes by positions: it holds the rows of its own rung
+    (a row lifted a rung there pads by a thousand positions and more, which
+    costs more than the forward it saves) and fills the padding rows its
+    count is rounded up to with the next longest, which ride for nothing.
+    ``None`` says forward the batch whole: it is one group anyway, or it fits
+    a group's positions and the plan's padded positions are not under three
+    quarters of the whole batch's, so a split would pay per forward (a
+    launch, a read of the weights) for nothing."""
     n = len(lengths)
-    if n <= _GROUP:
+    by_rows = _group_rows(width) == _GROUP  # every group closes by rows
+    if by_rows and n <= _GROUP:
         return None
     order = np.argsort(lengths, kind="stable")
-    groups, start = [], 0
-    for end in range(n % _GROUP or _GROUP, n + 1, _GROUP):
-        rows = order[start:end]
-        groups.append((rows, min(_bucket_len(int(lengths[rows[-1]]), max_len), width)))
-        start = end
-    planned = batch_bucket(_GROUP) * sum(rung for _, rung in groups)
-    if 4 * planned >= 3 * batch_bucket(n) * width:
+    ladder = np.array([_bucket_len(1 << e, max_len) for e in range(4, max(width, 16).bit_length() + 1)])
+    rungs = np.minimum(ladder[np.searchsorted(ladder[:-1], np.asarray(lengths)[order])], width).tolist()
+    groups, planned, end = [], 0, n
+    while end > 0:
+        rung = rungs[end - 1]
+        start = max(0, end - _group_rows(rung))
+        if _group_rows(rung) == _GROUP:
+            rows = batch_bucket(_GROUP, rung)
+        else:
+            while rungs[start] != rung:
+                start += 1
+            rows = batch_bucket(end - start, rung)
+            start = max(0, end - rows)
+        groups.append((order[start:end], rung))
+        planned += rows * rung
+        end = start
+    groups.reverse()
+    whole = batch_bucket(n, width) * width
+    if len(groups) == 1 or ((by_rows or whole <= _GROUP_POSITIONS) and 4 * planned >= 3 * whole):
         return None
     return groups
 
 
-def _forward_groups(runtime, ids, mask, plan, built: set[int]) -> tuple[np.ndarray, list[tuple[int, dict]]]:
+def _forward_groups(runtime, ids, mask, plan, built: set[int], **asked) -> tuple[np.ndarray, list[tuple[np.ndarray, dict]]]:
     """Forwards a planned batch group by group, every forward dispatched
     before the first result is fetched: vectors in the rows' own order, and
-    each group's (texts, what the runtime forwarded).
+    each group's (rows, what the runtime forwarded). ``asked`` goes to every
+    ``dispatch`` (a trunk's ``routing=True``).
 
-    Every group rides at batch shape ``_GROUP``, so the shapes are ``_GROUP``
-    x the length ladder. Which rungs a batch's groups land on differs from
-    batch to batch, so the first call that reaches a rung builds every rung
-    below it as well (``built``): a later batch compiles nothing."""
-    top = max(rung for _, rung in plan)
-    if top not in built:
+    A group closed by rows rides at batch shape ``_GROUP`` (a remainder is
+    padded to it), so those shapes are ``_GROUP`` x the length ladder up to
+    the 512 rung. Which rungs a batch's groups land on differs from batch to
+    batch, so the first call that reaches such a rung builds every rung below
+    it as well (``built``): a later batch compiles nothing. A group closed by
+    positions rides at the runtime's padding of its own count, a power of two
+    that may be under 8; those shapes, three a rung at most and each up to a
+    whole ``_GROUP_POSITIONS`` forward to build, are built when first met."""
+    by_rows = [rung for _, rung in plan if _group_rows(rung) == _GROUP]
+    top = max(by_rows, default=0)
+    if top and top not in built:
         ladder = {min(_bucket_len(t, runtime.max_len), top) for t in range(1, top + 1)}
         for rung in sorted(ladder - built):
             runtime.dispatch(np.zeros((_GROUP, rung), np.int32), np.ones((_GROUP, rung), np.float32))
@@ -77,16 +118,16 @@ def _forward_groups(runtime, ids, mask, plan, built: set[int]) -> tuple[np.ndarr
     pending = []
     for rows, rung in plan:
         group_ids, group_mask = ids[rows, :rung], mask[rows, :rung]
-        if len(rows) < _GROUP:  # the remainder: padded, or its shape would be a new one
+        if len(rows) < _group_rows(rung) == _GROUP:  # the remainder: padded, or its shape would be a new one
             pad = ((0, _GROUP - len(rows)), (0, 0))
             group_ids, group_mask = np.pad(group_ids, pad), np.pad(group_mask, pad)
-        pending.append(runtime.dispatch(group_ids, group_mask))
+        pending.append(runtime.dispatch(group_ids, group_mask, **asked))
     results = [fetch() for fetch in pending]
     first = results[0][0]
     out = np.empty((len(ids),) + first.shape[1:], first.dtype)
     for (rows, _), (vectors, _info) in zip(plan, results):
         out[rows] = vectors[: len(rows)]
-    return out, [(len(rows), info) for (rows, _), (_, info) in zip(plan, results)]
+    return out, [(rows, info) for (rows, _), (_, info) in zip(plan, results)]
 
 
 def _summed(infos: list[dict]) -> dict:
@@ -122,16 +163,23 @@ class SentenceTransformerEmbedder(BaseEmbedder):
 
     ``trunk=`` (a ``TrunkConfig``, or the path of a model's published
     ``config.json``) runs a decoder-style trunk from ``_trunk.py``'s layer
-    table in the encoder's place: sparse experts, latent attention, a
-    multi-stream residual; causal, pooled at the last real token. Same ``embed_batch``, tokenizer resolution, pad ladder
-    and spans; ``dim``/``depth``/``heads`` are then the config's.
+    table in the encoder's place: sparse experts, latent or grouped-query
+    (window and full) attention, a multi-stream, plain or parallel
+    residual; causal, pooled at the last real token. Same ``embed_batch``,
+    tokenizer resolution, pad ladder and spans; ``dim``/``depth``/``heads``
+    are then the config's.
 
     The pad ladder: a batch is padded to its longest text's length rung
-    (16, 32, 64, ... ``max_len``) and its count to a power of two from 8.
-    A batch of more than ``_GROUP`` texts whose lengths differ enough is
-    forwarded in length-sorted groups of ``_GROUP`` instead, each on its
-    own longest member's rung (``length_groups``); the vectors are the same
-    and come back in the caller's order."""
+    (16, 32, 64, ... ``max_len``) and its count to a power of two from 8
+    (from 4, 2 and 1 on the 1,024, 2,048 and longer rungs: the floor never
+    pads a batch past 8 x 512 positions). A batch of more than ``_GROUP``
+    texts whose lengths differ enough is forwarded in length-sorted groups
+    of ``_GROUP`` instead, each on its own longest member's rung
+    (``length_groups``). No group holds more than ``_GROUP_POSITIONS`` =
+    16,384 padded positions: above the 512 rung a group closes by positions
+    (16 rows on the 1,024 rung, ... one on the 16,384 one), so a batch of
+    whole documents is forwarded rung by rung however few its texts are.
+    The vectors are the same and come back in the caller's order."""
 
     def __init__(
         self,
@@ -263,7 +311,7 @@ class SentenceTransformerEmbedder(BaseEmbedder):
 
         m_occupancy = occupancy_histogram()
         _tracer = get_tracer()
-        built: set[int] = set()  # the group rungs this embedder's forward is compiled for
+        self._built: set[int] = set()  # the group rungs this embedder's forward is compiled for
 
         def embed_batch(texts: Sequence[str]) -> list[np.ndarray]:
             import time as _time
@@ -289,14 +337,7 @@ class SentenceTransformerEmbedder(BaseEmbedder):
                     tok.set_attribute("tokens_real", tokens_real)
                     tok.set_attribute("len_bucket", len_bucket)
                 with _tracer.span("embed.forward") as fwd:
-                    plan = length_groups(
-                        lengths, len_bucket, self.runtime.max_len, self.runtime.batch_bucket
-                    )
-                    if plan is None:
-                        out, whole = self.runtime.forward(ids, mask)
-                        parts = [(docs, whole)]
-                    else:
-                        out, parts = _forward_groups(self.runtime, ids, mask, plan, built)
+                    out, parts = self._forward_planned(ids, mask, lengths)
                     # what the runtime really forwarded (the padded shapes,
                     # a trunk's expert rows), as the runtime itself counts it
                     fwd.set_attribute("tokens_real", tokens_real)
@@ -309,9 +350,9 @@ class SentenceTransformerEmbedder(BaseEmbedder):
             # Surge Gate ladder visibility: how well realized batches
             # fill the encoder's pad bucket (the shape XLA compiled for),
             # one observation per batch forwarded
-            for count, info in parts:
+            for rows, info in parts:
                 m_occupancy.labels("embed", str(info["batch_bucket"])).observe(
-                    min(1.0, count / info["batch_bucket"])
+                    min(1.0, len(rows) / info["batch_bucket"])
                 )
             return [out[i] for i in range(docs)]
 
@@ -323,6 +364,17 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         self._batched = True
         # batched path: fn receives a list of texts
         self._fn = embed_batch
+
+    def _forward_planned(self, ids, mask, lengths, **asked):
+        """One tokenized batch as ``embed_batch`` forwards it: whole, or group
+        by group as ``length_groups`` plans it. Vectors in the rows' order and
+        each forward's (rows, what the runtime forwarded); ``asked`` goes to
+        the runtime with every forward."""
+        plan = length_groups(lengths, int(ids.shape[1]), self.runtime.max_len, self.runtime.batch_bucket)
+        if plan is None:
+            out, whole = self.runtime.forward(ids, mask, **asked)
+            return out, [(np.arange(len(ids)), whole)]
+        return _forward_groups(self.runtime, ids, mask, plan, self._built, **asked)
 
     def _single(self, text: str) -> np.ndarray:
         return self._embed_batch([text])[0]

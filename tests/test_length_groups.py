@@ -99,7 +99,7 @@ def test_a_rung_is_never_wider_than_the_batch():
 def test_on_a_mesh_a_group_is_what_the_runtime_makes_of_it():
     # 64 devices: a group of 32 is padded to 64 rows, as the whole batch of 64 is
     lengths = np.array([5] * GROUP + [60] * GROUP)
-    assert length_groups(lengths, 64, 512, lambda n: max(64, pow2_from_8(n))) is None
+    assert length_groups(lengths, 64, 512, lambda n, width=0: max(64, pow2_from_8(n))) is None
     assert length_groups(lengths, 64, 512, pow2_from_8) is not None
 
 
@@ -284,3 +284,126 @@ def test_a_trunk_rides_the_same_plan_and_its_expert_rows_add_up(trunk_embedder, 
         assert span["batch_bucket"] == GROUP and span["len_bucket"] == 32
     else:
         assert {k: span[k] for k in info} == info
+
+
+# -- whole documents: groups closed by positions ------------------------------------
+
+POSITIONS = embedders._GROUP_POSITIONS
+DOC_TICKS = [  # ticks of `doc-ingest-ticks`, in tokens
+    [2449, 4929, 6590, 7021], [1843, 4230, 5197, 8734], [1739, 2416, 4598, 5578], [4161, 5262, 6094, 6370],
+    [3324, 8344, 9653, 11879], [2214, 3429, 4214, 4240], [1210, 2100, 6132, 8622],
+]
+
+
+def test_the_budget_is_the_largest_group_forwarded_before():
+    assert POSITIONS == GROUP * 512 == 16384
+    assert [embedders._group_rows(r) for r in (16, 256, 512, 1024, 2048, 4096, 8192, 16384, 32768)] == [
+        32, 32, 32, 16, 8, 4, 2, 1, 1,
+    ]
+    # the count's floor: 8 rows up to the 512 rung, never past 8 x 512 positions above it
+    assert [pow2_from_8(1, w) for w in (0, 16, 512, 1024, 2048, 4096, 16384)] == [8, 8, 8, 4, 2, 1, 1]
+    assert [pow2_from_8(n, 2048) for n in (1, 2, 3, 5, 8)] == [2, 2, 4, 8, 8]
+    assert [pow2_from_8(n) for n in (1, 8, 9, 33)] == [8, 8, 16, 64]
+
+
+@pytest.mark.parametrize("lengths", DOC_TICKS, ids=lambda t: "-".join(map(str, t)))
+def test_a_tick_of_documents_rides_rung_by_rung_inside_the_budget(lengths):
+    lengths = np.array(lengths)
+    width = width_of(lengths, 16384)
+    plan = length_groups(lengths, width, 16384, pow2_from_8)
+    assert plan is not None and sorted(np.concatenate([rows for rows, _ in plan])) == [0, 1, 2, 3]
+    rungs = [rung for _, rung in plan]
+    assert rungs == sorted(rungs) and rungs[-1] == width
+    for rows, rung in plan:
+        # on its longest member's rung, the padded forward inside the budget, at a count that may be under 8
+        own = [t for t in lengths[rows] if embedders._bucket_len(int(t), 16384) == rung]
+        assert own and max(lengths[rows]) == max(own) and len(rows) <= pow2_from_8(len(own), rung)
+        assert pow2_from_8(len(rows), rung) * rung <= POSITIONS and len(rows) <= embedders._group_rows(rung)
+    assert sum(pow2_from_8(len(rows), rung) * rung for rows, rung in plan) <= pow2_from_8(4, width) * width
+
+
+def test_the_plan_of_the_first_doc_tick_by_hand():
+    plan = length_groups(np.array(DOC_TICKS[0]), 8192, 16384, pow2_from_8)
+    assert [(sorted(rows.tolist()), rung) for rows, rung in plan] == [([0], 4096), ([1], 8192), ([2, 3], 8192)]
+    four = length_groups(np.array([9000, 9001, 9002, 9003]), 16384, 16384, pow2_from_8)
+    assert [(len(rows), rung) for rows, rung in four] == [(1, 16384)] * 4
+
+
+@pytest.mark.parametrize(
+    "lengths, width",
+    [([11879], 16384), ([1500], 2048), ([5000, 6000], 8192), ([3000, 3100, 3200, 1500], 4096), ([600] * 16, 1024),
+     ([600, 1500, 40, 1100, 2000, 700], 2048)],
+    ids=["a-probe-of-12k", "a-probe-of-1500", "two-on-8192", "four-on-4096", "sixteen-on-1024", "six-saving-a-quarter"],
+)
+def test_one_group_inside_the_budget_goes_whole(lengths, width):
+    assert length_groups(np.array(lengths), width, 16384, pow2_from_8) is None
+    assert pow2_from_8(len(lengths), width) * width <= POSITIONS
+
+
+def test_short_and_long_texts_in_one_batch():
+    lengths = np.array([40] * 40 + [3000, 700, 9000])
+    plan = length_groups(lengths, 16384, 16384, pow2_from_8)
+    # the 700 alone on the 1,024 rung rides at 4 rows: three of the short ones fill them for nothing
+    assert [(len(rows), rung) for rows, rung in plan] == [(5, 64), (32, 64), (4, 1024), (1, 4096), (1, 16384)]
+    assert sorted(np.concatenate([rows for rows, _ in plan])) == list(range(43))
+
+
+@pytest.mark.parametrize(
+    "lengths, max_len",
+    [(PASSAGES, 512), (CHUNKS, 512), (CHUNKS[:32], 512), (lognormal_lengths(24, 7, 0.4, 2, 31, seed=2), 512)],
+    ids=["bge-ingest", "xing4-64-chunks", "xing4-chunk-ingest", "minilm-retrieve"],
+)
+def test_the_cells_at_chunk_widths_keep_their_plans(lengths, max_len):
+    """Up to the 512 rung the plan is what it was before the budget: groups
+    of ``_GROUP`` from the long end, the remainder at the short end."""
+    width = width_of(lengths, max_len)
+    plan = length_groups(lengths, width, max_len, pow2_from_8)
+    n = len(lengths)
+    order = np.argsort(lengths, kind="stable")
+    old = [
+        (order[start:end], min(embedders._bucket_len(int(lengths[order[end - 1]]), max_len), width))
+        for start, end in zip([0] + list(range(n % GROUP or GROUP, n, GROUP)), range(n % GROUP or GROUP, n + 1, GROUP))
+    ]
+    if n <= GROUP or 4 * GROUP * sum(r for _, r in old) >= 3 * pow2_from_8(n) * width:
+        assert plan is None
+    else:
+        assert [(rows.tolist(), rung) for rows, rung in plan] == [(rows.tolist(), rung) for rows, rung in old]
+
+
+@pytest.fixture(scope="module")
+def doc_embedder():
+    """The toy grouped-query trunk at 2,048 positions, float32."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.xpacks.llm._trunk import TrunkConfig, TrunkRuntime
+
+    with open(os.path.join(ROOT, "benchmarks", "configs", "command-a-plus-05-2026.json"), encoding="utf-8") as f:
+        body = json.load(f)
+    toy = body.pop("rehearse")
+    body.update({k: v for k, v in toy.items() if k not in ("embedder", "index", "published")})
+    body["published"] = {"num_experts": 8}
+    embedder = SentenceTransformerEmbedder(trunk=TrunkConfig.from_dict(body, name="toy-docs"), max_len=2048)
+    embedder.runtime = TrunkRuntime(embedder.runtime.config, max_len=2048, seed=5, dtype=jnp.float32)
+    return embedder
+
+
+def test_documents_ride_in_groups_under_8_rows_and_alone_give_the_same_vectors(doc_embedder, compiles):
+    lengths = [600, 1500, 40, 1100, 2000, 700, 300, 90, 50, 1900]
+    texts = texts_of(lengths, seed=7)
+    got = np.stack(doc_embedder._embed_batch(texts))
+    span = forward_span().attributes
+    # from the long end: four on the 2,048 rung; 700 and 600 on 1,024 and, in their two padding rows, 300 and 90; 50 and 40 on 64, a remainder padded to a group
+    assert span["groups"] == 3 and span["tokens_padded"] == 4 * 2048 + 4 * 1024 + GROUP * 64
+    assert span["tokens_real"] == sum(lengths) and span["batch_bucket"] == GROUP and span["len_bucket"] == 2048
+    assert span["attn_pairs_visited"] > span["attn_pairs_allowed"] > 0
+    shapes = doc_embedder.runtime._fwd._cache_size()
+    assert shapes == 2 + 3  # (4, 2048), (4, 1024) and the ladder 16, 32, 64 of the group closed by rows: none above it
+    for i in (1, 2, 4):
+        alone = doc_embedder._embed_batch([texts[i]])[0]
+        assert np.abs(alone - got[i]).max() < 2e-4
+    alone_span = forward_span().attributes
+    assert alone_span["groups"] == 1 and alone_span["batch_bucket"] == 2 and alone_span["tokens_padded"] == 2 * 2048
+    # a later tick of the same rungs compiles nothing
+    before = len(compiles)
+    doc_embedder._embed_batch(texts_of([650, 1400, 30, 1200, 1900, 800, 280, 70, 60, 2000], seed=8))
+    assert len(compiles) == before and forward_span().attributes["groups"] == 3

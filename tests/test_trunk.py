@@ -104,10 +104,14 @@ def test_config_file_gives_the_cut_layer_table():
 def test_an_unknown_kind_is_named():
     config = TrunkConfig.from_dict(rehearse_dict(), hc_mult=1)
     assert config.layer_table()[0].residual == "add"
-    with pytest.raises(NotImplementedError, match="'add'"):
-        _trunk.param_shapes(config)
+    assert set(_trunk.param_shapes(config)["layers"][0]) == {"attn_norm", "attn", "ffn_norm", "ffn"}
+    linear = TrunkConfig.from_dict(rehearse_dict(), layer_types=("linear_attention",) * 3)
+    with pytest.raises(NotImplementedError, match="'linear_attention'"):
+        _trunk.param_shapes(linear)
     with pytest.raises(ValueError, match="scoring_func"):
         TrunkConfig(scoring_func="softmax")
+    with pytest.raises(ValueError, match="use_qk_norm"):
+        TrunkConfig(use_qk_norm=True)
 
 
 # -- (b) each block kind alone ---------------------------------------------------
@@ -166,7 +170,7 @@ def test_residual_block_and_sinkhorn(blocks):
     n = config.hc_mult
     streams = jax.random.normal(jax.random.PRNGKey(6), (n,) + h.shape, jnp.float32)
     sublayer = lambda u: jnp.tanh(u) * 0.5  # noqa: E731
-    got = _trunk.RESIDUAL["mhc"].apply(p, streams, sublayer, config)
+    got = _trunk._mhc(p, streams, sublayer, config)  # one sub-layer of the kind's layer
     ref_streams = jnp.transpose(streams, (1, 2, 0, 3))
     want = ref.residual(p, ref_streams, sublayer, body)
     assert np.abs(np.asarray(jnp.transpose(got, (1, 2, 0, 3))) - np.asarray(want)).max() < 1e-4
@@ -320,3 +324,347 @@ def test_embedder_takes_the_path_of_a_config_file():
     assert embedder.get_embedding_dimension() == 3584 and len(config.layer_table()) == 6
     with pytest.raises(TypeError):
         SentenceTransformerEmbedder(trunk={"hidden_size": 64})  # a dict is no path: TrunkConfig.from_dict
+
+
+# -- (g) grouped-query trunks: command-a-plus-05-2026 at its rehearse sizes ----------
+
+from benchmarks.harness import reference_gqa as gqa_ref  # noqa: E402
+from pathway_tpu.ops import block_attention  # noqa: E402
+
+GQA_FILE = os.path.join(ROOT, "benchmarks", "configs", "command-a-plus-05-2026.json")
+
+
+def gqa_dict(**changes) -> dict:
+    with open(GQA_FILE, encoding="utf-8") as f:
+        body = json.load(f)
+    toy = body.pop("rehearse")
+    for key, value in toy.items():
+        if isinstance(value, dict) and isinstance(body.get(key), dict):
+            body[key] = {**body[key], **value}
+        else:
+            body[key] = value
+    body.update(changes)
+    return body
+
+
+@pytest.fixture(scope="module")
+def gqa():
+    body = gqa_dict()
+    return body, TrunkConfig.from_dict(body, name="toy-gqa")
+
+
+@pytest.fixture(scope="module")
+def gqa_runtime(gqa):
+    _body, config = gqa
+    return TrunkRuntime(config, max_len=128, seed=7, dtype=jnp.float32)
+
+
+def reference_rows(params, ids, mask, body):
+    return np.stack(
+        [np.asarray(gqa_ref.encode(params, row, int(m.sum()), body)[0]) for row, m in zip(ids, mask)]
+    )
+
+
+def test_the_config_file_reads_as_the_issue_says():
+    config = TrunkConfig.from_file(GQA_FILE, name="command-a-plus-05-2026")
+    table = config.layer_table()
+    assert [k.attention for k in table] == ["gqa_window"] * 3 + ["gqa_full"]
+    assert {k.ffn for k in table} == {"moe"} and {k.residual for k in table} == {"parallel"}
+    assert (config.hidden_size, config.num_attention_heads, config.num_key_value_heads, config.head_dim) == (4096, 128, 8, 128)
+    assert (config.n_routed_experts, config.held, config.num_experts_per_tok, config.n_shared_experts) == (128, (0, 16), 8, 4)
+    assert (config.moe_intermediate_size, config.sliding_window, config.vocab_size) == (4096, 4096, 32768)
+    assert config.norm_eps == 1e-5 and config.routed_scaling_factor == 1.0 and config.topk_method == "greedy"
+    assert config.shared_expert_combination_strategy == "average"
+    shapes = _trunk.param_shapes(config)
+    assert "bias" not in shapes["layers"][0]["ffn"] and set(shapes["layers"][0]) == {"norm", "attn", "ffn"}
+    sizes = [int(np.prod(s)) for s, _ in jax.tree_util.tree_leaves(shapes, is_leaf=_trunk._is_leaf)]
+    assert 4.72e9 < sum(sizes) < 4.74e9  # ISSUE 32's arithmetic: 4,598.9M in the layers + 134.2M of embedding
+
+
+@pytest.mark.parametrize("rows, seed", [(3, 0), (5, 1), (8, 2)])
+def test_gqa_forward_float32_matches_the_reference_past_the_window(gqa, gqa_runtime, rows, seed):
+    body, config = gqa
+    ids, mask = batch(rows, 128, seed)  # row 0 fills 128 positions: 8 windows of 16
+    assert mask.sum(axis=1).max() > 4 * config.sliding_window
+    got, info = gqa_runtime.forward(ids, mask)
+    want = reference_rows(gqa_runtime.params, ids, mask, body)
+    assert np.linalg.norm(got - want, axis=1).max() < F32_TOL
+    assert info["attn_pairs_allowed"] == sum(
+        block_attention.pairs_allowed(int(t), w) for t in mask.sum(axis=1) for w in (16, 16, 16, None)
+    )
+    assert info["attn_pairs_visited"] == info["batch_bucket"] * 4 * 128 * 128  # one block a row at this width
+
+
+def test_gqa_forward_bfloat16_follows_its_own_experts(gqa):
+    body, config = gqa
+    runtime = TrunkRuntime(config, max_len=128, seed=8)
+    ids, mask = batch(4, 64, 3)
+    got, info = runtime.forward(ids, mask, routing=True)
+    choice = info["expert_choice"]
+    assert choice.shape == (4, 4, 64, 2) and ((choice >= 0).all(axis=-1) == (mask > 0)[None]).all()
+    want = np.stack(
+        [
+            np.asarray(gqa_ref.encode(runtime.params, ids[i], int(mask[i].sum()), body, forced=choice[:, i])[0])
+            for i in range(4)
+        ]
+    )
+    assert np.linalg.norm(got - want, axis=1).max() < BF16_TOL
+
+
+@pytest.fixture(scope="module")
+def gqa_blocks(gqa):
+    body, config = gqa
+    params = f32_tree(_trunk.init_params(config, 13, jnp.float32))
+    h = jax.random.normal(jax.random.PRNGKey(9), (2, 48, config.hidden_size), jnp.float32)
+    return body, config, params, h
+
+
+@pytest.mark.parametrize("kind, types", [("gqa_window", "sliding_attention"), ("gqa_full", "full_attention")])
+def test_gqa_attention_blocks(gqa_blocks, kind, types):
+    body, config, params, h = gqa_blocks
+    p = params["layers"][0]["attn"]
+    ctx = {"rope_pairs": _trunk.interleaved_rope_tables(config, h.shape[1])}
+    got = np.asarray(_trunk.ATTENTION[kind].apply(p, h, config, ctx))
+    want = np.stack([np.asarray(gqa_ref.attention(p, row, body, types)) for row in h])
+    assert np.abs(got - want).max() < 1e-4
+    other = "gqa_full" if kind == "gqa_window" else "gqa_window"
+    assert np.abs(got - np.asarray(_trunk.ATTENTION[other].apply(p, h, config, ctx))).max() > 1e-2
+
+
+def test_a_full_layer_turns_nothing_and_a_window_layer_by_interleaved_pairs(gqa_blocks):
+    _body, config, params, h = gqa_blocks
+    p = params["layers"][0]["attn"]
+    short = h[:, : config.sliding_window]  # inside one window the masks agree: what differs is the rotary
+    ctx = {"rope_pairs": _trunk.interleaved_rope_tables(config, short.shape[1])}
+    turned = _trunk.ATTENTION["gqa_window"].apply(p, short, config, ctx)
+    plain = _trunk.ATTENTION["gqa_full"].apply(p, short, config, ctx)
+    assert np.abs(np.asarray(turned) - np.asarray(plain)).max() > 1e-2
+    assert np.abs(np.asarray(turned[:, 0]) - np.asarray(plain[:, 0])).max() < 1e-5  # position 0 turns by nothing
+    # the program turns de-interleaved halves, the reference interleaved pairs: the same logits
+    q = jax.random.normal(jax.random.PRNGKey(1), (1, 8, 24, 16), jnp.float32)
+    k = jax.random.normal(jax.random.PRNGKey(2), (1, 8, 24, 16), jnp.float32)
+    cos, sin = _trunk.interleaved_rope_tables(config, 24)
+    halves = lambda x: jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)  # noqa: E731
+    mine = jnp.einsum("bhqe,bhke->bhqk", _trunk._rotate_pairs(halves(q), cos, sin), _trunk._rotate_pairs(halves(k), cos, sin))
+    theirs = jnp.einsum(
+        "qhe,khe->hqk",
+        gqa_ref.rotate_pairs(jnp.moveaxis(q[0], 0, 1), config.rope_theta),
+        gqa_ref.rotate_pairs(jnp.moveaxis(k[0], 0, 1), config.rope_theta),
+    )
+    assert np.abs(np.asarray(mine[0]) - np.asarray(theirs)).max() < 1e-4
+    pair = gqa_ref.rotate_pairs(jnp.ones((3, 1, 4)), 10.0)  # dims (0, 1) turn by t, (2, 3) by t / sqrt(10)
+    assert np.allclose(pair[2, 0], [np.cos(2) - np.sin(2), np.cos(2) + np.sin(2)] + [np.cos(2 / 10**0.5) - np.sin(2 / 10**0.5), np.cos(2 / 10**0.5) + np.sin(2 / 10**0.5)], atol=1e-5)
+
+
+def test_query_head_j_reads_key_value_head_j_over_group(gqa_blocks):
+    _body, config, params, h = gqa_blocks
+    p = dict(params["layers"][0]["attn"])
+    heads, width = config.num_attention_heads, config.head_dim
+    group = heads // config.num_key_value_heads
+    p["wo"] = jnp.eye(heads * width).reshape(heads, width, heads * width)  # the heads' outputs side by side
+    base = np.asarray(_trunk.ATTENTION["gqa_full"].apply(p, h, config, {})).reshape(2, 48, heads, width)
+    p["wv"] = p["wv"].at[:, 1].multiply(2.0)  # key-value head 1 alone
+    moved = np.asarray(_trunk.ATTENTION["gqa_full"].apply(p, h, config, {})).reshape(2, 48, heads, width)
+    changed = np.abs(moved - base).max(axis=(0, 1, 3)) > 1e-6
+    assert changed.tolist() == [group <= j < 2 * group for j in range(heads)]
+
+
+@pytest.mark.parametrize("window", [1, 5, 16])
+def test_the_window_counts_the_token_itself(window):
+    # a value only position s carries reaches t while t - s <= window - 1 and not at t - s = window
+    length, s = 40, 7
+    q = jnp.zeros((1, 1, 2, length, 8))  # flat logits: every allowed key weighs the same
+    k = jnp.zeros((1, 1, length, 8))
+    v = jnp.zeros((1, 1, length, 8)).at[0, 0, s].set(1.0)
+    out = np.asarray(block_attention.attention(q, k, v, scale=1.0, window=window, block_q=8, block_k=16))[0, 0, 0, :, 0]
+    seen = out > 0
+    assert seen.tolist() == [s <= t <= s + window - 1 for t in range(length)]
+    assert np.allclose(out[seen], 1.0 / np.minimum(np.arange(length)[seen] + 1, window))
+
+
+def materialised(q, k, v, scale, window):
+    length = q.shape[3]
+    logits = jnp.einsum("bhgtd,bhsd->bhgts", q, k, precision="highest") * scale
+    t, s = jnp.arange(length)[:, None], jnp.arange(length)[None, :]
+    allowed = (s <= t) if window is None else (s <= t) & (t - s < window)
+    return jnp.einsum("bhgts,bhsd->bhgtd", jax.nn.softmax(jnp.where(allowed, logits, -jnp.inf), axis=-1), v, precision="highest")
+
+
+@pytest.mark.parametrize(
+    "length, window, block_q, block_k",
+    [(64, None, 16, 32), (64, 16, 16, 32), (128, 16, 16, 16), (128, 40, 32, 64), (100, 16, None, None),
+     (160, 48, 32, 64), (32, 4096, None, None), (96, 33, 32, 16)],
+)
+def test_blocked_attention_is_materialised_attention(length, window, block_q, block_k):
+    keys = jax.random.split(jax.random.PRNGKey(length), 3)
+    q = 2.0 * jax.random.normal(keys[0], (2, 2, 4, length, 16))
+    k, v = jax.random.normal(keys[1], (2, 2, length, 16)), jax.random.normal(keys[2], (2, 2, length, 16))
+    got = block_attention.attention(q, k, v, scale=0.25, window=window, block_q=block_q, block_k=block_k)
+    assert np.abs(np.asarray(got) - np.asarray(materialised(q, k, v, 0.25, window))).max() < 2e-5
+    # the blocks visited hold every allowed pair and, with a window, not every causal block
+    size_q, size_k = block_attention.blocks(length, block_q, block_k)
+    steps = block_attention.visited_steps(length, window, size_q, size_k)
+    assert block_attention.pairs_visited(length, window, block_q, block_k) >= block_attention.pairs_allowed(length, window)
+    if window is not None and window + size_q + size_k < length:
+        assert sum(steps) < sum(block_attention.visited_steps(length, None, size_q, size_k))
+
+
+def test_a_window_layer_visits_under_half_of_the_causal_blocks_at_16k():
+    whole = block_attention.pairs_visited(16384, None)
+    assert 0.40 < block_attention.pairs_visited(16384, 4096) / whole < 0.50
+    assert block_attention.pairs_allowed(16384, 4096) == 4096 * 4097 // 2 + (16384 - 4096) * 4096
+
+
+def test_the_parallel_residual_gives_both_blocks_one_normed_input(gqa_blocks):
+    _body, config, params, h = gqa_blocks
+    p = {"norm": params["layers"][0]["norm"], "attn": "a", "ffn": "f"}
+    seen = {}
+
+    def attend(params_of, u):
+        seen[params_of] = u
+        return 2.0 * u
+
+    def feed(params_of, u):
+        seen[params_of] = u
+        return jnp.tanh(u)
+
+    got = _trunk.RESIDUAL["parallel"].layer(p, h, attend, feed, config)
+    normed = _trunk.layer_norm(h, p["norm"], config.norm_eps)
+    assert seen["a"] is seen["f"] and np.abs(np.asarray(seen["a"]) - np.asarray(normed)).max() == 0
+    assert np.abs(np.asarray(got) - np.asarray(h + 2.0 * normed + jnp.tanh(normed))).max() < 1e-6
+    want = gqa_ref.layer_norm(h, p["norm"], config.norm_eps)
+    assert np.abs(np.asarray(normed) - np.asarray(want)).max() < 1e-5
+    assert np.abs(np.asarray(normed).mean(axis=-1)).max() < 0.05  # mean-centred, up to the gain's spread
+
+
+def test_shared_experts_are_averaged_and_the_chosen_weights_sum_to_one(gqa_blocks):
+    body, config, params, h = gqa_blocks
+    p = params["layers"][1]["ffn"]
+    flat = h.reshape(-1, h.shape[-1])
+    ctx = {"valid": jnp.ones(flat.shape[0], bool), "expert_counts": [], "expert_choice": []}
+    got = np.asarray(_trunk.FFN["moe"].apply(p, h, config, ctx)).reshape(flat.shape)
+    want, scores = gqa_ref.expert_ffn(p, flat, body, experts_held=(0, 4))
+    assert np.abs(got - np.asarray(want)).max() < 1e-4
+    # the shared part is the mean of the experts that lie side by side in the one FFN
+    f, n = config.moe_intermediate_size, config.n_shared_experts
+    singles = [
+        _trunk._gated_ffn(
+            {"w_gate": p["shared"]["w_gate"][:, i * f : (i + 1) * f], "w_up": p["shared"]["w_up"][:, i * f : (i + 1) * f],
+             "w_down": p["shared"]["w_down"][i * f : (i + 1) * f]}, flat)
+        for i in range(n)
+    ]
+    routed, _counts, choice = moe.expert_layer(
+        flat, ctx["valid"], p["router"], None, p["w_gate"], p["w_up"], p["w_down"],
+        top_k=2, scale=1.0, experts_held=(0, 4),
+    )
+    assert np.abs(got - np.asarray(routed + sum(singles) / n)).max() < 1e-4
+    weights, chosen = moe.route(flat, p["router"], None, top_k=2, scale=1.0)
+    assert np.abs(np.asarray(weights).sum(axis=1) - 1).max() < 1e-6
+    own = np.argsort(np.asarray(scores), axis=-1)[:, -2:]
+    assert (np.sort(np.asarray(chosen), axis=-1) == np.sort(own, axis=-1)).all() and (np.asarray(choice) == np.asarray(chosen)).all()
+
+
+def test_all_the_shares_of_a_layer_add_up_to_the_uncut_layer(gqa):
+    """Eight experts over shares of 2: what the four shares' routed parts
+    give, with the attention, the shared experts and x counted once, is the
+    uncut layer as the reference computes it."""
+    body, _config = gqa
+    whole_body = gqa_dict(num_experts=8, experts_held=None)
+    whole = TrunkConfig.from_dict(whole_body, name="uncut")
+    assert whole.held == (0, 8) and whole.n_routed_experts == 8
+    params = f32_tree(_trunk.init_params(whole, 17, jnp.float32))
+    p = params["layers"][0]
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 40, whole.hidden_size), jnp.float32)
+    h = _trunk.layer_norm(x, p["norm"], whole.norm_eps)
+    flat = h.reshape(-1, h.shape[-1])
+    valid = jnp.ones(flat.shape[0], bool)
+    parts = []
+    for first in range(0, 8, 2):
+        routed, _counts, _choice = moe.expert_layer(
+            flat, valid, p["ffn"]["router"], None, p["ffn"]["w_gate"][first : first + 2],
+            p["ffn"]["w_up"][first : first + 2], p["ffn"]["w_down"][first : first + 2],
+            top_k=2, scale=1.0, experts_held=(first, 2),
+        )
+        parts.append(np.asarray(routed))
+    assert all(np.abs(part).max() > 0 for part in parts)
+    ctx = {"rope_pairs": _trunk.interleaved_rope_tables(whole, 40)}
+    attention = np.asarray(_trunk.ATTENTION["gqa_window"].apply(p["attn"], h, whole, ctx))[0]
+    shared = np.asarray(_trunk._gated_ffn(p["ffn"]["shared"], flat)) / whole.n_shared_experts
+    want, _scores = gqa_ref.layer(p, x[0], None, whole_body, "sliding_attention")
+    assert np.abs(np.asarray(x[0]) + attention + shared + sum(parts) - np.asarray(want)).max() < 2e-4
+    # and a cut layer is the program's own layer on its share
+    cut = TrunkConfig.from_dict(gqa_dict(num_experts=2, experts_held=[2, 2]), name="cut")
+    held = dict(p, ffn={k: (v[2:4] if k in ("w_gate", "w_up", "w_down") else v) for k, v in p["ffn"].items()})
+    ctx.update(valid=valid, expert_counts=[], expert_choice=[])
+
+    def attend(p_attn, u):
+        return _trunk.ATTENTION["gqa_window"].apply(p_attn, u, cut, ctx)
+
+    def feed(p_ffn, u):
+        return _trunk.FFN["moe"].apply(p_ffn, u, cut, ctx)
+
+    got = np.asarray(_trunk.RESIDUAL["parallel"].layer(held, x, attend, feed, cut))[0]
+    assert np.abs(got - (np.asarray(x[0]) + attention + shared + parts[1])).max() < 2e-4
+
+
+def test_gqa_padding_and_companions_change_no_vector(gqa_runtime):
+    ids, mask = batch(rows=3, width=64, seed=5)
+    together = gqa_runtime.forward_ids(ids, mask)
+    for i in range(3):
+        alone = gqa_runtime.forward_ids(ids[i : i + 1], mask[i : i + 1])
+        assert np.abs(alone[0] - together[i]).max() < 1e-5
+    wide, info = gqa_runtime.forward(np.pad(ids, ((0, 0), (0, 64))), np.pad(mask, ((0, 0), (0, 64))))
+    assert info["len_bucket"] == 128 and np.abs(wide - together).max() < 1e-5
+
+
+# -- (h) the grouped matmul's column blocks and the expert layer's passes --------------
+
+
+@pytest.mark.parametrize(
+    "k, n, block",
+    [(3584, 1024, 1024), (1024, 3584, 3584), (4096, 4096, 1024), (64, 32, 32)],
+    ids=["xing4-up", "xing4-down", "command-a", "toy"],
+)
+def test_grouped_matmul_in_column_blocks_is_ragged_dot(k, n, block):
+    assert moe.column_block(k, n, 2) == block
+    groups, sizes = 3, np.array([128, 0, 256])
+    rng = np.random.default_rng(k + n)
+    x = jnp.asarray(rng.standard_normal((512, k)), jnp.bfloat16)  # the last tile is past every group
+    w = jnp.asarray(rng.standard_normal((groups, k, n)) / np.sqrt(k), jnp.bfloat16)
+    tile_group, used = moe.tile_groups(jnp.asarray(sizes, jnp.int32), 4)
+    assert np.asarray(tile_group).tolist() == [0, 2, 2, 2] and int(used[0]) == 3
+    got = np.asarray(moe.grouped_matmul(x, w, tile_group, used), np.float32)[:384]
+    want = np.asarray(jax.lax.ragged_dot(x[:384], w, jnp.asarray(sizes, jnp.int32)), np.float32)
+    assert np.abs(got - want).max() < 0.05 and np.abs(want).max() > 1
+
+
+@pytest.mark.parametrize("routing", ["all_to_the_held", "spread"])
+def test_an_expert_layer_in_passes_drops_no_pair(routing):
+    """2 of 16 experts held: a pass has room for a quarter of the pairs;
+    with every token sent to the held experts it takes four passes and more."""
+    tokens, d, f, experts, top_k = 600, 32, 16, 16, 2
+    rng = np.random.default_rng(3)
+    h = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
+    router = rng.standard_normal((d, experts)).astype(np.float32) * 0.1
+    if routing == "all_to_the_held":
+        router[:, 4:6] += 4.0 * np.sign(router[:, 4:6])  # |logit| large ...
+        h = jnp.abs(h) * jnp.sign(jnp.asarray(router[:, 4]))[None, :]  # ... and positive for experts 4 and 5
+        router[:, 5] = router[:, 4] * 0.9
+    w = lambda *shape: jnp.asarray(rng.standard_normal(shape) / np.sqrt(shape[1]), jnp.float32)  # noqa: E731
+    w_gate, w_up, w_down = w(2, d, f), w(2, d, f), w(2, f, d)
+    valid = jnp.asarray(np.arange(tokens) < tokens - 9)
+    assert moe.pass_rows(tokens * top_k, experts, 2) < moe.plan_rows(tokens * top_k, 2)
+    got, counts, choice = moe.expert_layer(
+        h, valid, jnp.asarray(router), None, w_gate, w_up, w_down, top_k=top_k, scale=1.0, experts_held=(4, 2)
+    )
+    if routing == "all_to_the_held":
+        assert int(np.asarray(counts)[4:6].sum()) == 2 * (tokens - 9)
+    body = {"num_experts_per_tok": top_k, "norm_topk_prob": True}
+    p = {"router": jnp.asarray(router), "w_gate": w_gate, "w_up": w_up, "w_down": w_down}
+    forced = jnp.asarray(choice)
+    want, _scores = gqa_ref.expert_ffn(p, h, body, experts_held=(4, 2), shared=False, forced=forced)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-4
+    assert np.abs(np.asarray(got)[tokens - 9 :]).max() == 0
+    busiest = int(np.asarray(counts)[4:6].max())
+    sparse, _scores = gqa_ref.expert_ffn(p, h, body, experts_held=(4, 2), shared=False, forced=forced, busiest=max(busiest, 1))
+    assert np.abs(np.asarray(sparse) - np.asarray(want)).max() < 1e-4

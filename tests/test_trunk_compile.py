@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from pathway_tpu.ops import knn, moe
+from pathway_tpu.ops import block_attention, knn, moe
 
 TOKENS, D, F, EXPERTS, TOP_K = 16384, 3584, 1024, 64, 4  # Xing4.0-29B-A4B, a 32 x 512 tick
 
@@ -90,3 +90,41 @@ def test_search_program_sorts_nothing_of_the_corpus_size(one_chip, no_cache, buc
     # the block maxima and the gather (nothing of their size is a temporary
     # beside them)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * bucket * rows * 4 + 2**20
+
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.mark.parametrize("rows, width", [(1, 16384), (2, 8192)], ids=["1x16384", "2x8192"])
+def test_the_document_forward_fits_a_v5e_and_keeps_no_logits(one_chip, no_cache, monkeypatch, rows, width):
+    """command-a-plus-05-2026's cut (4 layers, 16 of 128 experts, published
+    widths) at the 16,384 positions of a whole group: parameters and
+    temporaries under the chip's 16.9 GB with room for the index, attention
+    as the blocked kernel (no [B, H, T, T] array anywhere), the experts'
+    4096 x 4096 matrices staged in column blocks."""
+    import functools
+
+    from pathway_tpu.xpacks.llm import _trunk
+
+    monkeypatch.setattr(moe, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(block_attention, "pallas_interpret", lambda: False)
+    config = _trunk.TrunkConfig.from_file(
+        os.path.join(ROOT, "benchmarks", "configs", "command-a-plus-05-2026.json"), name="command-a-plus-05-2026"
+    )
+    template = jax.eval_shape(lambda: _trunk.init_params(config, 0, jnp.bfloat16))
+    params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), template)
+    compiled = jax.jit(functools.partial(_trunk.forward, config=config)).lower(
+        params,
+        jax.ShapeDtypeStruct((rows, width), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert 9.4e9 < memory.argument_size_in_bytes < 9.6e9  # 4,733M parameters at bfloat16
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.5e9  # of 16.9 GB; the index takes 0.54
+    text = compiled.as_text()
+    # four attention kernels and twelve grouped matmuls, each of them Mosaic's
+    assert text.count("tpu_custom_call") >= 16
+    assert block_attention.ATTN_KERNEL_NAME in text and moe.GMM_KERNEL_NAME in text
+    # the largest array is an expert tensor [16, 4096, 4096]: [B, H, T, T] logits would be 64 to 128 times that
+    assert _largest_shape(text) == 16 * 4096 * 4096 < rows * 128 * width * width // 32
+    assert moe.column_block(4096, 4096, 2) == 1024
