@@ -9,6 +9,7 @@ from __future__ import annotations
 import hashlib
 import re
 import struct
+from itertools import chain, repeat
 from typing import Sequence
 
 import numpy as np
@@ -20,10 +21,40 @@ CLS_ID = 1
 _RESERVED = 2
 
 
+# The bound of a tokenizer's word-to-id map: entries, and the longest word
+# worth one. A run of letters or digits longer than that is a key or a number
+# and does not come again. An entry is at most some 160 bytes (the word, its
+# id, its slot), so a full map is 40 MB at the very worst, however long the
+# process streams.
+_MAP_ENTRIES = 262_144
+_MAP_WORD_CHARS = 32
+
+
 class HashingTokenizer:
+    """Feature hashing: a token's id is ``_hash`` of its text, nothing else.
+
+    A word is hashed once, not once a token: each instance keeps a map from
+    word to id (ids depend on ``vocab_size``, so instances share nothing),
+    bounded by ``_MAP_ENTRIES``. A word longer than ``_MAP_WORD_CHARS`` is
+    hashed every time and never stored. A new word that finds the map full
+    makes it **start afresh**: a vocabulary that drifts keeps its current
+    words, and the common words of running text are back after one hash
+    each. A stream of words that never repeat pays a read that fails and a
+    store above the hash, a token.
+
+    ``words`` and ``word_hits`` count the lookups and those the map answered.
+    They only grow; a caller that wants one call's share reads them before
+    and after. Under concurrent callers they may lose an update, and the map
+    may pass its bound by what the other callers' batches store: they feed a
+    span's attributes and a memory bound, never an id.
+    """
+
     def __init__(self, vocab_size: int = 30522, lowercase: bool = True):
         self.vocab_size = vocab_size
         self.lowercase = lowercase
+        self._ids: dict[str, int] = {}
+        self.words = 0
+        self.word_hits = 0
 
     def _hash(self, token: str) -> int:
         h = struct.unpack(
@@ -36,9 +67,36 @@ class HashingTokenizer:
             text = text.lower()
         return _TOKEN_RE.findall(text)
 
+    def _word_ids(self, words: list[str]) -> np.ndarray:
+        """The ids of ``words``: one read of the map each, and the words it
+        lacks hashed and stored. No lock: dictionary reads and writes are
+        atomic under the interpreter lock, and two threads that miss the
+        same word both hash it and store the same id."""
+        ids = self._ids
+        out = np.fromiter(map(ids.get, words, repeat(PAD_ID)), np.int32, len(words))
+        missed = np.flatnonzero(out == PAD_ID).tolist()  # no word's id is PAD_ID
+        if missed:
+            hashed = []
+            for i in missed:
+                word = words[i]
+                word_id = ids.get(word)  # missed twice in one call: hashed once
+                if word_id is None:
+                    word_id = self._hash(word)
+                    if len(word) <= _MAP_WORD_CHARS and _MAP_ENTRIES:
+                        if len(ids) >= _MAP_ENTRIES:
+                            ids.clear()
+                        ids[word] = word_id
+                hashed.append(word_id)
+            out[missed] = hashed
+        self.words += len(words)
+        self.word_hits += len(words) - len(missed)
+        return out
+
     def encode(self, text: str, max_len: int) -> list[int]:
-        ids = [CLS_ID] + [self._hash(t) for t in self.tokenize(text)]
-        return ids[:max_len]
+        """``[CLS] + [_hash(t) for t in tokenize(text)]``, cut to ``max_len``:
+        the plain statement of what a row of ``encode_batch`` holds."""
+        words = self.tokenize(text)[: max(max_len - 1, 0)]
+        return ([CLS_ID] + self._word_ids(words).tolist())[:max_len]
 
     def encode_batch(
         self, texts: Sequence[str], max_len: int
@@ -48,16 +106,20 @@ class HashingTokenizer:
         the widest shape a forward of these texts needs, not the shape every
         text is forwarded at: the embedder reads the real lengths off the
         mask and may forward column slices ``[:, :rung]`` of length-sorted
-        groups (``embedders.length_groups``)."""
-        encoded = [self.encode(t, max_len) for t in texts]
-        longest = max((len(e) for e in encoded), default=1)
-        bucket = _bucket_len(longest, max_len)
-        ids = np.full((len(texts), bucket), PAD_ID, dtype=np.int32)
-        mask = np.zeros((len(texts), bucket), dtype=np.float32)
-        for i, e in enumerate(encoded):
-            ids[i, : len(e)] = e
-            mask[i, : len(e)] = 1.0
-        return ids, mask
+        groups (``embedders.length_groups``).
+
+        One pass: every word of the batch goes through the map in one
+        iterator, and ids and mask are written with array operations."""
+        keep = max(max_len - 1, 0)
+        words = [self.tokenize(t)[:keep] for t in texts]
+        counts = np.fromiter(map(len, words), np.intp, len(words))
+        bucket = _bucket_len(int(counts.max(initial=0)) + 1, max_len)  # CLS and the words
+        real = np.arange(bucket) <= counts[:, None]
+        ids = np.full((len(words), bucket), PAD_ID, dtype=np.int32)
+        ids[:, :1] = CLS_ID
+        # row by row, left to right: the order the words were chained in
+        ids[:, 1:][real[:, 1:]] = self._word_ids(list(chain.from_iterable(words)))
+        return ids, real.astype(np.float32)
 
     def count_tokens(self, text: str) -> int:
         return len(self.tokenize(text))

@@ -141,6 +141,13 @@ def _summed(infos: list[dict]) -> dict:
     return total
 
 
+def _word_counts(tokenizer) -> tuple[int, int] | None:
+    """What a tokenizer that keeps a word-to-id map has counted so far: (words
+    looked up, those the map answered); ``None`` of one that keeps none."""
+    words = getattr(tokenizer, "words", None)
+    return None if words is None else (words, tokenizer.word_hits)
+
+
 class BaseEmbedder(UDF):
     """UDF str -> np.ndarray; also callable on expressions."""
 
@@ -325,6 +332,7 @@ class SentenceTransformerEmbedder(BaseEmbedder):
             with _tracer.span("embed.batch", model=model, docs=docs) as sp:
                 t0 = _time.perf_counter()
                 with _tracer.span("embed.tokenize", docs=docs) as tok:
+                    looked_up = _word_counts(self.tokenizer)
                     ids, mask = self.tokenizer.encode_batch(
                         # runtime.max_len is clamped to the checkpoint's
                         # position table; exceeding it would silently clamp
@@ -336,6 +344,10 @@ class SentenceTransformerEmbedder(BaseEmbedder):
                     len_bucket = int(ids.shape[1])
                     tok.set_attribute("tokens_real", tokens_real)
                     tok.set_attribute("len_bucket", len_bucket)
+                    if looked_up is not None:  # this call's share of the tokenizer's counts
+                        words, hits = _word_counts(self.tokenizer)
+                        tok.set_attribute("words", words - looked_up[0])
+                        tok.set_attribute("word_hits", hits - looked_up[1])
                 with _tracer.span("embed.forward") as fwd:
                     out, parts = self._forward_planned(ids, mask, lengths)
                     # what the runtime really forwarded (the padded shapes,

@@ -683,8 +683,8 @@ def test_embed_batch_splits_into_tokenize_and_forward():
     assert {r.name for r in _children(records, batch)} == {"embed.tokenize", "embed.forward"}
     assert batch.attributes["docs"] == 3 and tokenize.attributes["docs"] == 3
     # one CLS and one id a word: 4 + 2 + 8 real tokens, in a 16-wide length
-    # bucket; 3 texts pad to the 8-row batch bucket, which is what is forwarded
-    assert tokenize.attributes == {"docs": 3, "tokens_real": 14, "len_bucket": 16}
+    # bucket; the 11 words were all in the tokenizer's map from the first call
+    assert tokenize.attributes == {"docs": 3, "tokens_real": 14, "len_bucket": 16, "words": 11, "word_hits": 11}
     assert forward.attributes == {
         "groups": 1, "batch_bucket": 8, "len_bucket": 16, "tokens_real": 14, "tokens_padded": 128,
     }
@@ -694,6 +694,43 @@ def test_embed_batch_splits_into_tokenize_and_forward():
     assert batch.start_perf_ns <= tokenize.start_perf_ns
     assert _end(tokenize) <= forward.start_perf_ns and _end(forward) <= _end(batch)
     assert tokenize.duration_ns + forward.duration_ns <= batch.duration_ns
+
+
+def test_tokenize_span_counts_the_words_and_the_maps_hits():
+    embedder = _toy_embedder()
+    texts = ["alpha beta gamma", "delta", "", "epsilon zeta eta theta ? !"]  # 10 distinct words
+    seen = []
+    for _ in range(2):
+        tracing.get_tracer().clear()
+        embedder._embed_batch(texts)
+        seen.append(_one(_layer_spans(), "embed.tokenize").attributes)
+    assert seen[0] == {"docs": 4, "tokens_real": 14, "len_bucket": 16, "words": 10, "word_hits": 0}
+    assert seen[1] == {**seen[0], "word_hits": 10}
+    for attributes in seen:  # nothing was truncated: every real token but a text's CLS is a word
+        assert attributes["words"] == attributes["tokens_real"] - attributes["docs"]
+    # half new words: the span carries this call's share, not the tokenizer's totals
+    tracing.get_tracer().clear()
+    embedder._embed_batch(["alpha beta", "iota kappa"])
+    assert _one(_layer_spans(), "embed.tokenize").attributes == {
+        "docs": 2, "tokens_real": 6, "len_bucket": 16, "words": 4, "word_hits": 2,
+    }
+    assert (embedder.tokenizer.words, embedder.tokenizer.word_hits) == (24, 12)
+
+
+def test_tokenize_span_of_a_tokenizer_without_a_word_map_has_no_word_counts():
+    embedder = _toy_embedder()
+    plain = embedder.tokenizer
+
+    class NoMap:
+        vocab_size = plain.vocab_size
+
+        def encode_batch(self, texts, max_len):
+            return plain.encode_batch(texts, max_len)
+
+    embedder.tokenizer = NoMap()
+    tracing.get_tracer().clear()
+    embedder._embed_batch(["one two three", "four"])
+    assert _one(_layer_spans(), "embed.tokenize").attributes == {"docs": 2, "tokens_real": 6, "len_bucket": 16}
 
 
 @pytest.mark.parametrize(
