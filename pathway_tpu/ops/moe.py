@@ -1,7 +1,9 @@
 """Sparse expert layer: route, dispatch, grouped matmul, combine.
 
 An expert layer sends each token to ``top_k`` of ``n_experts`` gated
-feed-forward experts and sums what they return, weighted by the router.
+feed-forward experts and sums what they return, weighted by the router
+(``route``: the sigmoid router, bias-corrected where the model has a bias,
+or the top-k of the logits softmaxed over the chosen k).
 Nothing is dropped and there is no capacity factor: the (token, expert)
 pairs are sorted by expert into contiguous groups of rows (``dispatch``),
 every group is multiplied by its own expert's weights in one grouped matmul
@@ -52,9 +54,11 @@ _VMEM_LIMIT = 64 * 1024 * 1024  # two weight blocks and the row tiles
 _WEIGHT_BLOCK_BYTES = 8 * 1024 * 1024  # of one staged block of an expert's matrix: 4096 x 1024 bf16
 
 
-def route(h, router, bias, *, top_k: int, scale: float, normalise: bool = True):
-    """Sigmoid router, its choice corrected by a bias where one is given
-    (``noaux_tc``, one group; ``bias=None``: the top-k of the scores).
+def route(h, router, bias, *, top_k: int, scale: float, normalise: bool = True, scoring: str = "sigmoid"):
+    """``scoring="sigmoid"``: the sigmoid router, its choice corrected by a
+    bias where one is given (``noaux_tc``, one group; ``bias=None``: the
+    top-k of the scores). ``scoring="softmax"``: the top-k of the logits,
+    their weights the softmax over those k (no bias, always normalised).
 
     ``h`` [T, d]; ``router`` [d, E]; ``bias`` [E]. Scores, choice and weights
     are float32: ``s = sigmoid(h W_r)``, the choice is the top-k of
@@ -65,6 +69,9 @@ def route(h, router, bias, *, top_k: int, scale: float, normalise: bool = True):
         router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
+    if scoring == "softmax":
+        picked, choice = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(picked, axis=1) * scale, choice.astype(jnp.int32)
     scores = jax.nn.sigmoid(logits)
     corrected = scores if bias is None else scores + bias.astype(jnp.float32)
     _, choice = jax.lax.top_k(corrected, top_k)
@@ -250,7 +257,7 @@ def rows_computed(counts) -> int:
 
 def expert_layer(
     h, valid, router, bias, w_gate, w_up, w_down, *,
-    top_k: int, scale: float, normalise: bool = True, experts_held=None,
+    top_k: int, scale: float, normalise: bool = True, experts_held=None, scoring: str = "sigmoid",
 ):
     """The routed part of an expert layer on ``h`` [T, d]: what the held
     experts add, float32 [T, d], the router's token counts [E], and its
@@ -258,7 +265,9 @@ def expert_layer(
     the router's correction bias [E], or None where it has none."""
     n_experts = router.shape[1]
     with jax.named_scope("trunk.moe.route"):
-        weights, choice = route(h, router, bias, top_k=top_k, scale=scale, normalise=normalise)
+        # the sigmoid router is called as it always was: a stand-in for it need not know the other
+        other = {} if scoring == "sigmoid" else {"scoring": scoring}
+        weights, choice = route(h, router, bias, top_k=top_k, scale=scale, normalise=normalise, **other)
         plan = dispatch(choice, valid, n_experts, experts_held)
     rows = plan.src.shape[0]
     tile_group, used = tile_groups(plan.group_sizes, rows // TILE_ROWS)

@@ -11,19 +11,28 @@ by kind, so another architecture adds kinds, not branches. Kinds so far:
   ``ops/block_attention.py`` (no logits in HBM, blocks outside the mask
   skipped, each key-value head read once for its query heads), the first
   inside a sliding window with rotary over interleaved pairs, the second
-  causal over the whole row and unrotated; a model's ``layer_types`` names
-  them layer by layer;
-* feed-forward ``dense`` (gated silu) and ``moe`` (``ops/moe.py``: sigmoid
-  router, its top-k bias-corrected and scaled where the config says so,
-  dropless grouped experts, shared experts summed or averaged);
+  causal over the whole row and unrotated, its softmax scale the config's
+  ``attention_multiplier`` where one is given; ``mamba2``: a Mamba-2 mixer in
+  the attention's place (in-projection, depthwise causal convolution, the
+  chunked state-space scan of ``ops/ssd_scan.py``, gated RMS norm,
+  out-projection), prefill form, no state kept between calls; a model's
+  ``layer_types`` names them layer by layer;
+* feed-forward ``dense`` (gated silu) and ``moe`` (``ops/moe.py``: a sigmoid
+  router, its top-k bias-corrected and scaled where the config says so, or
+  the top-k of the logits softmaxed over the chosen k; dropless grouped
+  experts; shared experts summed or averaged, of the routed width each or of
+  ``shared_intermediate_size`` together);
 * residual ``mhc``: manifold-constrained hyper-connections (arXiv:2512.24880),
   ``hc_mult`` residual streams mixed by a doubly stochastic matrix per token;
-  ``add``: ``x + attn(norm x)``, then ``x + ffn(norm x)``; ``parallel``: one
+  ``add``: ``x + m attn(norm x)``, then ``x + m ffn(norm x)``, ``m`` the
+  config's ``residual_multiplier`` (1 where it has none); ``parallel``: one
   norm a layer, ``x + attn(h) + ffn(h)``;
 * norm ``rms`` and ``layer`` (mean-centred, a gain and no bias).
 
 ``TrunkConfig.from_dict`` reads the key names of the ``model_type`` it is
-given (``cohere2_moe``'s beside the default ones) onto the same fields.
+given (``cohere2_moe``'s and ``granitemoehybrid``'s beside the default ones)
+onto the same fields. The embedded tokens are multiplied by
+``embedding_multiplier`` where the config states one.
 
 ``TrunkRuntime`` has ``EncoderRuntime``'s surface and is what
 ``SentenceTransformerEmbedder(trunk=...)`` runs: the whole forward over a
@@ -47,7 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pathway_tpu.ops import block_attention, moe
+from pathway_tpu.ops import block_attention, moe, ssd_scan
 from pathway_tpu.xpacks.llm._encoder import _bucket_batch
 
 # ``cohere2_moe``'s names for what the fields below hold, and what its
@@ -59,7 +68,20 @@ _COHERE2_MOE_KEYS = {
     "intermediate_size": "moe_intermediate_size",  # no key of its own for one expert's width
 }
 _COHERE2_MOE_FIXED = {"topk_method": "greedy", "routed_scaling_factor": 1.0, "hc_mult": 1}
-_LAYER_TYPES = {"sliding_attention": "gqa_window", "full_attention": "gqa_full"}
+# ``granitemoehybrid``'s: its modelling code has one shared expert, a plain
+# top-k of the logits softmaxed over the chosen k, and one residual stream
+_GRANITE_HYBRID_KEYS = {
+    "num_local_experts": "n_routed_experts",
+    "intermediate_size": "moe_intermediate_size",  # no key of its own for one expert's width
+}
+_GRANITE_HYBRID_FIXED = {
+    "scoring_func": "softmax", "norm_topk_prob": True, "topk_method": "greedy", "routed_scaling_factor": 1.0,
+    "n_shared_experts": 1, "first_k_dense_replace": 0, "hc_mult": 1,
+}
+_LAYER_TYPES = {
+    "sliding_attention": "gqa_window", "full_attention": "gqa_full",
+    "mamba": "mamba2", "attention": "gqa_full",  # granitemoehybrid's names; its attention is unrotated ("nope")
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,17 +147,39 @@ class TrunkConfig:
     use_gated_activation: bool = True
     layer_norm_eps: float | None = None  # given: the norms are layer norms
     shared_expert_combination_strategy: str = "sum"
+    shared_intermediate_size: int = 0  # the shared experts' width together; 0: n_shared_experts of the routed width
+    attention_multiplier: float | None = None  # a grouped-query layer's softmax scale; None: head_dim ** -0.5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # on each branch of the ``add`` residual
+    # Mamba-2 layers (``layer_types`` "mamba")
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    mamba_n_groups: int = 1
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    normalization_function: str = "rmsnorm"
     # the chip's share of the routed experts: (first, count); None holds all
     experts_held: tuple[int, int] | None = None
 
     def __post_init__(self):
         unsupported = {
-            "scoring_func": self.scoring_func == "sigmoid",
+            # softmax over the chosen k is softmax over all, top-k, renormalised
+            "scoring_func": self.scoring_func == "sigmoid" or (self.scoring_func == "softmax" and self.norm_topk_prob),
             "topk_method": self.topk_method in ("noaux_tc", "greedy"),
             "n_group": self.n_group == 1 and self.topk_group == 1,
             "hidden_act": self.hidden_act == "silu",
             "attention_bias": not self.attention_bias,
-            "position_embedding_type": self.position_embedding_type == "rope_gptj",
+            "position_embedding_type": self.position_embedding_type
+            == ("nope" if self.model_type == "granitemoehybrid" else "rope_gptj"),
+            "mamba_n_groups": self.mamba_n_groups == 1,
+            "mamba_proj_bias": not self.mamba_proj_bias,
+            "mamba_conv_bias": self.mamba_conv_bias,
+            "mamba_expand": self.mamba_n_heads * self.mamba_d_head in (0, self.mamba_expand * self.hidden_size),
+            "normalization_function": self.normalization_function == "rmsnorm",
             "rotary_pct": self.rotary_pct == 1,
             "use_qk_norm": not self.use_qk_norm,
             "use_gated_activation": self.use_gated_activation,
@@ -149,18 +193,24 @@ class TrunkConfig:
     @classmethod
     def from_dict(cls, config: dict, **overrides: Any) -> "TrunkConfig":
         """From a ``config.json``'s keys; keys that say nothing about the
-        trunk's shape are passed over. ``model_type`` ``cohere2_moe`` has
-        names of its own for some fields. A file cut to one chip's share
-        (``experts_held``) counts the experts held under the published key
-        and states the published count, the router's width, under
-        ``published``."""
-        if config.get("model_type") == "cohere2_moe":
-            config = {_COHERE2_MOE_KEYS.get(k, k): v for k, v in config.items()}
-            config = {**_COHERE2_MOE_FIXED, **config}
+        trunk's shape are passed over. ``model_type`` ``cohere2_moe`` and
+        ``granitemoehybrid`` have names of their own for some fields. A file
+        cut to one chip's share (``experts_held``) counts the experts held
+        under the published key and states the published count, the router's
+        width, under ``published``."""
+        renamed = {
+            "cohere2_moe": (_COHERE2_MOE_KEYS, _COHERE2_MOE_FIXED, "num_experts"),
+            "granitemoehybrid": (_GRANITE_HYBRID_KEYS, _GRANITE_HYBRID_FIXED, "num_local_experts"),
+        }.get(config.get("model_type"))
+        if renamed is not None:
+            keys, fixed, experts_key = renamed
+            config = {**fixed, **{keys.get(k, k): v for k, v in config.items()}}
             if config.get("experts_held") is not None:
                 config["n_routed_experts"] = config.get("published", {}).get(
-                    "num_experts", config["n_routed_experts"]
+                    experts_key, config["n_routed_experts"]
                 )
+            if not config.get("head_dim"):  # granitemoehybrid states none
+                config["head_dim"] = config["hidden_size"] // config["num_attention_heads"]
         names = {f.name for f in dataclasses.fields(cls)}
         picked = {k: v for k, v in config.items() if k in names}
         if picked.get("layer_types") is not None:
@@ -428,16 +478,79 @@ def _gqa(p, h, c: TrunkConfig, ctx: dict, *, window: bool):
     batch, heads, length, _ = q.shape
     mixed = block_attention.attention(
         q.reshape(batch, kv_heads, heads // kv_heads, length, width), k, v,
-        scale=width**-0.5, window=c.sliding_window if window else None,
+        scale=width**-0.5 if c.attention_multiplier is None else c.attention_multiplier,
+        window=c.sliding_window if window else None,
     ).reshape(q.shape)
     out = jnp.einsum("bhte,hed->btd", mixed, p["wo"].astype(h.dtype), preferred_element_type=jnp.float32)
     return out.astype(h.dtype)
+
+
+def _mamba2_shapes(c: TrunkConfig) -> dict:
+    heads, states = c.mamba_n_heads, c.mamba_d_state
+    inner = heads * c.mamba_d_head
+    channels = inner + 2 * states  # x, B and C go through the convolution; one group
+    return {
+        "w_in": ((c.hidden_size, inner + channels + heads), "kernel"),  # [z | x B C | dt]
+        "conv": ((c.mamba_d_conv, channels), "conv"),
+        "conv_bias": ((channels,), "conv_bias"),
+        "dt_bias": ((heads,), "dt_bias"),
+        "A_log": ((heads,), "a_log"),
+        "D": ((heads,), "ones"),
+        "norm": ((inner,), "gain"),
+        "w_out": ((inner, c.hidden_size), "kernel"),
+    }
+
+
+def causal_conv(x, taps, bias):
+    """Depthwise causal convolution along ``x`` [B, T, C]: position ``t`` sees
+    the last ``len(taps)`` positions, itself included (``taps[-1]`` is its
+    own), summed in float32. Before the row's start there are zeros."""
+    width, length = taps.shape[0], x.shape[1]
+    taps = taps.astype(jnp.float32)
+    out = bias.astype(jnp.float32) + taps[-1] * x.astype(jnp.float32)
+    for back in range(1, width):  # each shift its own pad of its own slice: nothing float32 of x's size is kept
+        shifted = jnp.pad(x[:, : length - back], ((0, 0), (back, 0), (0, 0)))
+        out = out + taps[-1 - back] * shifted.astype(jnp.float32)
+    return out
+
+
+def _mamba2(p, h, c: TrunkConfig, ctx: dict):
+    """A Mamba-2 mixer over the whole row (prefill form; the state starts at
+    zero and is not kept): ``[z | xBC | dt] = h W_in``, ``xBC <-
+    silu(conv(xBC))``, the selective scan over ``x`` [T, heads, d_head] with
+    ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``, then
+    ``rms(y * silu(z)) * g`` over all channels and the out-projection."""
+    heads, width, states = c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state
+    inner = heads * width
+    batch, length, _ = h.shape
+    projected = _dot(h, p["w_in"])
+    z, dt = projected[..., :inner], projected[..., -heads:]
+
+    def convolved(first: int, channels: int):
+        """x, B and C each through their own channels of the convolution: three
+        arrays, each laid out for its own reader."""
+        at = slice(first, first + channels)
+        part = projected[..., inner + first : inner + first + channels]
+        return jax.nn.silu(causal_conv(part, p["conv"][:, at], p["conv_bias"][at])).astype(h.dtype)
+
+    with jax.named_scope("trunk.mamba2.conv"):
+        x, b, c_ = convolved(0, inner), convolved(inner, states), convolved(inner + states, states)
+    with jax.named_scope("trunk.mamba2.scan"):
+        y = ssd_scan.scan(
+            x.reshape(batch, length, heads, width),
+            jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)),
+            -jnp.exp(p["A_log"].astype(jnp.float32)),
+            b, c_, p["D"].astype(jnp.float32), chunk=c.mamba_chunk_size,
+        )
+    gated = y.reshape(batch, length, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return _dot(rms_norm(gated, p["norm"], c.rms_norm_eps).astype(h.dtype), p["w_out"])
 
 
 ATTENTION = {
     "mla": Block(_mla_shapes, _mla, "trunk.mla"),
     "gqa_window": Block(_gqa_shapes, functools.partial(_gqa, window=True), "trunk.gqa_window"),
     "gqa_full": Block(_gqa_shapes, functools.partial(_gqa, window=False), "trunk.gqa_full"),
+    "mamba2": Block(_mamba2_shapes, _mamba2, "trunk.mamba2"),
 }
 
 # -- feed-forward kinds --------------------------------------------------------
@@ -474,7 +587,7 @@ def _moe_shapes(c: TrunkConfig) -> dict:
         "w_up": ((held, d, f), "expert_kernel"),
         "w_down": ((held, f, d), "expert_kernel"),
         # the shared experts side by side: one gated FFN whose output is their sum
-        "shared": _gated_shapes(d, f * c.n_shared_experts),
+        "shared": _gated_shapes(d, c.shared_intermediate_size or f * c.n_shared_experts),
     }
     if c.topk_method != "noaux_tc":  # a plain top-k has no correction bias
         del shapes["bias"]
@@ -486,7 +599,7 @@ def _moe(p, h, c: TrunkConfig, ctx: dict):
     routed, counts, choice = moe.expert_layer(
         flat, ctx["valid"], p["router"], p.get("bias"), p["w_gate"], p["w_up"], p["w_down"],
         top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor,
-        normalise=c.norm_topk_prob, experts_held=c.experts_held,
+        normalise=c.norm_topk_prob, experts_held=c.experts_held, scoring=c.scoring_func,
     )
     ctx["expert_counts"].append(counts)
     ctx["expert_choice"].append(choice.reshape(h.shape[:-1] + choice.shape[-1:]))
@@ -599,9 +712,15 @@ def _add_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
     return {"attn_norm": gain, "attn": attention, "ffn_norm": gain, "ffn": ffn}
 
 
+def _add_branch(x, branch, c: TrunkConfig):
+    if c.residual_multiplier == 1:
+        return x + branch
+    return (x.astype(jnp.float32) + c.residual_multiplier * branch.astype(jnp.float32)).astype(x.dtype)
+
+
 def _add_layer(p, x, attend, feed, c: TrunkConfig):
-    x = x + attend(p["attn"], norm(x, p["attn_norm"], c))
-    return x + feed(p["ffn"], norm(x, p["ffn_norm"], c))
+    x = _add_branch(x, attend(p["attn"], norm(x, p["attn_norm"], c)), c)
+    return _add_branch(x, feed(p["ffn"], norm(x, p["ffn_norm"], c)), c)
 
 
 def _parallel_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
@@ -689,6 +808,18 @@ def _init_leaf(key, shape, kind, dtype, streams):
         return (normal() / math.sqrt(shape[1])).astype(dtype)
     if kind == "router_bias":
         return 0.01 * normal()
+    # Mamba-2's published initialisation (arXiv:2405.21060, its reference code)
+    if kind == "conv":  # [taps, channels]
+        return (normal() / math.sqrt(shape[0])).astype(dtype)
+    if kind == "conv_bias":
+        return jnp.zeros(shape, jnp.float32)
+    if kind == "a_log":  # A = -exp(A_log) uniform in -16..-1
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    if kind == "dt_bias":  # softplus(dt_bias) log-uniform in 1e-3..1e-1
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    if kind == "ones":
+        return jnp.ones(shape, jnp.float32)
     if kind == "mhc_proj":  # [n, d, n + n + n * n]: fan-in is all the streams
         return (normal() / math.sqrt(shape[0] * shape[1])).astype(dtype)
     if kind == "mhc_alpha":
@@ -747,6 +878,8 @@ def forward(params, ids, mask, *, config: TrunkConfig):
     if "gqa_window" in kinds_of_attention:
         ctx["rope_pairs"] = interleaved_rope_tables(config, ids.shape[1])
     x = params["embed"][ids]
+    if config.embedding_multiplier != 1:
+        x = (x.astype(jnp.float32) * config.embedding_multiplier).astype(x.dtype)
     state = _block(RESIDUAL, table[0].residual, "residual").enter(x, config)
     for kinds, p in zip(table, params["layers"]):
         attention = _block(ATTENTION, kinds.attention, "attention")
@@ -802,6 +935,7 @@ class TrunkRuntime:
             for kinds in config.layer_table()
             if kinds.attention in ("gqa_window", "gqa_full")
         ]
+        self._scans = sum(kinds.attention == "mamba2" for kinds in config.layer_table())
 
     @property
     def params(self):
@@ -832,14 +966,28 @@ class TrunkRuntime:
             "attn_pairs_visited": rows * sum(block_attention.pairs_visited(width, w) for w in windows),
         }
 
+    def _scan_chunks(self, lengths: np.ndarray, rows: int, width: int) -> dict:
+        """The chunks of the state-space scans of one forward, all ``mamba2``
+        layers: those that hold a real token, and those walked at the
+        forwarded shape. Nothing for a table without such a layer."""
+        if not self._scans:
+            return {}
+        chunk = self.config.mamba_chunk_size
+        return {
+            "ssm_chunks_useful": self._scans * ssd_scan.chunks_useful(lengths, chunk),
+            "ssm_chunks_visited": self._scans * ssd_scan.chunks_visited(rows, width, chunk),
+        }
+
     def dispatch(
         self, ids: np.ndarray, mask: np.ndarray, routing: bool = False
     ) -> Callable[[], tuple[np.ndarray, dict]]:
         """Starts the forward of one padded batch and returns the call that
         waits for it (``EncoderRuntime.dispatch``): vectors [n, dim] and what
         was really forwarded, the padded shape and the expert layers' row
-        counts, and where the table has blocked attention layers their
-        ``attn_pairs_allowed`` and ``attn_pairs_visited``. ``routing=True``
+        counts, where the table has blocked attention layers their
+        ``attn_pairs_allowed`` and ``attn_pairs_visited``, and where it has
+        ``mamba2`` layers their ``ssm_chunks_useful`` and
+        ``ssm_chunks_visited``. ``routing=True``
         adds ``expert_choice`` [expert layers, n, T, k], the experts each
         token went to (-1: nowhere); it stays on the device unless asked for."""
         n = ids.shape[0]
@@ -856,6 +1004,7 @@ class TrunkRuntime:
             "tokens_padded": int(ids.size),
             "trunk": self.config.name,
             **self._attention_pairs(lengths, bucket, int(ids.shape[1])),
+            **self._scan_chunks(lengths, bucket, int(ids.shape[1])),
         }
 
         def fetch() -> tuple[np.ndarray, dict]:

@@ -36,7 +36,7 @@ _GROUP_POSITIONS = _GROUP * 512
 # sizes, an expert's largest load) is the largest forwarded
 _ADDED = (
     "tokens_padded", "expert_rows_useful", "expert_rows_computed",
-    "attn_pairs_allowed", "attn_pairs_visited",
+    "attn_pairs_allowed", "attn_pairs_visited", "ssm_chunks_useful", "ssm_chunks_visited",
 )
 
 

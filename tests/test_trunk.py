@@ -109,7 +109,9 @@ def test_an_unknown_kind_is_named():
     with pytest.raises(NotImplementedError, match="'linear_attention'"):
         _trunk.param_shapes(linear)
     with pytest.raises(ValueError, match="scoring_func"):
-        TrunkConfig(scoring_func="softmax")
+        TrunkConfig(scoring_func="tanh")
+    with pytest.raises(ValueError, match="scoring_func"):  # softmax over the chosen k is the normalised one only
+        TrunkConfig(scoring_func="softmax", norm_topk_prob=False)
     with pytest.raises(ValueError, match="use_qk_norm"):
         TrunkConfig(use_qk_norm=True)
 
@@ -334,8 +336,9 @@ from pathway_tpu.ops import block_attention  # noqa: E402
 GQA_FILE = os.path.join(ROOT, "benchmarks", "configs", "command-a-plus-05-2026.json")
 
 
-def gqa_dict(**changes) -> dict:
-    with open(GQA_FILE, encoding="utf-8") as f:
+def toy_dict(path: str, **changes) -> dict:
+    """A configuration file at its ``rehearse`` sizes (nested groups overlaid key by key)."""
+    with open(path, encoding="utf-8") as f:
         body = json.load(f)
     toy = body.pop("rehearse")
     for key, value in toy.items():
@@ -345,6 +348,10 @@ def gqa_dict(**changes) -> dict:
             body[key] = value
     body.update(changes)
     return body
+
+
+def gqa_dict(**changes) -> dict:
+    return toy_dict(GQA_FILE, **changes)
 
 
 @pytest.fixture(scope="module")
@@ -668,3 +675,276 @@ def test_an_expert_layer_in_passes_drops_no_pair(routing):
     busiest = int(np.asarray(counts)[4:6].max())
     sparse, _scores = gqa_ref.expert_ffn(p, h, body, experts_held=(4, 2), shared=False, forced=forced, busiest=max(busiest, 1))
     assert np.abs(np.asarray(sparse) - np.asarray(want)).max() < 1e-4
+
+
+# -- (i) granite-4.0-h-small: Mamba-2 layers, an unrotated full layer, a softmax-of-top-k router ----
+
+from benchmarks.harness import reference_ssm as ssm_ref  # noqa: E402
+from pathway_tpu.ops import ssd_scan  # noqa: E402
+
+SSM_FILE = os.path.join(ROOT, "benchmarks", "configs", "granite-4.0-h-small.json")
+
+
+def ssm_dict(**changes) -> dict:
+    return toy_dict(SSM_FILE, **changes)
+
+
+@pytest.fixture(scope="module")
+def ssm():
+    body = ssm_dict()
+    return body, TrunkConfig.from_dict(body, name="toy-ssm")
+
+
+@pytest.fixture(scope="module")
+def ssm_runtime(ssm):
+    _body, config = ssm
+    runtime = TrunkRuntime(config, max_len=128, seed=7, dtype=jnp.float32)
+    # the stream enters at unit scale (as the benchmark's weights have it): what a layer adds is then visible
+    runtime.params = dict(runtime.params, embed=runtime.params["embed"] / config.embedding_multiplier)
+    return runtime
+
+
+def ssm_reference_rows(params, ids, mask, body, **kw):
+    return np.stack(
+        [np.asarray(ssm_ref.encode(params, row, int(m.sum()), body, **kw)[0]) for row, m in zip(ids, mask)]
+    )
+
+
+def test_the_granite_config_file_reads_as_the_issue_says():
+    config = TrunkConfig.from_file(SSM_FILE, name="granite-4.0-h-small")
+    table = config.layer_table()
+    assert [k.attention for k in table] == ["mamba2"] * 5 + ["gqa_full"] + ["mamba2"] * 4
+    assert {k.ffn for k in table} == {"moe"} and {k.residual for k in table} == {"add"}
+    assert (config.hidden_size, config.num_attention_heads, config.num_key_value_heads, config.head_dim) == (4096, 32, 8, 128)
+    assert (config.mamba_n_heads, config.mamba_d_head, config.mamba_d_state, config.mamba_d_conv, config.mamba_chunk_size) == (128, 64, 128, 4, 256)
+    assert (config.n_routed_experts, config.held, config.num_experts_per_tok, config.n_shared_experts) == (72, (0, 36), 10, 1)
+    assert (config.moe_intermediate_size, config.shared_intermediate_size, config.vocab_size) == (768, 1536, 50176)
+    assert (config.attention_multiplier, config.embedding_multiplier, config.residual_multiplier) == (0.0078125, 12, 0.22)
+    assert config.scoring_func == "softmax" and config.topk_method == "greedy" and config.routed_scaling_factor == 1.0
+    assert config.norm_eps == 1e-5 and config.position_embedding_type == "nope"
+    shapes = _trunk.param_shapes(config)
+    assert "bias" not in shapes["layers"][0]["ffn"] and shapes["layers"][0]["ffn"]["router"][0] == (4096, 72)
+    assert shapes["layers"][0]["attn"]["w_in"][0] == (4096, 16768) and shapes["layers"][0]["attn"]["conv"][0] == (4, 8448)
+    template = jax.eval_shape(lambda: _trunk.init_params(config, 0, jnp.bfloat16))
+    assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(template)) == 4_757_211_776
+    mamba = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(template["layers"][0]["attn"]))
+    assert mamba == 102_286_976
+
+
+@pytest.mark.parametrize(
+    "key, value",
+    [("mamba_n_groups", 8), ("mamba_proj_bias", True), ("normalization_function", "layernorm"), ("position_embedding_type", "rope")],
+)
+def test_granite_keys_without_a_block_are_refused_by_name(key, value):
+    with pytest.raises(ValueError, match=key):
+        TrunkConfig.from_dict(ssm_dict(**{key: value}), name="toy-ssm")
+
+
+@pytest.mark.parametrize("rows, seed", [(3, 0), (5, 1), (8, 2)])
+def test_ssm_forward_float32_matches_the_recurrence_over_several_chunks(ssm, ssm_runtime, rows, seed):
+    body, config = ssm
+    ids, mask = batch(rows, 128, seed)  # row 0 fills 128 positions: 8 chunks of 16
+    assert mask.sum(axis=1).max() == 8 * config.mamba_chunk_size
+    got, info = ssm_runtime.forward(ids, mask)
+    want = ssm_reference_rows(ssm_runtime.params, ids, mask, body)
+    assert np.linalg.norm(got - want, axis=1).max() < F32_TOL
+    assert info["ssm_chunks_useful"] == 9 * sum(-(-int(t) // 16) for t in mask.sum(axis=1))
+    assert info["ssm_chunks_visited"] == 9 * info["batch_bucket"] * 8
+    assert info["attn_pairs_allowed"] == sum(block_attention.pairs_allowed(int(t), None) for t in mask.sum(axis=1))
+    # a reference that loses the state between chunks is somebody else's vectors
+    lost = ssm_reference_rows(ssm_runtime.params, ids[:2], mask[:2], body, mode="no_carry")
+    assert np.linalg.norm(lost - want[:2], axis=1).min() > 50 * F32_TOL
+
+
+def test_ssm_forward_bfloat16_follows_its_own_experts(ssm):
+    body, config = ssm
+    runtime = TrunkRuntime(config, max_len=128, seed=8)
+    ids, mask = batch(4, 64, 3)
+    got, info = runtime.forward(ids, mask, routing=True)
+    choice = info["expert_choice"]
+    assert choice.shape == (10, 4, 64, 3) and ((choice >= 0).all(axis=-1) == (mask > 0)[None]).all()
+    want = np.stack(
+        [
+            np.asarray(ssm_ref.encode(runtime.params, ids[i], int(mask[i].sum()), body, forced=choice[:, i])[0])
+            for i in range(4)
+        ]
+    )
+    assert np.linalg.norm(got - want, axis=1).max() < BF16_TOL
+
+
+@pytest.fixture(scope="module")
+def ssm_blocks(ssm):
+    body, config = ssm
+    params = f32_tree(_trunk.init_params(config, 13, jnp.float32))
+    h = jax.random.normal(jax.random.PRNGKey(9), (2, 72, config.hidden_size), jnp.float32)
+    return body, config, params, h
+
+
+def test_mamba2_block(ssm_blocks):
+    body, config, params, h = ssm_blocks
+    p = params["layers"][0]["attn"]
+    got = _trunk.ATTENTION["mamba2"].apply(p, h, config, {})
+    for i in range(2):  # 72 positions: four whole chunks of 16 and a part of one
+        want = ssm_ref.mamba(p, h[i], body)
+        assert np.abs(np.asarray(got[i]) - np.asarray(want)).max() < 2e-4
+    # the convolution sees the token itself and the three before it
+    taps = jnp.asarray([[0.0], [0.0], [0.0], [1.0]])
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 9, 1))
+    assert np.allclose(_trunk.causal_conv(x, taps, jnp.zeros(1)), x)
+    assert np.allclose(_trunk.causal_conv(x, taps[::-1], jnp.zeros(1))[:, 3:], x[:, :-3])
+
+
+def recurrence(x, dt, A, B, C, D):
+    """The scan position by position: S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t, y_t = S_t C_t + D x_t."""
+
+    def step(state, at):
+        x_t, dt_t, b_t, c_t = at
+        state = jnp.exp(dt_t * A)[:, None, None] * state + (dt_t[:, None] * x_t)[:, :, None] * b_t[None, None, :]
+        return state, jnp.einsum("hpn,n->hp", state, c_t) + D[:, None] * x_t
+
+    def one_row(x, dt, B, C):
+        return jax.lax.scan(step, jnp.zeros(x.shape[1:] + B.shape[-1:]), (x, dt, B, C))[1]
+
+    return jax.vmap(one_row)(x, dt, B, C)
+
+
+def scan_inputs(batch_rows, length, heads, width, states, seed=0):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(batch_rows, length, heads, width)), jnp.float32)
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), size=(batch_rows, length, heads))), jnp.float32)
+    A = -jnp.asarray(rng.uniform(1, 16, size=heads), jnp.float32)
+    B, C = (jnp.asarray(rng.normal(size=(batch_rows, length, states)), jnp.float32) for _ in range(2))
+    return x, dt, A, B, C, jnp.asarray(rng.normal(size=heads), jnp.float32)
+
+
+@pytest.mark.parametrize(
+    "length, chunk, heads, width",
+    # chunks that divide the length and that do not; the state carried over 4 to 8 chunks; two blocks of 16
+    # heads and one of fewer; 8 heads of 16 share a 128-lane tile, 2 heads of 64 as at the published sizes,
+    # a head of 128 has its own
+    [(64, 16, 32, 16), (70, 16, 8, 16), (96, 256, 4, 8), (128, 32, 32, 64), (80, 16, 2, 128)],
+)
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+def test_ssd_scan_is_the_recurrence(path, length, chunk, heads, width):
+    inputs = scan_inputs(2, length, heads, width, 16, seed=length)
+    want = np.asarray(recurrence(*inputs))
+    if path == "kernel":
+        got = ssd_scan.scan_pallas(*inputs, chunk=chunk)  # interpreted: the backend is the CPU
+    else:
+        got = ssd_scan.scan_xla(*inputs, chunk=chunk)
+    assert got.shape == want.shape and np.abs(np.asarray(got) - want).max() < 1e-4 * np.abs(want).max()
+    if chunk < length:  # a scan that drops the carried state is another function
+        x, dt, A, B, C, D = inputs
+        lost = jnp.concatenate(  # every chunk scanned from a zero state
+            [ssd_scan.scan_xla(x[:, i : i + chunk], dt[:, i : i + chunk], A, B[:, i : i + chunk], C[:, i : i + chunk], D, chunk) for i in range(0, length, chunk)],
+            axis=1,
+        )
+        assert np.abs(np.asarray(lost) - want).max() > 0.05 * np.abs(want).max()
+        assert np.abs(np.asarray(lost)[:, :chunk] - want[:, :chunk]).max() < 1e-4 * np.abs(want).max()
+
+
+def test_scan_chunk_counts():
+    assert ssd_scan.chunks_useful([1, 256, 257, 5000]) == 1 + 1 + 2 + 20
+    assert ssd_scan.chunks_visited(2, 8192) == 64 and ssd_scan.chunks_visited(8, 64) == 8
+    assert ssd_scan.chunks_useful([70], chunk=16) == 5 and ssd_scan.chunks_visited(3, 128, chunk=16) == 24
+
+
+def test_the_softmax_of_the_top_k_sums_to_one_and_follows_the_logits(ssm_blocks):
+    _body, config, params, h = ssm_blocks
+    p = params["layers"][0]["ffn"]
+    flat = h.reshape(-1, h.shape[-1])
+    weights, choice = moe.route(flat, p["router"], None, top_k=3, scale=1.0, scoring="softmax")
+    logits = np.asarray(flat @ p["router"])
+    assert np.abs(np.asarray(weights).sum(axis=1) - 1).max() < 1e-6
+    assert (np.sort(np.asarray(choice), axis=1) == np.sort(np.argsort(logits, axis=1)[:, -3:], axis=1)).all()
+    picked = np.take_along_axis(logits, np.asarray(choice), axis=1)
+    assert (np.diff(picked, axis=1) <= 0).all() and (np.diff(np.asarray(weights), axis=1) <= 0).all()
+    assert np.allclose(np.asarray(weights), np.exp(picked) / np.exp(picked).sum(axis=1, keepdims=True), atol=1e-6)
+    sigmoid, _ = moe.route(flat, p["router"], None, top_k=3, scale=1.0)
+    assert np.abs(np.asarray(sigmoid) - np.asarray(weights)).max() > 1e-3
+
+
+@pytest.mark.parametrize("key, other", [("attention_multiplier", 0.25), ("embedding_multiplier", 3.0), ("residual_multiplier", 0.5)])
+def test_each_multiplier_changes_the_result(ssm, ssm_runtime, key, other):
+    body, _config = ssm
+    changed = TrunkConfig.from_dict(dict(body, **{key: other}), name="toy-ssm")
+    ids, mask = batch(2, 64, 4)
+    runtime = TrunkRuntime(changed, max_len=128, dtype=jnp.float32)
+    runtime.params = ssm_runtime.params
+    got = runtime.forward_ids(ids, mask)
+    assert np.linalg.norm(got - ssm_runtime.forward_ids(ids, mask), axis=1).min() > 1e-3
+    want = ssm_reference_rows(runtime.params, ids, mask, dict(body, **{key: other}))
+    assert np.linalg.norm(got - want, axis=1).max() < F32_TOL
+
+
+def test_ssm_padding_and_companions_change_no_vector(ssm_runtime):
+    ids, mask = batch(rows=3, width=64, seed=5)
+    together = ssm_runtime.forward_ids(ids, mask)
+    for i in range(3):
+        alone = ssm_runtime.forward_ids(ids[i : i + 1], mask[i : i + 1])
+        assert np.abs(alone[0] - together[i]).max() < 1e-5
+    wide, info = ssm_runtime.forward(np.pad(ids, ((0, 0), (0, 64))), np.pad(mask, ((0, 0), (0, 64))))
+    assert info["len_bucket"] == 128 and np.abs(wide - together).max() < 1e-5
+
+
+@pytest.mark.parametrize("layer, kind", [(0, "mamba"), (5, "attention")])
+def test_the_two_shares_of_a_granite_layer_add_up_to_the_uncut_layer(ssm, layer, kind):
+    """Eight experts over two shares of 4 (the deployment's: experts 0-35 and
+    36-71 of 72): what the shares' routed parts give, with the mixer, the
+    shared expert and x counted once, is the uncut layer as the reference
+    computes it."""
+    whole_body = ssm_dict(num_local_experts=8, experts_held=None, published={})
+    whole = TrunkConfig.from_dict(whole_body, name="uncut")
+    assert whole.held == (0, 8) and whole.n_routed_experts == 8
+    params = f32_tree(_trunk.init_params(whole, 17, jnp.float32))
+    p, m = params["layers"][layer], whole.residual_multiplier
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 40, whole.hidden_size), jnp.float32)
+    block = _trunk.ATTENTION[whole.layer_table()[layer].attention]
+    mixed = np.asarray(block.apply(p["attn"], _trunk.rms_norm(x, p["attn_norm"], whole.norm_eps), whole, {}))[0]
+    after = x + m * mixed[None]
+    flat = _trunk.rms_norm(after, p["ffn_norm"], whole.norm_eps).reshape(-1, whole.hidden_size)
+    valid = jnp.ones(flat.shape[0], bool)
+    parts = []
+    for first in (0, 4):
+        routed, _counts, _choice = moe.expert_layer(
+            flat, valid, p["ffn"]["router"], None, p["ffn"]["w_gate"][first : first + 4],
+            p["ffn"]["w_up"][first : first + 4], p["ffn"]["w_down"][first : first + 4],
+            top_k=3, scale=1.0, experts_held=(first, 4), scoring="softmax",
+        )
+        parts.append(np.asarray(routed))
+    assert all(np.abs(part).max() > 0 for part in parts)
+    shared = np.asarray(_trunk._gated_ffn(p["ffn"]["shared"], flat))
+    want, _logits = ssm_ref.layer(p, x[0], None, whole_body, kind)
+    assert np.abs(np.asarray(after[0]) + m * (shared + sum(parts)) - np.asarray(want)).max() < 2e-4
+    # and a cut layer is the program's own layer on its share
+    cut = TrunkConfig.from_dict(ssm_dict(experts_held=[4, 4]), name="cut")
+    held = dict(p, ffn={k: (v[4:8] if k in ("w_gate", "w_up", "w_down") else v) for k, v in p["ffn"].items()})
+    ctx = {"valid": valid, "expert_counts": [], "expert_choice": []}
+
+    def attend(p_attn, u):
+        return block.apply(p_attn, u, cut, ctx)
+
+    def feed(p_ffn, u):
+        return _trunk.FFN["moe"].apply(p_ffn, u, cut, ctx)
+
+    got = np.asarray(_trunk.RESIDUAL["add"].layer(held, x, attend, feed, cut))[0]
+    assert np.abs(got - (np.asarray(after[0]) + m * (shared + parts[1]))).max() < 2e-4
+
+
+@pytest.mark.parametrize(
+    "file, leaves, total, signature",
+    [(CONFIG_FILE, 147, 4_323_079_812, "1a103e61132f4735"), (GQA_FILE, 50, 4_733_292_544, "fc28f37aca32afce")],
+)
+def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, signature):
+    """xing4's and command-a's trees, leaf for leaf (path, shape, dtype), as
+    the commit before the Mamba kind built them."""
+    import hashlib
+
+    config = TrunkConfig.from_file(file, name="as-before")
+    template = jax.eval_shape(lambda: _trunk.init_params(config, 0, jnp.bfloat16))
+    flat = jax.tree_util.tree_flatten_with_path(template)[0]
+    assert (len(flat), sum(int(np.prod(a.shape)) for _, a in flat)) == (leaves, total)
+    described = repr([(str(path), a.shape, str(a.dtype)) for path, a in flat])
+    assert hashlib.sha256(described.encode()).hexdigest()[:16] == signature
+    assert config.attention_multiplier is None and config.residual_multiplier == 1 and config.embedding_multiplier == 1
+    assert config.shared_intermediate_size == 0 and config.scoring_func == "sigmoid"

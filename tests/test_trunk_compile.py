@@ -128,3 +128,44 @@ def test_the_document_forward_fits_a_v5e_and_keeps_no_logits(one_chip, no_cache,
     # the largest array is an expert tensor [16, 4096, 4096]: [B, H, T, T] logits would be 64 to 128 times that
     assert _largest_shape(text) == 16 * 4096 * 4096 < rows * 128 * width * width // 32
     assert moe.column_block(4096, 4096, 2) == 1024
+
+
+@pytest.mark.parametrize("rows, width", [(1, 16384), (2, 8192)], ids=["1x16384", "2x8192"])
+def test_the_paper_forward_fits_a_v5e_and_keeps_no_decay_mask(one_chip, no_cache, monkeypatch, rows, width):
+    """granite-4.0-h-small's cut (10 layers, 36 of 72 experts, published
+    widths) at the 16,384 positions of a whole group: parameters and
+    temporaries under the chip's 16.9 GB with room for the index, every
+    Mamba layer's scan as the chunked kernel (no [B, T / 256, H, 256, 256]
+    decay mask anywhere), the one full layer as the blocked kernel."""
+    import functools
+
+    from pathway_tpu.ops import ssd_scan
+    from pathway_tpu.xpacks.llm import _trunk
+
+    for module in (moe, block_attention, ssd_scan):
+        monkeypatch.setattr(module, "pallas_interpret", lambda: False)
+    config = _trunk.TrunkConfig.from_file(
+        os.path.join(ROOT, "benchmarks", "configs", "granite-4.0-h-small.json"), name="granite-4.0-h-small"
+    )
+    template = jax.eval_shape(lambda: _trunk.init_params(config, 0, jnp.bfloat16))
+    params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), template)
+    compiled = jax.jit(functools.partial(_trunk.forward, config=config)).lower(
+        params,
+        jax.ShapeDtypeStruct((rows, width), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert 9.5e9 < memory.argument_size_in_bytes < 9.53e9  # 4,757,211,776 parameters at bfloat16
+    # 5.3 GB of temporaries: one layer's take 2.9 (the experts' 168,448 rows of 4096 in and out, 1.38 GB
+    # each, half of them never used: the other chip's pairs), the rest is how ten layers' buffers pack
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9  # of 16.9 GB; the index takes 0.54
+    text = compiled.as_text()
+    # nine scans, one attention kernel and thirty grouped matmuls, each of them Mosaic's
+    assert text.count("tpu_custom_call") >= 40
+    assert all(name in text for name in (ssd_scan.SSD_KERNEL_NAME, block_attention.ATTN_KERNEL_NAME, moe.GMM_KERNEL_NAME))
+    # the largest array is the experts' rows [168,448, 4096] bfloat16 (1.38 GB); one layer's decay mask
+    # [B, T / 256, 128, 256, 256] float32 would be 2.1 GB; the largest float32 shape written is the in-projection's accumulator
+    assert _largest_shape(text) == moe.plan_rows(rows * width * 10, 36) * 4096
+    mask = rows * width * 128 * 256
+    floats = [math.prod(map(int, dims.split(","))) for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+    assert max(floats) <= rows * width * 16768 < mask and f"{width // 256},128,256,256]" not in text
