@@ -18,10 +18,19 @@ they used, so nothing is moved for them. Blocks that lie wholly inside the
 mask skip the mask arithmetic too.
 
 Right padding needs no mask of its own: a padding key lies after every real
-query, so the causal mask already hides it; the rows of padding queries hold
-finite numbers nobody reads. Multiplies in the operands' dtype, logits,
-softmax and accumulation in float32. Its device ops are called
-``ATTN_KERNEL_NAME`` in a trace; ``ops/backend.py`` decides interpret mode.
+query, so the causal mask already hides it. ``lengths`` [B] says how many
+real tokens each row holds, and the kernel reads it as a scalar prefetch: a
+query block whose first position is at or past its row's length is **dead**.
+A dead block runs no matmul and no softmax on any of its steps, moves
+nothing (its maps stay on the blocks the row's last live step used) and
+writes **zeros**: nobody reads a padding query's output (the trunk pools at
+the last real token), but it goes on through the out-projection, so it has
+to be finite, and the same on every call. The padding positions of a row's
+last live block hold finite numbers nobody reads, as before. A live block is
+computed as it would be without ``lengths``, to the last bit. Multiplies in
+the operands' dtype, logits, softmax and accumulation in float32. Its device
+ops are called ``ATTN_KERNEL_NAME`` in a trace; ``ops/backend.py`` decides
+interpret mode.
 """
 
 from __future__ import annotations
@@ -55,21 +64,29 @@ def kv_range(qi, block_q: int, block_k: int, window: int | None):
     return lo, last_query // block_k
 
 
-def visited_steps(length: int, window: int | None, block_q: int, block_k: int) -> list[int]:
-    """Key blocks each query block of a ``length``-position row visits."""
+def visited_steps(
+    length: int, window: int | None, block_q: int, block_k: int, tokens: int | None = None
+) -> list[int]:
+    """Key blocks each query block of a ``length``-position row visits when
+    the row holds ``tokens`` real tokens (``None``: all of them are): none
+    for a dead block."""
+    tokens = length if tokens is None else tokens
     steps = []
     for qi in range(-(-length // block_q)):
         lo, hi = kv_range(qi, block_q, block_k, window)
-        steps.append(hi - lo + 1)
+        steps.append(hi - lo + 1 if qi * block_q < tokens else 0)
     return steps
 
 
-def pairs_visited(length: int, window: int | None = None, block_q=None, block_k=None) -> int:
+def pairs_visited(
+    length: int, window: int | None = None, block_q=None, block_k=None, tokens: int | None = None
+) -> int:
     """Query-key pairs of the blocks the kernel visits for one row of
-    ``length`` positions (one head): what it computes, padding and the
-    masked corners of its edge blocks included."""
+    ``length`` positions (one head) that holds ``tokens`` real tokens
+    (``None``: all): what it computes, the padding inside the last live block
+    and the masked corners of its edge blocks included."""
     block_q, block_k = blocks(length, block_q, block_k)
-    return sum(visited_steps(length, window, block_q, block_k)) * block_q * block_k
+    return sum(visited_steps(length, window, block_q, block_k, tokens)) * block_q * block_k
 
 
 def pairs_allowed(tokens: int, window: int | None = None) -> int:
@@ -79,14 +96,21 @@ def pairs_allowed(tokens: int, window: int | None = None) -> int:
     return window * (window + 1) // 2 + (tokens - window) * window
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, window, block_q, block_k):
-    qi, j = pl.program_id(2), pl.program_id(3)
+def _last_live(lengths_ref, b, block_q: int):
+    """The last query block of row ``b`` that holds a real token (0 for an empty row)."""
+    return jnp.maximum((lengths_ref[b] + block_q - 1) // block_q - 1, 0)
+
+
+def _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, window, block_q, block_k):
+    b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     heads, _, width = q_ref.shape[2:]
     rows = heads * block_q
     lo, hi = kv_range(qi, block_q, block_k, window)
     kb = lo + j
+    live = qi * block_q < lengths_ref[b]
+    last_step = j == pl.num_programs(3) - 1
 
-    @pl.when(j == 0)
+    @pl.when(live & (j == 0))
     def _():
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
@@ -125,19 +149,26 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, window,
     inside = kb * block_k + block_k - 1 <= qi * block_q
     if window is not None:
         inside &= qi * block_q + block_q - 1 - kb * block_k < window
-    visited = kb <= hi
+    visited = live & (kb <= hi)
     pl.when(visited & inside)(functools.partial(_step, False))
     pl.when(visited & jnp.logical_not(inside))(functools.partial(_step, True))
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(live & last_step)
     def _():
         out = acc_ref[...] / l_ref[...]
         o_ref[0, 0] = out.reshape(heads, block_q, width).astype(o_ref.dtype)
 
+    @pl.when(jnp.logical_not(live) & last_step)
+    def _():
+        o_ref[0, 0] = jnp.zeros(o_ref.shape[2:], o_ref.dtype)
 
-def attention(q, k, v, *, scale: float, window: int | None = None, block_q=None, block_k=None):
-    """``q`` [B, H_kv, G, T, d]; ``k``, ``v`` [B, H_kv, T, d] -> [B, H_kv, G, T, d]."""
+
+def attention(q, k, v, *, scale: float, window: int | None = None, lengths=None, block_q=None, block_k=None):
+    """``q`` [B, H_kv, G, T, d]; ``k``, ``v`` [B, H_kv, T, d] -> [B, H_kv, G, T, d].
+    ``lengths`` int32 [B]: the real tokens of each right-padded row (``None``:
+    every row is full); the positions of a row's dead blocks come back zero."""
     batch, kv_heads, group, length, width = q.shape
+    lengths = jnp.full((batch,), length, jnp.int32) if lengths is None else jnp.asarray(lengths, jnp.int32)
     block_q, block_k = blocks(length, block_q, block_k)
     pad = -length % max(block_q, block_k)
     if pad:  # a length off the ladder: padding keys lie after every real query
@@ -146,12 +177,17 @@ def attention(q, k, v, *, scale: float, window: int | None = None, block_q=None,
     padded = length + pad
     steps = max(visited_steps(padded, window, block_q, block_k))
 
-    def q_map(b, h, qi, j):
-        return (b, h, 0, qi, 0)
+    # a dead block's inputs stay where the row's last live step left them
+    def q_map(b, h, qi, j, lengths_ref):
+        return (b, h, 0, jnp.minimum(qi, _last_live(lengths_ref, b, block_q)), 0)
 
-    def kv_map(b, h, qi, j):
-        lo, hi = kv_range(qi, block_q, block_k, window)
-        return (b, h, jnp.minimum(lo + j, hi), 0)
+    def kv_map(b, h, qi, j, lengths_ref):
+        last = _last_live(lengths_ref, b, block_q)
+        lo, hi = kv_range(jnp.minimum(qi, last), block_q, block_k, window)
+        return (b, h, jnp.where(qi > last, hi, jnp.minimum(lo + j, hi)), 0)
+
+    def out_map(b, h, qi, j, lengths_ref):
+        return (b, h, 0, qi, 0)
 
     rows = group * block_q
     out = pl.pallas_call(
@@ -159,23 +195,26 @@ def attention(q, k, v, *, scale: float, window: int | None = None, block_q=None,
             _kernel, scale=scale, window=window, block_q=block_q, block_k=block_k
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(batch, kv_heads, padded // block_q, steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, block_q, width), q_map),
-            pl.BlockSpec((1, 1, block_k, width), kv_map),
-            pl.BlockSpec((1, 1, block_k, width), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, block_q, width), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, width), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, kv_heads, padded // block_q, steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, group, block_q, width), q_map),
+                pl.BlockSpec((1, 1, block_k, width), kv_map),
+                pl.BlockSpec((1, 1, block_k, width), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, group, block_q, width), out_map),
+            scratch_shapes=[
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, width), jnp.float32),
+            ],
+        ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=pallas_interpret(),
         name=ATTN_KERNEL_NAME,
-    )(q, k, v)
+    )(lengths, q, k, v)
     return out[:, :, :, :length] if pad else out

@@ -8,8 +8,9 @@ by kind, so another architecture adds kinds, not branches. Kinds so far:
 * attention ``mla``: multi-head latent attention in its expanded (prefill)
   form, YaRN rotary on the decoupled rotary part, causal, no cache;
   ``gqa_window`` and ``gqa_full``: grouped-query attention through
-  ``ops/block_attention.py`` (no logits in HBM, blocks outside the mask
-  skipped, each key-value head read once for its query heads), the first
+  ``ops/block_attention.py`` (no logits in HBM, blocks outside the mask and
+  query blocks past a row's last real token skipped, each key-value head
+  read once for its query heads), the first
   inside a sliding window with rotary over interleaved pairs, the second
   causal over the whole row and unrotated, its softmax scale the config's
   ``attention_multiplier`` where one is given; ``mamba2``: a Mamba-2 mixer in
@@ -480,6 +481,7 @@ def _gqa(p, h, c: TrunkConfig, ctx: dict, *, window: bool):
         q.reshape(batch, kv_heads, heads // kv_heads, length, width), k, v,
         scale=width**-0.5 if c.attention_multiplier is None else c.attention_multiplier,
         window=c.sliding_window if window else None,
+        lengths=ctx.get("lengths"),
     ).reshape(q.shape)
     out = jnp.einsum("bhte,hed->btd", mixed, p["wo"].astype(h.dtype), preferred_element_type=jnp.float32)
     return out.astype(h.dtype)
@@ -871,7 +873,8 @@ def forward(params, ids, mask, *, config: TrunkConfig):
     the router sent them) and the router's choice [expert layers, B, T, k]
     int32 (-1 at a padding position)."""
     table = config.layer_table()
-    ctx = {"valid": mask.reshape(-1) > 0, "expert_counts": [], "expert_choice": []}
+    lengths = mask.sum(axis=1).astype(jnp.int32)
+    ctx = {"valid": mask.reshape(-1) > 0, "lengths": lengths, "expert_counts": [], "expert_choice": []}
     kinds_of_attention = {kinds.attention for kinds in table}
     if "mla" in kinds_of_attention:
         ctx["rope"] = rope_tables(config, ids.shape[1])
@@ -895,7 +898,7 @@ def forward(params, ids, mask, *, config: TrunkConfig):
 
         state = _block(RESIDUAL, kinds.residual, "residual").layer(p, state, attend, feed, config)
     # pool before the last norm: only the last real position of each row is kept
-    last = jnp.maximum(mask.sum(axis=1).astype(jnp.int32) - 1, 0)
+    last = jnp.maximum(lengths - 1, 0)
     pooled = norm(_block(RESIDUAL, table[-1].residual, "residual").exit(state, last), params["final_norm"], config)
     vectors = pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-12)
     if not ctx["expert_counts"]:  # no expert layer in the table
@@ -950,12 +953,13 @@ class TrunkRuntime:
     def batch_bucket(self, n: int, width: int = 0) -> int:
         return _bucket_batch(n, width)
 
-    def _attention_pairs(self, lengths: np.ndarray, rows: int, width: int) -> dict:
+    def _attention_pairs(self, lengths: np.ndarray, width: int) -> dict:
         """What the blocked attention layers of one forward are asked for and
         what their kernel visits, in query-key pairs a head: the pairs inside
         the masks over each row's real tokens, and the pairs of the blocks
-        visited at the forwarded shape (``rows`` x ``width``, padding rows and
-        positions included). Nothing for a table without such a layer."""
+        visited at the forwarded ``width`` up to each row's last real token
+        (a bucket's padding rows visit nothing). Nothing for a table without
+        such a layer."""
         windows = self._windows
         if not windows:
             return {}
@@ -963,7 +967,9 @@ class TrunkRuntime:
             "attn_pairs_allowed": sum(
                 block_attention.pairs_allowed(int(t), w) for w in windows for t in lengths
             ),
-            "attn_pairs_visited": rows * sum(block_attention.pairs_visited(width, w) for w in windows),
+            "attn_pairs_visited": sum(
+                block_attention.pairs_visited(width, w, tokens=int(t)) for w in windows for t in lengths
+            ),
         }
 
     def _scan_chunks(self, lengths: np.ndarray, rows: int, width: int) -> dict:
@@ -1003,7 +1009,7 @@ class TrunkRuntime:
             "len_bucket": int(ids.shape[1]),
             "tokens_padded": int(ids.size),
             "trunk": self.config.name,
-            **self._attention_pairs(lengths, bucket, int(ids.shape[1])),
+            **self._attention_pairs(lengths, int(ids.shape[1])),
             **self._scan_chunks(lengths, bucket, int(ids.shape[1])),
         }
 

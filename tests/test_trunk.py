@@ -399,7 +399,10 @@ def test_gqa_forward_float32_matches_the_reference_past_the_window(gqa, gqa_runt
     assert info["attn_pairs_allowed"] == sum(
         block_attention.pairs_allowed(int(t), w) for t in mask.sum(axis=1) for w in (16, 16, 16, None)
     )
-    assert info["attn_pairs_visited"] == info["batch_bucket"] * 4 * 128 * 128  # one block a row at this width
+    # one block a row at this width, in each of the four layers; the bucket's padding rows visit nothing
+    assert info["attn_pairs_visited"] == sum(
+        block_attention.pairs_visited(128, w, tokens=int(t)) for t in mask.sum(axis=1) for w in (16, 16, 16, None)
+    ) == rows * 4 * 128 * 128 <= info["batch_bucket"] * 4 * 128 * 128
 
 
 def test_gqa_forward_bfloat16_follows_its_own_experts(gqa):
@@ -514,6 +517,77 @@ def test_blocked_attention_is_materialised_attention(length, window, block_q, bl
     assert block_attention.pairs_visited(length, window, block_q, block_k) >= block_attention.pairs_allowed(length, window)
     if window is not None and window + size_q + size_k < length:
         assert sum(steps) < sum(block_attention.visited_steps(length, None, size_q, size_k))
+
+
+RAGGED = [  # length, window, block_q, block_k, real tokens a row: none, inside a block, on a block's edge, all
+    (64, None, 16, 32, (0, 21, 32, 64)),
+    (64, 16, 16, 32, (0, 21, 32, 64)),
+    (128, 40, 32, 64, (0, 33, 96, 128)),
+    (100, 16, None, None, (0, 1, 57, 100)),  # one block a row: a row is live or dead as a whole
+    (96, 33, 32, 16, (0, 5, 64, 96)),
+    (160, None, 32, 64, (0, 100, 128, 160)),
+]
+
+
+def ragged_inputs(length, rows):
+    keys = jax.random.split(jax.random.PRNGKey(length + 1), 3)
+    q = 2.0 * jax.random.normal(keys[0], (rows, 2, 4, length, 16))
+    return q, jax.random.normal(keys[1], (rows, 2, length, 16)), jax.random.normal(keys[2], (rows, 2, length, 16))
+
+
+@pytest.mark.parametrize("length, window, block_q, block_k, tokens", RAGGED)
+def test_ragged_lengths_keep_every_real_position_and_zero_every_dead_block(length, window, block_q, block_k, tokens):
+    q, k, v = ragged_inputs(length, len(tokens))
+    kw = dict(scale=0.25, window=window, block_q=block_q, block_k=block_k)
+    got = np.asarray(block_attention.attention(q, k, v, lengths=jnp.asarray(tokens, jnp.int32), **kw))
+    full = np.asarray(block_attention.attention(q, k, v, **kw))
+    want = np.asarray(materialised(q, k, v, 0.25, window))
+    size_q, _ = block_attention.blocks(length, block_q, block_k)
+    for row, t in enumerate(tokens):
+        live_until = -(-t // size_q) * size_q  # the last live block's padding positions are computed as before
+        assert np.abs(got[row, :, :, :t] - want[row, :, :, :t]).max(initial=0.0) < 2e-5
+        assert np.array_equal(got[row, :, :, :live_until], full[row, :, :, :live_until])  # to the last bit
+        assert not got[row, :, :, live_until:].any()
+    assert np.isfinite(got).all()
+
+
+@pytest.mark.parametrize("length, window, block_q, block_k, tokens", RAGGED)
+def test_no_lengths_is_every_row_full(length, window, block_q, block_k, tokens):
+    q, k, v = ragged_inputs(length, 2)
+    kw = dict(scale=0.25, window=window, block_q=block_q, block_k=block_k)
+    full = block_attention.attention(q, k, v, lengths=jnp.full((2,), length, jnp.int32), **kw)
+    assert np.array_equal(np.asarray(block_attention.attention(q, k, v, **kw)), np.asarray(full))
+
+
+@pytest.mark.parametrize("length, window, block_q, block_k, tokens", RAGGED)
+def test_pairs_visited_counts_the_steps_the_kernel_runs(length, window, block_q, block_k, tokens):
+    size_q, size_k = block_attention.blocks(length, block_q, block_k)
+    padded = length + -length % max(size_q, size_k)
+    grid_steps = max(block_attention.visited_steps(padded, window, size_q, size_k))
+    for t in tokens:
+        ran = 0  # the kernel's predicate, grid step by grid step: live & (lo + j <= hi)
+        for qi in range(padded // size_q):
+            lo, hi = block_attention.kv_range(qi, size_q, size_k, window)
+            ran += sum(qi * size_q < t and lo + j <= hi for j in range(grid_steps))
+        assert block_attention.pairs_visited(length, window, block_q, block_k, tokens=t) == ran * size_q * size_k
+        assert block_attention.pairs_visited(length, window, block_q, block_k, tokens=t) >= block_attention.pairs_allowed(t, window)
+    assert block_attention.pairs_visited(length, window, block_q, block_k, tokens=length) == block_attention.pairs_visited(
+        length, window, block_q, block_k
+    )
+    assert block_attention.visited_steps(length, window, size_q, size_k, tokens=0) == [0] * -(-length // size_q)
+
+
+def test_a_dead_block_reads_no_key_and_no_query():
+    # what lies past a row's last live block is never touched: poison there reaches no output
+    length, tokens = 128, (0, 40, 64, 128)
+    q, k, v = ragged_inputs(length, len(tokens))
+    clean = np.asarray(block_attention.attention(q, k, v, scale=0.25, lengths=jnp.asarray(tokens), block_q=16, block_k=32))
+    for row, t in enumerate(tokens):
+        dead_from = -(-t // 16) * 16
+        q = q.at[row, :, :, dead_from:].set(jnp.nan)
+        k, v = (a.at[row, :, -(-dead_from // 32) * 32 :].set(jnp.nan) for a in (k, v))
+    got = np.asarray(block_attention.attention(q, k, v, scale=0.25, lengths=jnp.asarray(tokens), block_q=16, block_k=32))
+    assert np.array_equal(got, clean)
 
 
 def test_a_window_layer_visits_under_half_of_the_causal_blocks_at_16k():
