@@ -35,15 +35,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pathway_tpu.observability import device_scopes
+from pathway_tpu.observability.device_scopes import scope
 from pathway_tpu.observability.tracing import NOOP_SPAN, get_tracer
 
 
-# The jax.named_scope names below are op metadata only (nothing computed
-# changes): a profiler capture shows the scan, the top-k and the corpus
-# preparation under these names instead of XLA's generated ones.
+# The scope names below are op metadata only (nothing computed changes): a
+# profiler capture shows the scan, the top-k and the corpus preparation
+# under these names instead of XLA's generated ones (device_scopes).
 
 
-@jax.named_scope("knn.scores")
+@scope("knn.scores")
 def _scores(
     queries: jax.Array, corpus: jax.Array, metric: str, bf16: bool
 ) -> jax.Array:
@@ -119,7 +121,7 @@ def _blockmax_topk(s: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     return scores, idx
 
 
-@jax.named_scope("knn.topk")
+@scope("knn.topk")
 def _masked_topk(s: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     """Exact top-k over [B, N] scores; ``topk_stage1`` picks the first
     stage. The old one takes the top-k of every 1,024-column block and
@@ -144,7 +146,7 @@ def _masked_topk(s: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     return jax.lax.top_k(s, k)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "metric", "bf16"))
+@functools.partial(device_scopes.jit, static_argnames=("k", "metric", "bf16"))
 def dense_topk(
     queries: jax.Array,  # [B, D] f32
     corpus: jax.Array,  # [N, D] f32 (padded)
@@ -167,8 +169,8 @@ def dense_topk(
 # change, NOT per query. Per-query work is one [B,D]x[D,N] MXU matmul + topk.
 
 
-@functools.partial(jax.jit, static_argnames=("metric", "bf16"))
-@jax.named_scope("corpus.prepare")
+@functools.partial(device_scopes.jit, static_argnames=("metric", "bf16"))
+@scope("corpus.prepare")
 def prepare_corpus(corpus: jax.Array, metric: str, bf16: bool = True):
     """Returns (prep [N,D], c2 [N]) — prep is normalized (cosine) and cast;
     c2 is the squared-norm column needed by l2sq."""
@@ -182,7 +184,7 @@ def prepare_corpus(corpus: jax.Array, metric: str, bf16: bool = True):
     return prep, c2
 
 
-@functools.partial(jax.jit, static_argnames=("k", "metric", "bf16"))
+@functools.partial(device_scopes.jit, static_argnames=("k", "metric", "bf16"))
 def dense_topk_prepared(
     queries: jax.Array,  # [B, D] f32
     prep: jax.Array,  # [N, D] prepared (normalized/cast)
@@ -192,7 +194,7 @@ def dense_topk_prepared(
     metric: str = "cosine",
     bf16: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    with jax.named_scope("knn.scores"):
+    with scope("knn.scores"):
         if metric == "cosine":
             q = queries / (
                 jnp.linalg.norm(queries, axis=-1, keepdims=True) + 1e-30
@@ -229,7 +231,7 @@ def shard_base_indices(n: int, n_shards: int) -> np.ndarray:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("k", "metric", "bf16", "mesh", "axis")
+    device_scopes.jit, static_argnames=("k", "metric", "bf16", "mesh", "axis")
 )
 def _sharded_topk_impl(queries, corpus, valid, base_idx, k, metric, bf16, mesh, axis):
     from jax.sharding import PartitionSpec as P
@@ -301,7 +303,7 @@ SCATTER_ROWS = 1024
 
 
 @functools.partial(
-    jax.jit,
+    device_scopes.jit,
     static_argnames=("sharding", "valid_sharding"),
     donate_argnames=("device", "valid", "prepared"),
 )
@@ -401,9 +403,63 @@ class DeviceCorpus:
         self._prepared: dict[tuple[str, bool], tuple[jax.Array, jax.Array]] = {}
         self._changed: set[int] = set()
         self.sharding = sharding
+        # every device program run, by what fixes its shape (device_programs)
+        self._ran: set[tuple] = set()
+        device_scopes.register(self)
 
     def __len__(self) -> int:
         return len(self.slot_of)
+
+    def device_programs(self):
+        """What ``device_scopes.tables()`` lowers again: the preparations,
+        scatters and searches made, each at the capacity it ran at."""
+        struct = jax.ShapeDtypeStruct
+
+        def corpus_arrays(capacity, copies=()):
+            rows = struct((capacity, self.dim), jnp.float32, sharding=self.sharding)
+            flags = struct((capacity,), jnp.bool_, sharding=self.valid_sharding)
+            prepared = {
+                (metric, bf16): (
+                    struct(rows.shape, jnp.bfloat16 if bf16 else jnp.float32, sharding=self.sharding),
+                    struct(flags.shape, jnp.float32, sharding=self.valid_sharding),
+                )
+                for metric, bf16 in copies
+            }
+            return rows, flags, prepared
+
+        for program, capacity, *rest in sorted(self._ran, key=repr):
+            if program == "prepare":
+                metric, bf16 = rest
+                rows, _flags, _ = corpus_arrays(capacity)
+                yield f"rows[{capacity}] {metric}", prepare_corpus, (rows, metric, bf16), {}
+            elif program == "scatter":
+                rows, flags, prepared = corpus_arrays(capacity, rest[0])
+                chunk = (
+                    struct((SCATTER_ROWS,), jnp.int32, sharding=self._replicated),
+                    struct((SCATTER_ROWS, self.dim), jnp.float32, sharding=self._replicated),
+                    struct((SCATTER_ROWS,), jnp.bool_, sharding=self._replicated),
+                )
+                yield (
+                    f"rows[{capacity}] copies{len(prepared)}",
+                    _scatter_rows,
+                    (rows, flags, prepared, *chunk),
+                    {"sharding": self.sharding, "valid_sharding": self.valid_sharding},
+                )
+            else:
+                shape, dtype, k, metric = rest
+                queries = struct(shape, jax.dtypes.canonicalize_dtype(dtype))
+                label = f"queries{list(shape)} rows[{capacity}] k{k} {metric}"
+                if program == "xla":  # the span's name for the one-chip search
+                    rows, flags, prepared = corpus_arrays(capacity, [(metric, SCAN_BF16)])
+                    prep, c2 = prepared[metric, SCAN_BF16]
+                    yield label, dense_topk_prepared, (queries, prep, c2, flags, k), {
+                        "metric": metric, "bf16": SCAN_BF16,
+                    }
+                else:  # "sharded": sharded_topk's call of its program
+                    rows, flags, _ = corpus_arrays(capacity)
+                    mesh, axis = self.sharding.mesh, self.sharding.spec[0]
+                    base = struct((capacity,), jnp.int32)
+                    yield label, _sharded_topk_impl, (queries, rows, flags, base, k, metric, True, mesh, axis), {}
 
     def upsert(self, key: int, vector: np.ndarray) -> None:
         slot = self.slot_of.get(key)
@@ -521,6 +577,7 @@ class DeviceCorpus:
             bf16=any(bf16 for _metric, bf16 in self._prepared),
             rows=len(self),
         ) as span:
+            self._ran.add(("scatter", self.capacity, tuple(sorted(self._prepared))))
             for chunk in chunks:
                 self._device, self._device_valid, self._prepared = _scatter_rows(
                     self._device,
@@ -550,6 +607,7 @@ class DeviceCorpus:
             with get_tracer().span(
                 "corpus.prepare", metric=metric, bf16=bf16, rows=len(self)
             ) as span:
+                self._ran.add(("prepare", self.capacity, metric, bf16))
                 self._prepared[key] = prepare_corpus(device, metric, bf16)
                 _ready_if_live(span, self._prepared[key])
         prep, c2 = self._prepared[key]
@@ -602,6 +660,7 @@ class DeviceCorpus:
                 metric=metric,
                 bf16=SCAN_BF16,
             )
+        self._ran.add((attrs["kernel"], self.capacity, queries.shape, queries.dtype, k, metric))
         with get_tracer().span("index.topk", **attrs):
             scores, slots = run()
             return np.asarray(scores), np.asarray(slots)

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pathway_tpu.observability.device_scopes import scope
 from pathway_tpu.ops.backend import pallas_interpret
 
 GMM_KERNEL_NAME = "moe_grouped_matmul"
@@ -264,20 +265,23 @@ def expert_layer(
     choice [T, k] (-1 at a padding position: routed nowhere). ``bias`` is
     the router's correction bias [E], or None where it has none."""
     n_experts = router.shape[1]
-    with jax.named_scope("trunk.moe.route"):
+    with scope("trunk.moe.route"):
         # the sigmoid router is called as it always was: a stand-in for it need not know the other
         other = {} if scoring == "sigmoid" else {"scoring": scoring}
         weights, choice = route(h, router, bias, top_k=top_k, scale=scale, normalise=normalise, **other)
+    with scope("trunk.moe.dispatch"):
         plan = dispatch(choice, valid, n_experts, experts_held)
     rows = plan.src.shape[0]
     tile_group, used = tile_groups(plan.group_sizes, rows // TILE_ROWS)
     step = pass_rows(choice.size, n_experts, w_gate.shape[0])
 
     def one_pass(first_row, first_tile, src, tiles):
-        with jax.named_scope("trunk.moe.experts"):
+        with scope("trunk.moe.gather"):
+            rows_in = gather_rows(h, src)
+        with scope("trunk.moe.experts"):
             live = jnp.maximum(used - first_tile, 0)
-            y = grouped_ffn(gather_rows(h, src), w_gate, w_up, w_down, tiles, live)
-        with jax.named_scope("trunk.moe.combine"):
+            y = grouped_ffn(rows_in, w_gate, w_up, w_down, tiles, live)
+        with scope("trunk.moe.combine"):
             return combine(y, plan, weights, first_row)
 
     if step >= rows:  # every row at once

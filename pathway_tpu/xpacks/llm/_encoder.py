@@ -15,6 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from pathway_tpu.observability import device_scopes
+from pathway_tpu.observability.device_scopes import scope
+
 
 class TransformerEncoder(nn.Module):
     vocab_size: int = 30522
@@ -27,31 +30,37 @@ class TransformerEncoder(nn.Module):
 
     @nn.compact
     def __call__(self, ids, mask):
-        x = nn.Embed(self.vocab_size, self.dim, dtype=self.dtype)(ids)
-        pos = nn.Embed(self.max_len, self.dim, dtype=self.dtype)(
-            jnp.arange(ids.shape[1])[None, :]
-        )
-        x = x + pos
+        # the scopes are op metadata only (device_scopes): flax names its
+        # modules by their order, so the parameter tree is as it was
+        with scope("encoder.embed"):
+            x = nn.Embed(self.vocab_size, self.dim, dtype=self.dtype)(ids)
+            pos = nn.Embed(self.max_len, self.dim, dtype=self.dtype)(
+                jnp.arange(ids.shape[1])[None, :]
+            )
+            x = x + pos
         attn_mask = mask[:, None, None, :] * mask[:, None, :, None]
         for _ in range(self.depth):
-            h = nn.LayerNorm(dtype=self.dtype)(x)
-            h = nn.MultiHeadDotProductAttention(
-                num_heads=self.heads,
-                dtype=self.dtype,
-                deterministic=True,
-            )(h, h, mask=attn_mask.astype(bool))
-            x = x + h
-            h = nn.LayerNorm(dtype=self.dtype)(x)
-            h = nn.Dense(self.dim * self.mlp_ratio, dtype=self.dtype)(h)
-            h = nn.gelu(h)
-            h = nn.Dense(self.dim, dtype=self.dtype)(h)
-            x = x + h
-        x = nn.LayerNorm(dtype=self.dtype)(x)
-        # masked mean pool + L2 normalize (sentence-transformers convention)
-        denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1.0)
-        pooled = (x * mask[:, :, None]).sum(axis=1) / denom
-        pooled = pooled.astype(jnp.float32)
-        return pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-12)
+            with scope("encoder.attention"):
+                h = nn.LayerNorm(dtype=self.dtype)(x)
+                h = nn.MultiHeadDotProductAttention(
+                    num_heads=self.heads,
+                    dtype=self.dtype,
+                    deterministic=True,
+                )(h, h, mask=attn_mask.astype(bool))
+                x = x + h
+            with scope("encoder.ffn"):
+                h = nn.LayerNorm(dtype=self.dtype)(x)
+                h = nn.Dense(self.dim * self.mlp_ratio, dtype=self.dtype)(h)
+                h = nn.gelu(h)
+                h = nn.Dense(self.dim, dtype=self.dtype)(h)
+                x = x + h
+        with scope("encoder.pool"):
+            x = nn.LayerNorm(dtype=self.dtype)(x)
+            # masked mean pool + L2 normalize (sentence-transformers convention)
+            denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1.0)
+            pooled = (x * mask[:, :, None]).sum(axis=1) / denom
+            pooled = pooled.astype(jnp.float32)
+            return pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-12)
 
 
 class CrossEncoderHead(nn.Module):
@@ -144,14 +153,19 @@ class EncoderRuntime:
         else:
             self._in_shard = None
 
-        @jax.jit
         def fwd(params, ids, mask):
             # op metadata only: a profiler capture shows the forward's ops
             # under this name instead of XLA's generated ones
-            with jax.named_scope("encoder.forward"):
+            with scope("encoder.forward"):
                 return self.model.apply(params, ids, mask)
 
-        self._fwd = fwd
+        self._fwd = device_scopes.jit(fwd)
+        self._ran: set[tuple] = set()  # (shape, ids dtype, mask dtype) of every forward made
+        device_scopes.register(self)
+
+    def device_programs(self):
+        """What ``device_scopes.tables()`` lowers again: every forward made."""
+        return device_scopes.forwards(self._fwd, self.params, self._ran, self._in_shard)
 
     def batch_bucket(self, n: int, width: int = 0) -> int:
         """The batch dimension a batch of ``n`` is padded to at ``width`` positions."""
@@ -179,6 +193,7 @@ class EncoderRuntime:
         if self._in_shard is not None:
             ids_j = jax.device_put(ids_j, self._in_shard)
             mask_j = jax.device_put(mask_j, self._in_shard)
+        self._ran.add((ids_j.shape, ids_j.dtype, mask_j.dtype))
         out = self._fwd(self.params, ids_j, mask_j)
         out.copy_to_host_async()
         info = {

@@ -57,6 +57,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pathway_tpu.observability import device_scopes
+from pathway_tpu.observability.device_scopes import scope
 from pathway_tpu.ops import block_attention, moe, ssd_scan
 from pathway_tpu.xpacks.llm._encoder import _bucket_batch
 
@@ -525,7 +527,8 @@ def _mamba2(p, h, c: TrunkConfig, ctx: dict):
     heads, width, states = c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state
     inner = heads * width
     batch, length, _ = h.shape
-    projected = _dot(h, p["w_in"])
+    with scope("trunk.mamba2.in_proj"):
+        projected = _dot(h, p["w_in"])
     z, dt = projected[..., :inner], projected[..., -heads:]
 
     def convolved(first: int, channels: int):
@@ -535,17 +538,18 @@ def _mamba2(p, h, c: TrunkConfig, ctx: dict):
         part = projected[..., inner + first : inner + first + channels]
         return jax.nn.silu(causal_conv(part, p["conv"][:, at], p["conv_bias"][at])).astype(h.dtype)
 
-    with jax.named_scope("trunk.mamba2.conv"):
+    with scope("trunk.mamba2.conv"):
         x, b, c_ = convolved(0, inner), convolved(inner, states), convolved(inner + states, states)
-    with jax.named_scope("trunk.mamba2.scan"):
+    with scope("trunk.mamba2.scan"):
         y = ssd_scan.scan(
             x.reshape(batch, length, heads, width),
             jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)),
             -jnp.exp(p["A_log"].astype(jnp.float32)),
             b, c_, p["D"].astype(jnp.float32), chunk=c.mamba_chunk_size,
         )
-    gated = y.reshape(batch, length, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    return _dot(rms_norm(gated, p["norm"], c.rms_norm_eps).astype(h.dtype), p["w_out"])
+    with scope("trunk.mamba2.gate_out"):
+        gated = y.reshape(batch, length, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        return _dot(rms_norm(gated, p["norm"], c.rms_norm_eps).astype(h.dtype), p["w_out"])
 
 
 ATTENTION = {
@@ -605,7 +609,7 @@ def _moe(p, h, c: TrunkConfig, ctx: dict):
     )
     ctx["expert_counts"].append(counts)
     ctx["expert_choice"].append(choice.reshape(h.shape[:-1] + choice.shape[-1:]))
-    with jax.named_scope("trunk.ffn"):
+    with scope("trunk.moe.shared"):
         shared = _gated_ffn(p["shared"], flat).astype(jnp.float32)
     if c.shared_expert_combination_strategy == "average":
         shared = shared / c.n_shared_experts
@@ -669,11 +673,11 @@ def _mhc(p, streams, sublayer, c: TrunkConfig):
     """``X <- H_res X + H_post^T F(u)`` with ``u = H_pre X``; ``sublayer`` is
     F with its own norm in front."""
     n = c.hc_mult
-    with jax.named_scope("trunk.mhc"):
+    with scope("trunk.mhc"):
         h_pre, h_post, h_res = mhc_coefficients(p, streams, c)
         mixed_in = sum(h_pre[i][..., None] * streams[i] for i in range(n)).astype(streams.dtype)
     out = sublayer(mixed_in)
-    with jax.named_scope("trunk.mhc"):
+    with scope("trunk.mhc"):
         return jnp.stack(
             [
                 (
@@ -880,27 +884,29 @@ def forward(params, ids, mask, *, config: TrunkConfig):
         ctx["rope"] = rope_tables(config, ids.shape[1])
     if "gqa_window" in kinds_of_attention:
         ctx["rope_pairs"] = interleaved_rope_tables(config, ids.shape[1])
-    x = params["embed"][ids]
-    if config.embedding_multiplier != 1:
-        x = (x.astype(jnp.float32) * config.embedding_multiplier).astype(x.dtype)
+    with scope("trunk.embed"):
+        x = params["embed"][ids]
+        if config.embedding_multiplier != 1:
+            x = (x.astype(jnp.float32) * config.embedding_multiplier).astype(x.dtype)
     state = _block(RESIDUAL, table[0].residual, "residual").enter(x, config)
     for kinds, p in zip(table, params["layers"]):
         attention = _block(ATTENTION, kinds.attention, "attention")
         ffn = _block(FFN, kinds.ffn, "feed-forward")
 
         def attend(p, h, attention=attention):
-            with jax.named_scope(attention.scope):
+            with scope(attention.scope):
                 return attention.apply(p, h, config, ctx)
 
         def feed(p, h, ffn=ffn):
-            with jax.named_scope(ffn.scope):
+            with scope(ffn.scope):
                 return ffn.apply(p, h, config, ctx)
 
         state = _block(RESIDUAL, kinds.residual, "residual").layer(p, state, attend, feed, config)
     # pool before the last norm: only the last real position of each row is kept
-    last = jnp.maximum(lengths - 1, 0)
-    pooled = norm(_block(RESIDUAL, table[-1].residual, "residual").exit(state, last), params["final_norm"], config)
-    vectors = pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-12)
+    with scope("trunk.pool"):
+        last = jnp.maximum(lengths - 1, 0)
+        pooled = norm(_block(RESIDUAL, table[-1].residual, "residual").exit(state, last), params["final_norm"], config)
+        vectors = pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-12)
     if not ctx["expert_counts"]:  # no expert layer in the table
         top_k, experts = max(config.num_experts_per_tok, 1), max(config.n_routed_experts, 1)
         return vectors, jnp.zeros((0, experts), jnp.int32), jnp.zeros((0,) + ids.shape + (top_k,), jnp.int32)
@@ -931,7 +937,9 @@ class TrunkRuntime:
         self.dtype = dtype
         self._seed = seed
         self._params = None
-        self._fwd = jax.jit(functools.partial(forward, config=config))
+        self._fwd = device_scopes.jit(functools.partial(forward, config=config))
+        self._ran: set[tuple] = set()  # (shape, ids dtype, mask dtype) of every forward made
+        device_scopes.register(self)
         # the window (None: the whole row) of each blocked attention layer
         self._windows = [
             config.sliding_window if kinds.attention == "gqa_window" else None
@@ -952,6 +960,10 @@ class TrunkRuntime:
 
     def batch_bucket(self, n: int, width: int = 0) -> int:
         return _bucket_batch(n, width)
+
+    def device_programs(self):
+        """What ``device_scopes.tables()`` lowers again: every forward made."""
+        return device_scopes.forwards(self._fwd, self.params, self._ran)
 
     def _attention_pairs(self, lengths: np.ndarray, width: int) -> dict:
         """What the blocked attention layers of one forward are asked for and
@@ -1002,7 +1014,9 @@ class TrunkRuntime:
         if bucket != n:
             ids = np.pad(ids, ((0, bucket - n), (0, 0)))
             mask = np.pad(mask, ((0, bucket - n), (0, 0)))
-        out, counts, choice = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))
+        ids_j, mask_j = jnp.asarray(ids), jnp.asarray(mask)
+        self._ran.add((ids_j.shape, ids_j.dtype, mask_j.dtype))
+        out, counts, choice = self._fwd(self.params, ids_j, mask_j)
         out.copy_to_host_async()
         info = {
             "batch_bucket": bucket,

@@ -172,13 +172,16 @@ def _spawn_group(script_path, n, port, extra_env=None, timeout=150):
                 [sys.executable, str(script_path)],
                 env=env,
                 stdout=subprocess.PIPE,
-                stderr=subprocess.STDOUT,
+                stderr=subprocess.PIPE,
                 text=True,
             )
         )
     outs = []
     try:
-        outs = [p.communicate(timeout=timeout)[0] for p in procs]
+        # a child's log lines (stderr) after the lines the tests parse
+        # (stdout), never between them: on one pipe a warning could land
+        # inside a "RESULT {...}" line
+        outs = ["\n".join(p.communicate(timeout=timeout)) for p in procs]
     finally:
         for p in procs:
             if p.poll() is None:
