@@ -24,7 +24,12 @@ by kind, so another architecture adds kinds, not branches. Kinds so far:
   experts; shared experts summed or averaged, of the routed width each or of
   ``shared_intermediate_size`` together);
 * residual ``mhc``: manifold-constrained hyper-connections (arXiv:2512.24880),
-  ``hc_mult`` residual streams mixed by a doubly stochastic matrix per token;
+  ``hc_mult`` residual streams mixed by a doubly stochastic matrix per token.
+  A sub-layer's step is the two Pallas kernels of ``ops/residual_mix.py`` and
+  nothing else: ``mix_in`` reads the streams once and makes the coefficients
+  (float32: RMS scale, sigmoids, the Sinkhorn rounds) and the sub-layer's
+  input, ``mix_out`` reads them once more with the sub-layer's output and
+  writes the new streams where the old ones were;
   ``add``: ``x + m attn(norm x)``, then ``x + m ffn(norm x)``, ``m`` the
   config's ``residual_multiplier`` (1 where it has none); ``parallel``: one
   norm a layer, ``x + attn(h) + ffn(h)``;
@@ -59,7 +64,7 @@ import numpy as np
 
 from pathway_tpu.observability import device_scopes
 from pathway_tpu.observability.device_scopes import scope
-from pathway_tpu.ops import block_attention, moe, ssd_scan
+from pathway_tpu.ops import block_attention, moe, residual_mix, ssd_scan
 from pathway_tpu.xpacks.llm._encoder import _bucket_batch
 
 # ``cohere2_moe``'s names for what the fields below hold, and what its
@@ -634,59 +639,29 @@ def _mhc_shapes(c: TrunkConfig) -> dict:
     }
 
 
-def sinkhorn(matrix, iters: int, eps: float):
-    """``matrix`` [n, n, ...] positive: ``iters`` rounds of a row then a
-    column normalisation, towards a doubly stochastic matrix per position."""
-    for _ in range(iters):
-        matrix = matrix / (matrix.sum(axis=1, keepdims=True) + eps)
-        matrix = matrix / (matrix.sum(axis=0, keepdims=True) + eps)
-    return matrix
-
-
 def mhc_coefficients(p, streams, c: TrunkConfig):
-    """H_pre [n, B, T], H_post [n, B, T] and H_res [n, n, B, T] of ``streams``
-    [n, B, T, d], in float32. The token axes are kept last: they fill the lanes.
-    Each pass reads the streams as they are stored and converts on the way:
-    no float32 copy of them is made."""
-    n = c.hc_mult
-    squares = sum(jnp.square(streams[i].astype(jnp.float32)).sum(axis=-1) for i in range(n))
-    scale = jax.lax.rsqrt(squares / (n * streams.shape[-1]) + c.rms_norm_eps)  # [B, T]
-    # x' P = rms_scale * (x (gain * P)): the gain goes into the small matrix,
-    # and the product is taken stream by stream, each read where it lies
-    proj = (p["norm"].astype(jnp.float32)[:, :, None] * p["proj"].astype(jnp.float32))
-    proj = proj.astype(streams.dtype)
-    raw = sum(
-        jnp.einsum("btd,dc->btc", streams[i], proj[i], preferred_element_type=jnp.float32)
-        for i in range(n)
+    """The first pass over ``streams`` [n, B, T, d] (``ops/residual_mix.py``
+    ``mix_in``): the sub-layer's input ``u = H_pre X`` [B, T, d] and the
+    packed float32 coefficients H_pre, H_post, H_res, which ``mix_out``
+    reads and ``residual_mix.coefficients`` unpacks."""
+    # x' P = rms_scale * (x (gain * P)): the gain goes into the small matrix
+    proj = p["norm"].astype(jnp.float32)[:, :, None] * p["proj"].astype(jnp.float32)
+    return residual_mix.mix_in(
+        streams, proj.astype(streams.dtype), p["alpha"], p["bias"],
+        iters=c.hc_sinkhorn_iters, eps=c.hc_eps, rms_eps=c.rms_norm_eps,
+        clamp=(c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max),
     )
-    raw = jnp.moveaxis(raw, -1, 0) * scale[None]
-    alpha, bias = p["alpha"].astype(jnp.float32), p["bias"].astype(jnp.float32)
-    pre = alpha[0] * raw[:n] + bias[:n, None, None]
-    post = alpha[1] * raw[n : 2 * n] + bias[n : 2 * n, None, None]
-    res = alpha[2] * raw[2 * n :] + bias[2 * n :, None, None]
-    res = jnp.clip(res, c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max)
-    res = sinkhorn(jnp.exp(res).reshape((n, n) + res.shape[1:]), c.hc_sinkhorn_iters, c.hc_eps)
-    return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), res
 
 
 def _mhc(p, streams, sublayer, c: TrunkConfig):
     """``X <- H_res X + H_post^T F(u)`` with ``u = H_pre X``; ``sublayer`` is
-    F with its own norm in front."""
-    n = c.hc_mult
+    F with its own norm in front. Two passes over the streams, a kernel each:
+    one going in, one coming out."""
     with scope("trunk.mhc"):
-        h_pre, h_post, h_res = mhc_coefficients(p, streams, c)
-        mixed_in = sum(h_pre[i][..., None] * streams[i] for i in range(n)).astype(streams.dtype)
+        mixed_in, coef = mhc_coefficients(p, streams, c)
     out = sublayer(mixed_in)
     with scope("trunk.mhc"):
-        return jnp.stack(
-            [
-                (
-                    sum(h_res[i, j][..., None] * streams[j] for j in range(n))
-                    + h_post[i][..., None] * out
-                ).astype(streams.dtype)
-                for i in range(n)
-            ]
-        )
+        return residual_mix.mix_out(streams, out, coef)
 
 
 def _mhc_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
@@ -708,9 +683,10 @@ def _mhc_enter(x, c: TrunkConfig):
 
 def _mhc_exit(streams, last):
     """The summed streams at each row's position ``last``: [B, d] float32.
-    Picked before the sum: only that position of each row is kept."""
-    picked = jnp.take_along_axis(streams, last[None, :, None, None], axis=2)[:, :, 0]  # [n, B, d]
-    return picked.astype(jnp.float32).sum(axis=0)
+    One masked sum over the positions, reading the streams where the last
+    kernel left them: a gather would first lay all of them out anew."""
+    here = jnp.arange(streams.shape[2])[None, :] == last[:, None]  # [B, T]
+    return jnp.where(here[None, :, :, None], streams, 0).astype(jnp.float32).sum(axis=(0, 2))
 
 
 def _add_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
@@ -752,10 +728,11 @@ class Residual(NamedTuple):
     layer: Callable  # (parameters, state, attend, feed, config) -> state
     enter: Callable  # (embedded tokens [B, T, d], config) -> state
     exit: Callable  # (state, last [B]) -> [B, d] float32
+    path: str | None = None  # what a forward's span says of the path that ran, where there is something to tell apart
 
 
 RESIDUAL = {
-    "mhc": Residual(_mhc_shapes_of_a_layer, _mhc_layer, _mhc_enter, _mhc_exit),
+    "mhc": Residual(_mhc_shapes_of_a_layer, _mhc_layer, _mhc_enter, _mhc_exit, path="mhc_fused"),
     "add": Residual(_add_shapes_of_a_layer, _add_layer, lambda x, c: x, _one_stream_exit),
     "parallel": Residual(_parallel_shapes_of_a_layer, _parallel_layer, lambda x, c: x, _one_stream_exit),
 }
@@ -940,13 +917,16 @@ class TrunkRuntime:
         self._fwd = device_scopes.jit(functools.partial(forward, config=config))
         self._ran: set[tuple] = set()  # (shape, ids dtype, mask dtype) of every forward made
         device_scopes.register(self)
+        table = config.layer_table()
         # the window (None: the whole row) of each blocked attention layer
         self._windows = [
             config.sliding_window if kinds.attention == "gqa_window" else None
-            for kinds in config.layer_table()
+            for kinds in table
             if kinds.attention in ("gqa_window", "gqa_full")
         ]
-        self._scans = sum(kinds.attention == "mamba2" for kinds in config.layer_table())
+        self._scans = sum(kinds.attention == "mamba2" for kinds in table)
+        path = _block(RESIDUAL, table[0].residual, "residual").path
+        self._residual = {} if path is None else {"residual": path}
 
     @property
     def params(self):
@@ -1002,7 +982,9 @@ class TrunkRuntime:
         """Starts the forward of one padded batch and returns the call that
         waits for it (``EncoderRuntime.dispatch``): vectors [n, dim] and what
         was really forwarded, the padded shape and the expert layers' row
-        counts, where the table has blocked attention layers their
+        counts, ``residual="mhc_fused"`` where the streams are mixed by the two
+        kernels of ``ops/residual_mix.py``, where the table has blocked
+        attention layers their
         ``attn_pairs_allowed`` and ``attn_pairs_visited``, and where it has
         ``mamba2`` layers their ``ssm_chunks_useful`` and
         ``ssm_chunks_visited``. ``routing=True``
@@ -1023,6 +1005,7 @@ class TrunkRuntime:
             "len_bucket": int(ids.shape[1]),
             "tokens_padded": int(ids.size),
             "trunk": self.config.name,
+            **self._residual,
             **self._attention_pairs(lengths, int(ids.shape[1])),
             **self._scan_chunks(lengths, bucket, int(ids.shape[1])),
         }

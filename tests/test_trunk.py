@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from benchmarks.harness import reference_trunk as ref
-from pathway_tpu.ops import moe
+from pathway_tpu.ops import moe, residual_mix
 from pathway_tpu.xpacks.llm import _trunk
 from pathway_tpu.xpacks.llm._trunk import TrunkConfig, TrunkRuntime
 
@@ -176,7 +176,8 @@ def test_residual_block_and_sinkhorn(blocks):
     ref_streams = jnp.transpose(streams, (1, 2, 0, 3))
     want = ref.residual(p, ref_streams, sublayer, body)
     assert np.abs(np.asarray(jnp.transpose(got, (1, 2, 0, 3))) - np.asarray(want)).max() < 1e-4
-    _pre, _post, h_res = _trunk.mhc_coefficients(p, streams, config)
+    _mixed_in, packed = _trunk.mhc_coefficients(p, streams, config)  # as the mix-in kernel hands them to mix-out
+    _pre, _post, h_res = residual_mix.coefficients(packed, n, h.shape[1])
     h_res = np.asarray(h_res)  # [n, n, B, T]
     assert np.abs(h_res.sum(axis=0) - 1).max() < 1e-4 and np.abs(h_res.sum(axis=1) - 1).max() < 1e-4
     assert h_res.min() > 0 and not np.allclose(h_res, np.swapaxes(h_res, 0, 1), atol=1e-3)
@@ -304,7 +305,7 @@ def test_embedder_with_trunk_runs_the_same_embed_batch(toy):
     moe_layers = 2
     assert forward.attributes == {
         "groups": 1, "batch_bucket": 8, "len_bucket": 16, "tokens_real": 14, "tokens_padded": 128,
-        "trunk": "toy", "expert_rows_useful": 14 * 2 * moe_layers,
+        "trunk": "toy", "residual": "mhc_fused", "expert_rows_useful": 14 * 2 * moe_layers,
         "expert_rows_computed": forward.attributes["expert_rows_computed"],
         "expert_tokens_max": forward.attributes["expert_tokens_max"],
         "expert_tokens_mean": 14 * 2 / 8,

@@ -169,3 +169,64 @@ def test_the_paper_forward_fits_a_v5e_and_keeps_no_decay_mask(one_chip, no_cache
     mask = rows * width * 128 * 256
     floats = [math.prod(map(int, dims.split(","))) for dims in re.findall(r"f32\[([\d,]+)\]", text)]
     assert max(floats) <= rows * width * 16768 < mask and f"{width // 256},128,256,256]" not in text
+
+
+@pytest.mark.parametrize("batch", [32, 8], ids=["batch_32x512", "probe_8x512"])
+def test_the_residual_mix_kernels_compile_for_a_v5e(one_chip, no_cache, monkeypatch, batch):
+    """Xing4.0-29B-A4B's four streams of 3584 at the cell's two shapes: both
+    kernels as Mosaic compiles them, 256 whole rows a block, the second one
+    writing where it read."""
+    from pathway_tpu.ops import residual_mix
+
+    monkeypatch.setattr(residual_mix, "pallas_interpret", lambda: False)
+    n, length, packed = 4, 512, residual_mix.packed_rows(4)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def mix_in(streams, proj, alpha, bias):
+        return residual_mix.mix_in(streams, proj, alpha, bias, iters=20, eps=1e-6, rms_eps=1e-6, clamp=(-30.0, 30.0))
+
+    going_in = jax.jit(mix_in).lower(
+        shape((n, batch, length, D), jnp.bfloat16), shape((n, D, packed), jnp.bfloat16),
+        shape((3,), jnp.float32), shape((packed,), jnp.float32),
+    ).compile()
+    assert residual_mix.MIX_IN_KERNEL_NAME in going_in.as_text() and residual_mix.row_block(length) == 256
+    coming_out = jax.jit(residual_mix.mix_out, donate_argnums=0).lower(
+        shape((n, batch, length, D), jnp.bfloat16), shape((batch, length, D), jnp.bfloat16),
+        shape((batch, length // 256, packed, 256), jnp.float32),
+    ).compile()
+    assert residual_mix.MIX_OUT_KERNEL_NAME in coming_out.as_text()
+    memory = coming_out.memory_analysis()
+    assert memory.alias_size_in_bytes == n * batch * length * D * 2 and memory.temp_size_in_bytes < 2**20  # in place
+
+
+def test_a_residual_step_passes_over_its_streams_twice_and_no_more(one_chip, no_cache, monkeypatch):
+    """One ``_mhc`` sub-layer at the cell's 32 x 512: the stacked [4, 32, 512,
+    3584] streams come out of the mix-out kernel and of nothing else (no
+    ``jnp.stack``, no second elementwise pass), and the scope ``trunk.mhc``
+    holds the two kernels and a few small operations on the parameters, not
+    the hundred fusions XLA made of the formulas."""
+    from pathway_tpu.observability import device_scopes
+    from pathway_tpu.ops import residual_mix
+    from pathway_tpu.xpacks.llm import _trunk
+
+    monkeypatch.setattr(residual_mix, "pallas_interpret", lambda: False)
+    config = _trunk.TrunkConfig.from_file(
+        os.path.join(ROOT, "benchmarks", "configs", "xing4-29b-a4b.json"), name="xing4-29b-a4b"
+    )
+    template = jax.eval_shape(lambda: _trunk.init_params(config, 0, jnp.bfloat16)["layers"][0]["attn_res"])
+    p = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), template)
+    streams = jax.ShapeDtypeStruct((4, 32, 512, D), jnp.bfloat16, sharding=one_chip)
+
+    def step(p, streams):
+        return _trunk._mhc(p, streams, jnp.tanh, config)
+
+    text = jax.jit(step, donate_argnums=1).lower(p, streams).compile().as_text()
+    free = {"parameter", "bitcast", "get-tuple-element", "reshape", "tuple", "constant"}  # no operation of the device's
+    rows = [r for r in device_scopes.rows_of(text) if r.opcode not in free]
+    stacked = [r for r in rows if r.type.startswith("bf16[4,32,512,3584]")]
+    assert [r.name.split(".")[0] for r in stacked] == [residual_mix.MIX_OUT_KERNEL_NAME]
+    mhc = [r.name.split(".")[0] for r in rows if r.scope == "trunk.mhc"]
+    assert residual_mix.MIX_IN_KERNEL_NAME in mhc and residual_mix.MIX_OUT_KERNEL_NAME in mhc
+    assert len(mhc) < 20, mhc
