@@ -64,6 +64,12 @@ VOCABULARY = (
     "trunk.mamba2.conv",
     "trunk.mamba2.scan",
     "trunk.mamba2.gate_out",
+    "trunk.gdn",
+    "trunk.gdn.in_proj",
+    "trunk.gdn.conv",
+    "trunk.gdn.scan",
+    "trunk.gdn.gate_out",
+    "trunk.gqa_gated",
     "trunk.ffn",
     "trunk.moe",
     "trunk.moe.route",

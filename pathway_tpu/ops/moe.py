@@ -3,7 +3,8 @@
 An expert layer sends each token to ``top_k`` of ``n_experts`` gated
 feed-forward experts and sums what they return, weighted by the router
 (``route``: the sigmoid router, bias-corrected where the model has a bias,
-or the top-k of the logits softmaxed over the chosen k).
+or the top-k of the logits softmaxed over the chosen k). The shared experts,
+summed, averaged or behind a sigmoid gate, are the trunk's (``_trunk._moe``).
 Nothing is dropped and there is no capacity factor: the (token, expert)
 pairs are sorted by expert into contiguous groups of rows (``dispatch``),
 every group is multiplied by its own expert's weights in one grouped matmul
@@ -27,7 +28,9 @@ rows, so that every row tile belongs to one expert. The expert's matrix is
 staged in VMEM in column blocks of at most ``_WEIGHT_BLOCK_BYTES``
 (``column_block``): the grid walks the column blocks outermost and the row
 tiles inside, so a block is staged once a group and the row tiles are read
-once a column block. A 3584 x 1024 or 1024 x 3584 matrix is one block, as
+once a column block. Groups are as many as the chip holds: 256 held of 512
+(2048 x 512 matrices, about 200 rows an expert in a 16,384-position forward)
+take the same path, one pass of ``pass_rows``. A 3584 x 1024 or 1024 x 3584 matrix is one block, as
 before the tiling; a 4096 x 4096 one is four of 4096 x 1024. Its device ops
 are called ``GMM_KERNEL_NAME`` in a trace. Measured on a v5e against
 ``jax.lax.ragged_dot`` at 16,384 x 4 rows into 64 groups of 3584 x 1024

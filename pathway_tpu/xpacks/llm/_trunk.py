@@ -16,12 +16,23 @@ by kind, so another architecture adds kinds, not branches. Kinds so far:
   ``attention_multiplier`` where one is given; ``mamba2``: a Mamba-2 mixer in
   the attention's place (in-projection, depthwise causal convolution, the
   chunked state-space scan of ``ops/ssd_scan.py``, gated RMS norm,
-  out-projection), prefill form, no state kept between calls; a model's
-  ``layer_types`` names them layer by layer;
+  out-projection), prefill form, no state kept between calls;
+  ``gated_deltanet``: a Gated DeltaNet mixer in the attention's place
+  (``[q | k | v | z]`` and ``[b | a]`` projections, a bias-free depthwise
+  causal convolution and silu, q and k L2-normalised a head, ``beta =
+  sigmoid(b)`` and ``g = -exp(A_log) softplus(a + dt_bias)``, the chunked
+  gated delta rule of ``ops/gated_delta.py``, a gated RMS norm a head,
+  out-projection), prefill form; ``gqa_gated``: grouped-query attention
+  through ``ops/block_attention.py`` with q and k through the config's norm
+  a head, rotary by halves on the first ``head_dim * rotary_pct`` dims and
+  the output times ``sigmoid(gate)``, the gate a second half of each query
+  head's projection; a model's ``layer_types`` (or ``qwen3_next``'s
+  ``full_attention_interval``) names them layer by layer;
 * feed-forward ``dense`` (gated silu) and ``moe`` (``ops/moe.py``: a sigmoid
   router, its top-k bias-corrected and scaled where the config says so, or
   the top-k of the logits softmaxed over the chosen k; dropless grouped
-  experts; shared experts summed or averaged, of the routed width each or of
+  experts; shared experts summed, averaged, or scaled by ``sigmoid(h
+  w_sg)`` (``sigmoid_gate``), of the routed width each or of
   ``shared_intermediate_size`` together);
 * residual ``mhc``: manifold-constrained hyper-connections (arXiv:2512.24880),
   ``hc_mult`` residual streams mixed by a doubly stochastic matrix per token.
@@ -33,11 +44,13 @@ by kind, so another architecture adds kinds, not branches. Kinds so far:
   ``add``: ``x + m attn(norm x)``, then ``x + m ffn(norm x)``, ``m`` the
   config's ``residual_multiplier`` (1 where it has none); ``parallel``: one
   norm a layer, ``x + attn(h) + ffn(h)``;
-* norm ``rms`` and ``layer`` (mean-centred, a gain and no bias).
+* norm ``rms``, ``layer`` (mean-centred, a gain and no bias) and
+  ``rms_offset`` (zero-centred: ``x / rms(x) * (1 + w)``, every norm of a
+  ``qwen3_next`` table, its q/k norms too).
 
 ``TrunkConfig.from_dict`` reads the key names of the ``model_type`` it is
-given (``cohere2_moe``'s and ``granitemoehybrid``'s beside the default ones)
-onto the same fields. The embedded tokens are multiplied by
+given (``cohere2_moe``'s, ``granitemoehybrid``'s and ``qwen3_next``'s beside
+the default ones) onto the same fields. The embedded tokens are multiplied by
 ``embedding_multiplier`` where the config states one.
 
 ``TrunkRuntime`` has ``EncoderRuntime``'s surface and is what
@@ -64,7 +77,7 @@ import numpy as np
 
 from pathway_tpu.observability import device_scopes
 from pathway_tpu.observability.device_scopes import scope
-from pathway_tpu.ops import block_attention, moe, residual_mix, ssd_scan
+from pathway_tpu.ops import block_attention, gated_delta, moe, residual_mix, ssd_scan
 from pathway_tpu.xpacks.llm._encoder import _bucket_batch
 
 # ``cohere2_moe``'s names for what the fields below hold, and what its
@@ -86,6 +99,23 @@ _GRANITE_HYBRID_FIXED = {
     "scoring_func": "softmax", "norm_topk_prob": True, "topk_method": "greedy", "routed_scaling_factor": 1.0,
     "n_shared_experts": 1, "first_k_dense_replace": 0, "hc_mult": 1,
 }
+# ``qwen3_next``'s: its modelling code has the top-k of the logits softmaxed
+# over the chosen k, one shared expert behind a sigmoid gate, q/k norms, rotary
+# by halves, zero-centred norms and one residual stream; its table follows
+# from ``full_attention_interval`` (every n-th layer full, the others linear)
+_QWEN3_NEXT_KEYS = {
+    "num_experts": "n_routed_experts",
+    "shared_expert_intermediate_size": "shared_intermediate_size",
+    "partial_rotary_factor": "rotary_pct",
+}
+_QWEN3_NEXT_FIXED = {
+    "scoring_func": "softmax", "topk_method": "greedy", "routed_scaling_factor": 1.0,
+    "n_shared_experts": 1, "first_k_dense_replace": 0, "hc_mult": 1, "use_qk_norm": True,
+    "shared_expert_combination_strategy": "sigmoid_gate", "position_embedding_type": "rope_half",
+}
+_QWEN3_NEXT_KINDS = {"linear_attention": "gated_deltanet", "full_attention": "gqa_gated"}
+# the rotary each ``model_type`` states; granitemoehybrid's attention is unrotated ("nope")
+_POSITIONS = {"granitemoehybrid": "nope", "qwen3_next": "rope_half"}
 _LAYER_TYPES = {
     "sliding_attention": "gqa_window", "full_attention": "gqa_full",
     "mamba": "mamba2", "attention": "gqa_full",  # granitemoehybrid's names; its attention is unrotated ("nope")
@@ -170,6 +200,16 @@ class TrunkConfig:
     mamba_conv_bias: bool = True
     mamba_proj_bias: bool = False
     normalization_function: str = "rmsnorm"
+    # gated delta rule layers (``qwen3_next``'s "linear_attention")
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_chunk_size: int = 0  # 0: the kernel's own (``gated_delta.CHUNK``)
+    decoder_sparse_step: int = 1
+    mlp_only_layers: tuple[int, ...] = ()
+    use_sliding_window: bool = False
     # the chip's share of the routed experts: (first, count); None holds all
     experts_held: tuple[int, int] | None = None
 
@@ -182,17 +222,20 @@ class TrunkConfig:
             "hidden_act": self.hidden_act == "silu",
             "attention_bias": not self.attention_bias,
             "position_embedding_type": self.position_embedding_type
-            == ("nope" if self.model_type == "granitemoehybrid" else "rope_gptj"),
+            == _POSITIONS.get(self.model_type, "rope_gptj"),
             "mamba_n_groups": self.mamba_n_groups == 1,
             "mamba_proj_bias": not self.mamba_proj_bias,
             "mamba_conv_bias": self.mamba_conv_bias,
             "mamba_expand": self.mamba_n_heads * self.mamba_d_head in (0, self.mamba_expand * self.hidden_size),
             "normalization_function": self.normalization_function == "rmsnorm",
-            "rotary_pct": self.rotary_pct == 1,
-            "use_qk_norm": not self.use_qk_norm,
+            "rotary_pct": self.rotary_pct == 1 or self.model_type == "qwen3_next",
+            "use_qk_norm": not self.use_qk_norm or self.model_type == "qwen3_next",
             "use_gated_activation": self.use_gated_activation,
             "shared_expert_combination_strategy": self.shared_expert_combination_strategy
-            in ("sum", "average"),
+            in ("sum", "average", "sigmoid_gate"),
+            "decoder_sparse_step": self.decoder_sparse_step == 1,
+            "mlp_only_layers": not self.mlp_only_layers,
+            "use_sliding_window": not self.use_sliding_window,
         }
         for key, ok in unsupported.items():
             if not ok:
@@ -201,14 +244,17 @@ class TrunkConfig:
     @classmethod
     def from_dict(cls, config: dict, **overrides: Any) -> "TrunkConfig":
         """From a ``config.json``'s keys; keys that say nothing about the
-        trunk's shape are passed over. ``model_type`` ``cohere2_moe`` and
-        ``granitemoehybrid`` have names of their own for some fields. A file
+        trunk's shape are passed over. ``model_type`` ``cohere2_moe``,
+        ``granitemoehybrid`` and ``qwen3_next`` have names of their own for
+        some fields; ``qwen3_next``'s table is read from
+        ``full_attention_interval``. A file
         cut to one chip's share (``experts_held``) counts the experts held
         under the published key and states the published count, the router's
         width, under ``published``."""
         renamed = {
             "cohere2_moe": (_COHERE2_MOE_KEYS, _COHERE2_MOE_FIXED, "num_experts"),
             "granitemoehybrid": (_GRANITE_HYBRID_KEYS, _GRANITE_HYBRID_FIXED, "num_local_experts"),
+            "qwen3_next": (_QWEN3_NEXT_KEYS, _QWEN3_NEXT_FIXED, "num_experts"),
         }.get(config.get("model_type"))
         if renamed is not None:
             keys, fixed, experts_key = renamed
@@ -219,6 +265,12 @@ class TrunkConfig:
                 )
             if not config.get("head_dim"):  # granitemoehybrid states none
                 config["head_dim"] = config["hidden_size"] // config["num_attention_heads"]
+            interval = config.get("full_attention_interval") if config.get("layer_types") is None else None
+            if interval:  # qwen3_next: layer i is full where (i + 1) % interval == 0
+                config["layer_types"] = [
+                    _QWEN3_NEXT_KINDS["linear_attention" if (i + 1) % interval else "full_attention"]
+                    for i in range(config["num_hidden_layers"])
+                ]
         names = {f.name for f in dataclasses.fields(cls)}
         picked = {k: v for k, v in config.items() if k in names}
         if picked.get("layer_types") is not None:
@@ -227,8 +279,9 @@ class TrunkConfig:
         if isinstance(scaling, dict):
             known = {f.name for f in dataclasses.fields(RopeScaling)}
             picked["rope_scaling"] = RopeScaling(**{k: v for k, v in scaling.items() if k in known})
-        if picked.get("experts_held") is not None:
-            picked["experts_held"] = tuple(picked["experts_held"])
+        for key in ("experts_held", "mlp_only_layers"):
+            if picked.get(key) is not None:
+                picked[key] = tuple(picked[key])
         picked.update(overrides)
         return cls(**picked)
 
@@ -248,7 +301,19 @@ class TrunkConfig:
 
     @property
     def norm_kind(self) -> str:
+        if self.model_type == "qwen3_next":
+            return "rms_offset"
         return "rms" if self.layer_norm_eps is None else "layer"
+
+    @property
+    def gain(self) -> str:
+        """A norm's gain as a parameter: its kind of initialisation follows the norm's."""
+        return "offset_gain" if self.norm_kind == "rms_offset" else "gain"
+
+    @property
+    def chunk(self) -> int:
+        """The gated delta rule's chunk."""
+        return self.linear_chunk_size or gated_delta.CHUNK
 
     @property
     def norm_eps(self) -> float:
@@ -308,7 +373,12 @@ def layer_norm(x, gain, eps: float):
     return (centred * scale * gain.astype(jnp.float32)).astype(x.dtype)
 
 
-NORM = {"rms": rms_norm, "layer": layer_norm}
+def rms_offset_norm(x, offset, eps: float):
+    """Zero-centred: ``x / rms(x) * (1 + w)``, in float32."""
+    return rms_norm(x, 1.0 + offset.astype(jnp.float32), eps)
+
+
+NORM = {"rms": rms_norm, "layer": layer_norm, "rms_offset": rms_offset_norm}
 
 
 def norm(x, gain, config: "TrunkConfig"):
@@ -441,10 +511,11 @@ def _gqa_shapes(c: TrunkConfig) -> dict:
     }
 
 
-def interleaved_rope_tables(config: TrunkConfig, length: int) -> tuple[np.ndarray, np.ndarray]:
-    """cos and sin [length, head_dim / 2] of positions 0..length-1: pair i,
-    dims (2i, 2i + 1), turns by position x theta^(-2i / head_dim)."""
-    width = config.head_dim
+def interleaved_rope_tables(config: TrunkConfig, length: int, width: int = 0) -> tuple[np.ndarray, np.ndarray]:
+    """cos and sin [length, width / 2] of positions 0..length-1 (``width``
+    the rotated dims, all ``head_dim`` of them by default): pair i turns by
+    position x theta^(-2i / width)."""
+    width = width or config.head_dim
     inv_freq = 1.0 / float(config.rope_theta) ** (np.arange(0, width, 2, dtype=np.float64) / width)
     angles = np.arange(length, dtype=np.float64)[:, None] * inv_freq[None, :]
     return np.cos(angles).astype(np.float32), np.sin(angles).astype(np.float32)
@@ -510,13 +581,16 @@ def _mamba2_shapes(c: TrunkConfig) -> dict:
     }
 
 
-def causal_conv(x, taps, bias):
+def causal_conv(x, taps, bias=None):
     """Depthwise causal convolution along ``x`` [B, T, C]: position ``t`` sees
     the last ``len(taps)`` positions, itself included (``taps[-1]`` is its
-    own), summed in float32. Before the row's start there are zeros."""
+    own), summed in float32, plus ``bias`` where one is given. Before the
+    row's start there are zeros."""
     width, length = taps.shape[0], x.shape[1]
     taps = taps.astype(jnp.float32)
-    out = bias.astype(jnp.float32) + taps[-1] * x.astype(jnp.float32)
+    out = taps[-1] * x.astype(jnp.float32)
+    if bias is not None:
+        out = bias.astype(jnp.float32) + out
     for back in range(1, width):  # each shift its own pad of its own slice: nothing float32 of x's size is kept
         shifted = jnp.pad(x[:, : length - back], ((0, 0), (back, 0), (0, 0)))
         out = out + taps[-1 - back] * shifted.astype(jnp.float32)
@@ -557,11 +631,102 @@ def _mamba2(p, h, c: TrunkConfig, ctx: dict):
         return _dot(rms_norm(gated, p["norm"], c.rms_norm_eps).astype(h.dtype), p["w_out"])
 
 
+def _gqa_gated_shapes(c: TrunkConfig) -> dict:
+    heads, kv_heads, width = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    return {
+        "wq": ((c.hidden_size, heads, 2 * width), "kernel"),  # a head's query, then its gate
+        "wk": ((c.hidden_size, kv_heads, width), "kernel"),
+        "wv": ((c.hidden_size, kv_heads, width), "kernel"),
+        "q_norm": ((width,), c.gain),
+        "k_norm": ((width,), c.gain),
+        "wo": ((heads, width, c.hidden_size), "kernel_out"),
+    }
+
+
+def _gqa_gated(p, h, c: TrunkConfig, ctx: dict):
+    """Grouped-query attention with an output gate (qwen3_next's full
+    layer): ``[q | gate] = h W_q`` a head, q and k through the config's norm
+    a head, rotary by halves on the first ``head_dim * rotary_pct`` dims
+    (dims i and i + half paired), causal over the whole row, then ``o *
+    sigmoid(gate)`` and the out-projection."""
+    kv_heads, width = c.num_key_value_heads, c.head_dim
+    projected = _heads(h, p["wq"])  # [B, H, T, 2 width]
+    q, gate = projected[..., :width], projected[..., width:]
+    q, k, v = norm(q, p["q_norm"], c), norm(_heads(h, p["wk"]), p["k_norm"], c), _heads(h, p["wv"])
+    cos, sin = ctx["rope_half"]
+    turned = 2 * cos.shape[-1]
+    q, k = (jnp.concatenate([_rotate_pairs(a[..., :turned], cos, sin), a[..., turned:]], axis=-1) for a in (q, k))
+    batch, heads, length, _ = q.shape
+    mixed = block_attention.attention(
+        q.reshape(batch, kv_heads, heads // kv_heads, length, width), k, v,
+        scale=width**-0.5, lengths=ctx.get("lengths"),
+    ).reshape(q.shape)
+    mixed = (mixed.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(h.dtype)
+    out = jnp.einsum("bhte,hed->btd", mixed, p["wo"].astype(h.dtype), preferred_element_type=jnp.float32)
+    return out.astype(h.dtype)
+
+
+def _gdn_shapes(c: TrunkConfig) -> dict:
+    heads = c.linear_num_value_heads
+    key, value = c.linear_num_key_heads * c.linear_key_head_dim, heads * c.linear_value_head_dim
+    return {
+        "w_qkvz": ((c.hidden_size, 2 * key + 2 * value), "kernel"),  # [q | k | v | z]
+        "w_ba": ((c.hidden_size, 2 * heads), "kernel"),  # [b | a]
+        "conv": ((c.linear_conv_kernel_dim, 2 * key + value), "conv"),  # q, k and v; no bias
+        "A_log": ((heads,), "a_log"),
+        "dt_bias": ((heads,), "ones"),
+        "norm": ((c.linear_value_head_dim,), "gain"),  # a plain gain, one a head's channel
+        "w_out": ((value, c.hidden_size), "kernel"),
+    }
+
+
+def _l2_normed(x, eps: float = 1e-6):
+    x32 = x.astype(jnp.float32)
+    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
+
+
+def _gdn(p, h, c: TrunkConfig, ctx: dict):
+    """A Gated DeltaNet mixer over the whole row (prefill form; the state
+    starts at zero and is not kept): ``[q | k | v | z] = h W_qkvz``, ``[b |
+    a] = h W_ba``, ``q k v <- silu(conv(q k v))`` (no bias), q and k
+    L2-normalised a head and q scaled by ``d_k^-1/2``, ``beta =
+    sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``, the gated delta
+    rule of ``ops/gated_delta.py``, then ``rms(o) * w * silu(z)`` a head and
+    the out-projection."""
+    key_heads, heads = c.linear_num_key_heads, c.linear_num_value_heads
+    dk, dv = c.linear_key_head_dim, c.linear_value_head_dim
+    key, value = key_heads * dk, heads * dv
+    batch, length, _ = h.shape
+    with scope("trunk.gdn.in_proj"):
+        projected = _dot(h, p["w_qkvz"])
+        b, a = jnp.split(_dot(h, p["w_ba"]).astype(jnp.float32), 2, axis=-1)
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(a + p["dt_bias"].astype(jnp.float32))
+
+    def convolved(first: int, channels: int, width: int):
+        """q, k and v each through their own channels of the convolution, [B, T, heads, width] float32."""
+        at = slice(first, first + channels)
+        return jax.nn.silu(causal_conv(projected[..., at], p["conv"][:, at])).reshape(batch, length, -1, width)
+
+    with scope("trunk.gdn.conv"):
+        q = (_l2_normed(convolved(0, key, dk)) * dk**-0.5).astype(h.dtype)
+        k = _l2_normed(convolved(key, key, dk)).astype(h.dtype)
+        v = convolved(2 * key, value, dv).astype(h.dtype)
+    with scope("trunk.gdn.scan"):
+        o = gated_delta.scan(q, k, v, g, beta, chunk=c.chunk)
+    with scope("trunk.gdn.gate_out"):
+        z = projected[..., 2 * key + value :].reshape(o.shape).astype(jnp.float32)
+        gated = rms_norm(o.astype(jnp.float32), p["norm"], c.rms_norm_eps) * jax.nn.silu(z)
+        return _dot(gated.astype(h.dtype).reshape(batch, length, value), p["w_out"])
+
+
 ATTENTION = {
     "mla": Block(_mla_shapes, _mla, "trunk.mla"),
     "gqa_window": Block(_gqa_shapes, functools.partial(_gqa, window=True), "trunk.gqa_window"),
     "gqa_full": Block(_gqa_shapes, functools.partial(_gqa, window=False), "trunk.gqa_full"),
     "mamba2": Block(_mamba2_shapes, _mamba2, "trunk.mamba2"),
+    "gqa_gated": Block(_gqa_gated_shapes, _gqa_gated, "trunk.gqa_gated"),
+    "gated_deltanet": Block(_gdn_shapes, _gdn, "trunk.gdn"),
 }
 
 # -- feed-forward kinds --------------------------------------------------------
@@ -602,6 +767,8 @@ def _moe_shapes(c: TrunkConfig) -> dict:
     }
     if c.topk_method != "noaux_tc":  # a plain top-k has no correction bias
         del shapes["bias"]
+    if c.shared_expert_combination_strategy == "sigmoid_gate":
+        shapes["shared_gate"] = ((d, 1), "kernel")
     return shapes
 
 
@@ -616,6 +783,8 @@ def _moe(p, h, c: TrunkConfig, ctx: dict):
     ctx["expert_choice"].append(choice.reshape(h.shape[:-1] + choice.shape[-1:]))
     with scope("trunk.moe.shared"):
         shared = _gated_ffn(p["shared"], flat).astype(jnp.float32)
+        if c.shared_expert_combination_strategy == "sigmoid_gate":
+            shared = shared * jax.nn.sigmoid(_dot(flat, p["shared_gate"]).astype(jnp.float32))
     if c.shared_expert_combination_strategy == "average":
         shared = shared / c.n_shared_experts
     return (routed + shared).astype(h.dtype).reshape(h.shape)
@@ -665,7 +834,7 @@ def _mhc(p, streams, sublayer, c: TrunkConfig):
 
 
 def _mhc_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
-    gain = ((c.hidden_size,), "gain")
+    gain = ((c.hidden_size,), c.gain)
     return {
         "attn_res": _mhc_shapes(c), "attn_norm": gain, "attn": attention,
         "ffn_res": _mhc_shapes(c), "ffn_norm": gain, "ffn": ffn,
@@ -690,7 +859,7 @@ def _mhc_exit(streams, last):
 
 
 def _add_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
-    gain = ((c.hidden_size,), "gain")
+    gain = ((c.hidden_size,), c.gain)
     return {"attn_norm": gain, "attn": attention, "ffn_norm": gain, "ffn": ffn}
 
 
@@ -706,7 +875,7 @@ def _add_layer(p, x, attend, feed, c: TrunkConfig):
 
 
 def _parallel_shapes_of_a_layer(c: TrunkConfig, attention: dict, ffn: dict) -> dict:
-    return {"norm": ((c.hidden_size,), "gain"), "attn": attention, "ffn": ffn}
+    return {"norm": ((c.hidden_size,), c.gain), "attn": attention, "ffn": ffn}
 
 
 def _parallel_layer(p, x, attend, feed, c: TrunkConfig):
@@ -765,7 +934,7 @@ def param_shapes(config: TrunkConfig) -> dict:
     return {
         "embed": ((config.vocab_size, d), "embedding"),
         "layers": layers,
-        "final_norm": ((d,), "gain"),
+        "final_norm": ((d,), config.gain),
     }
 
 
@@ -779,6 +948,8 @@ def _init_leaf(key, shape, kind, dtype, streams):
     normal = functools.partial(jax.random.normal, key, shape, jnp.float32)
     if kind == "gain":
         return 1.0 + 0.1 * normal()
+    if kind == "offset_gain":  # a zero-centred norm's w in (1 + w)
+        return 0.1 * normal()
     if kind == "embedding":
         return normal().astype(dtype)
     if kind == "kernel":
@@ -861,6 +1032,8 @@ def forward(params, ids, mask, *, config: TrunkConfig):
         ctx["rope"] = rope_tables(config, ids.shape[1])
     if "gqa_window" in kinds_of_attention:
         ctx["rope_pairs"] = interleaved_rope_tables(config, ids.shape[1])
+    if "gqa_gated" in kinds_of_attention:
+        ctx["rope_half"] = interleaved_rope_tables(config, ids.shape[1], int(config.head_dim * config.rotary_pct))
     with scope("trunk.embed"):
         x = params["embed"][ids]
         if config.embedding_multiplier != 1:
@@ -922,9 +1095,10 @@ class TrunkRuntime:
         self._windows = [
             config.sliding_window if kinds.attention == "gqa_window" else None
             for kinds in table
-            if kinds.attention in ("gqa_window", "gqa_full")
+            if kinds.attention in ("gqa_window", "gqa_full", "gqa_gated")
         ]
         self._scans = sum(kinds.attention == "mamba2" for kinds in table)
+        self._deltas = sum(kinds.attention == "gated_deltanet" for kinds in table)
         path = _block(RESIDUAL, table[0].residual, "residual").path
         self._residual = {} if path is None else {"residual": path}
 
@@ -965,16 +1139,20 @@ class TrunkRuntime:
         }
 
     def _scan_chunks(self, lengths: np.ndarray, rows: int, width: int) -> dict:
-        """The chunks of the state-space scans of one forward, all ``mamba2``
-        layers: those that hold a real token, and those walked at the
-        forwarded shape. Nothing for a table without such a layer."""
-        if not self._scans:
-            return {}
-        chunk = self.config.mamba_chunk_size
-        return {
-            "ssm_chunks_useful": self._scans * ssd_scan.chunks_useful(lengths, chunk),
-            "ssm_chunks_visited": self._scans * ssd_scan.chunks_visited(rows, width, chunk),
-        }
+        """The chunks of the recurrent scans of one forward, all ``mamba2``
+        layers and all ``gated_deltanet`` layers: those that hold a real
+        token, and those walked at the forwarded shape. Nothing for a table
+        without such a layer."""
+        counts = {}
+        if self._scans:
+            chunk = self.config.mamba_chunk_size
+            counts["ssm_chunks_useful"] = self._scans * ssd_scan.chunks_useful(lengths, chunk)
+            counts["ssm_chunks_visited"] = self._scans * ssd_scan.chunks_visited(rows, width, chunk)
+        if self._deltas:
+            chunk = self.config.chunk
+            counts["gdn_chunks_useful"] = self._deltas * gated_delta.chunks_useful(lengths, chunk)
+            counts["gdn_chunks_visited"] = self._deltas * gated_delta.chunks_visited(rows, width, chunk)
+        return counts
 
     def dispatch(
         self, ids: np.ndarray, mask: np.ndarray, routing: bool = False
@@ -985,9 +1163,10 @@ class TrunkRuntime:
         counts, ``residual="mhc_fused"`` where the streams are mixed by the two
         kernels of ``ops/residual_mix.py``, where the table has blocked
         attention layers their
-        ``attn_pairs_allowed`` and ``attn_pairs_visited``, and where it has
+        ``attn_pairs_allowed`` and ``attn_pairs_visited``, where it has
         ``mamba2`` layers their ``ssm_chunks_useful`` and
-        ``ssm_chunks_visited``. ``routing=True``
+        ``ssm_chunks_visited``, and where it has ``gated_deltanet`` layers
+        their ``gdn_chunks_useful`` and ``gdn_chunks_visited``. ``routing=True``
         adds ``expert_choice`` [expert layers, n, T, k], the experts each
         token went to (-1: nowhere); it stays on the device unless asked for."""
         n = ids.shape[0]

@@ -37,6 +37,7 @@ _GROUP_POSITIONS = _GROUP * 512
 _ADDED = (
     "tokens_padded", "expert_rows_useful", "expert_rows_computed",
     "attn_pairs_allowed", "attn_pairs_visited", "ssm_chunks_useful", "ssm_chunks_visited",
+    "gdn_chunks_useful", "gdn_chunks_visited",
 )
 
 

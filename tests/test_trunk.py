@@ -1007,12 +1007,19 @@ def test_the_two_shares_of_a_granite_layer_add_up_to_the_uncut_layer(ssm, layer,
 
 
 @pytest.mark.parametrize(
-    "file, leaves, total, signature",
-    [(CONFIG_FILE, 147, 4_323_079_812, "1a103e61132f4735"), (GQA_FILE, 50, 4_733_292_544, "fc28f37aca32afce")],
+    "file, leaves, total, signature, program",
+    [
+        (CONFIG_FILE, 147, 4_323_079_812, "1a103e61132f4735", "7b44b55b33b91983"),
+        (GQA_FILE, 50, 4_733_292_544, "fc28f37aca32afce", "7e6e329c357d58bb"),
+        (SSM_FILE, 168, 4_757_211_776, "34a1ce4295c7079c", "dbb58d93d5c42386"),
+    ],
 )
-def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, signature):
-    """xing4's and command-a's trees, leaf for leaf (path, shape, dtype), as
-    the commit before the Mamba kind built them."""
+def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, signature, program):
+    """xing4's, command-a's and granite's trees, leaf for leaf (path, shape,
+    dtype), as the commit before the Mamba kind built them (granite's: as
+    the commit before the delta-rule kind), and their forwards at the toy
+    sizes, equation for equation (the jaxpr), as the commit before the
+    delta-rule kind traced them."""
     import hashlib
 
     config = TrunkConfig.from_file(file, name="as-before")
@@ -1021,5 +1028,249 @@ def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, sig
     assert (len(flat), sum(int(np.prod(a.shape)) for _, a in flat)) == (leaves, total)
     described = repr([(str(path), a.shape, str(a.dtype)) for path, a in flat])
     assert hashlib.sha256(described.encode()).hexdigest()[:16] == signature
-    assert config.attention_multiplier is None and config.residual_multiplier == 1 and config.embedding_multiplier == 1
-    assert config.shared_intermediate_size == 0 and config.scoring_func == "sigmoid"
+    if file != SSM_FILE:
+        assert config.attention_multiplier is None and config.residual_multiplier == 1 and config.embedding_multiplier == 1
+        assert config.shared_intermediate_size == 0 and config.scoring_func == "sigmoid"
+    toy = TrunkConfig.from_dict(toy_dict(file), name="toy")
+    params = jax.eval_shape(lambda: _trunk.init_params(toy, 0, jnp.float32))
+    ids, mask = jax.ShapeDtypeStruct((2, 64), jnp.int32), jax.ShapeDtypeStruct((2, 64), jnp.float32)
+    traced = str(jax.make_jaxpr(lambda p, i, m: _trunk.forward(p, i, m, config=toy))(params, ids, mask))
+    assert hashlib.sha256(traced.encode()).hexdigest()[:16] == program
+
+
+# -- (j) Qwen3-Next-80B-A3B: gated delta rule layers, an output-gated full layer, a gated shared expert ----
+
+from benchmarks.harness import reference_gdn as gdn_ref  # noqa: E402
+from pathway_tpu.ops import gated_delta  # noqa: E402
+
+GDN_FILE = os.path.join(ROOT, "benchmarks", "configs", "qwen3-next-80b-a3b.json")
+
+
+def gdn_dict(**changes) -> dict:
+    return toy_dict(GDN_FILE, **changes)
+
+
+def gdn_params(config, seed, dtype=jnp.float32):
+    """The benchmark's weights (decays that remember across chunks, q/k norms near 2)."""
+    from benchmarks.harness import weights_gdn
+
+    return weights_gdn.make_params(jax.eval_shape(lambda: _trunk.init_params(config, 0, dtype)), seed)
+
+
+@pytest.fixture(scope="module")
+def gdn():
+    body = gdn_dict()
+    return body, TrunkConfig.from_dict(body, name="toy-gdn")
+
+
+@pytest.fixture(scope="module")
+def gdn_runtime(gdn):
+    _body, config = gdn
+    runtime = TrunkRuntime(config, max_len=128, seed=7, dtype=jnp.float32)
+    runtime.params = gdn_params(config, 7)
+    return runtime
+
+
+def gdn_reference_rows(params, ids, mask, body, **kw):
+    return np.stack(
+        [np.asarray(gdn_ref.encode(params, row, int(m.sum()), body, **kw)[0]) for row, m in zip(ids, mask)]
+    )
+
+
+def test_the_qwen3_next_config_file_reads_as_the_issue_says():
+    config = TrunkConfig.from_file(GDN_FILE, name="qwen3-next-80b-a3b")
+    table = config.layer_table()
+    assert [k.attention for k in table] == ["gated_deltanet"] * 3 + ["gqa_gated"]
+    assert {k.ffn for k in table} == {"moe"} and {k.residual for k in table} == {"add"} and config.norm_kind == "rms_offset"
+    assert (config.hidden_size, config.num_attention_heads, config.num_key_value_heads, config.head_dim) == (2048, 16, 2, 256)
+    assert (config.linear_num_key_heads, config.linear_num_value_heads, config.linear_key_head_dim) == (16, 32, 128)
+    assert (config.linear_value_head_dim, config.linear_conv_kernel_dim, config.chunk) == (128, 4, gated_delta.CHUNK) == (128, 4, 64)
+    assert (config.n_routed_experts, config.held, config.num_experts_per_tok, config.n_shared_experts) == (512, (0, 256), 10, 1)
+    assert (config.moe_intermediate_size, config.shared_intermediate_size, config.vocab_size) == (512, 512, 75968)
+    assert config.scoring_func == "softmax" and config.shared_expert_combination_strategy == "sigmoid_gate"
+    assert (config.rotary_pct, config.rope_theta, config.norm_eps, config.use_qk_norm) == (0.25, 10_000_000, 1e-6, True)
+    shapes = _trunk.param_shapes(config)
+    ffn, attn = shapes["layers"][0]["ffn"], shapes["layers"][0]["attn"]
+    assert ffn["router"][0] == (2048, 512) and ffn["shared_gate"][0] == (2048, 1) and "bias" not in ffn
+    assert attn["w_qkvz"][0] == (2048, 12288) and attn["w_ba"][0] == (2048, 64) and attn["conv"][0] == (4, 8192)
+    assert shapes["layers"][3]["attn"]["wq"][0] == (2048, 16, 512) and shapes["final_norm"][1] == "offset_gain"
+    template = jax.eval_shape(lambda: _trunk.init_params(config, 0, jnp.bfloat16))
+    count = lambda tree: sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(tree))  # noqa: E731
+    assert count(template) == 3_522_030_656
+    assert [count(layer) for layer in template["layers"]] == [843_225_280] * 3 + [836_770_304]
+    assert count(template["layers"][0]["attn"]) == 33_718_464 and count(template["layers"][3]["attn"]) == 27_263_488
+
+
+@pytest.mark.parametrize(
+    "key, value", [("use_sliding_window", True), ("mlp_only_layers", [1]), ("decoder_sparse_step", 2)]
+)
+def test_qwen3_next_keys_without_a_block_are_refused_by_name(key, value):
+    with pytest.raises(ValueError, match=key):
+        TrunkConfig.from_dict(gdn_dict(**{key: value}), name="toy-gdn")
+
+
+@pytest.mark.parametrize("rows, seed", [(3, 0), (5, 1), (8, 2)])
+def test_gdn_forward_float32_matches_the_recurrence_over_several_chunks(gdn, gdn_runtime, rows, seed):
+    body, config = gdn
+    ids, mask = batch(rows, 128, seed)  # row 0 fills 128 positions: 8 chunks of 16
+    assert mask.sum(axis=1).max() == 8 * config.chunk
+    got, info = gdn_runtime.forward(ids, mask)
+    want = gdn_reference_rows(gdn_runtime.params, ids, mask, body)
+    assert np.linalg.norm(got - want, axis=1).max() < F32_TOL
+    assert info["gdn_chunks_useful"] == 3 * sum(-(-int(t) // 16) for t in mask.sum(axis=1))
+    assert info["gdn_chunks_visited"] == 3 * info["batch_bucket"] * 8 and "ssm_chunks_useful" not in info
+    assert info["attn_pairs_allowed"] == sum(block_attention.pairs_allowed(int(t), None) for t in mask.sum(axis=1))
+    # a reference that loses the state between chunks is somebody else's vectors
+    lost = gdn_reference_rows(gdn_runtime.params, ids[:2], mask[:2], body, mode="no_carry")
+    assert np.linalg.norm(lost - want[:2], axis=1).min() > 50 * F32_TOL
+
+
+def test_gdn_forward_bfloat16_follows_its_own_experts(gdn):
+    body, config = gdn
+    runtime = TrunkRuntime(config, max_len=128)
+    runtime.params = gdn_params(config, 8, jnp.bfloat16)
+    ids, mask = batch(4, 64, 3)
+    got, info = runtime.forward(ids, mask, routing=True)
+    choice = info["expert_choice"]
+    assert choice.shape == (4, 4, 64, 3) and ((choice >= 0).all(axis=-1) == (mask > 0)[None]).all()
+    want = np.stack(
+        [
+            np.asarray(gdn_ref.encode(runtime.params, ids[i], int(mask[i].sum()), body, forced=choice[:, i])[0])
+            for i in range(4)
+        ]
+    )
+    assert np.linalg.norm(got - want, axis=1).max() < BF16_TOL
+
+
+@pytest.fixture(scope="module")
+def gdn_blocks(gdn):
+    body, config = gdn
+    params = gdn_params(config, 13)
+    h = jax.random.normal(jax.random.PRNGKey(9), (2, 72, config.hidden_size), jnp.float32)
+    return body, config, params, h
+
+
+def test_gated_deltanet_block(gdn_blocks):
+    body, config, params, h = gdn_blocks
+    p = params["layers"][0]["attn"]
+    got = _trunk.ATTENTION["gated_deltanet"].apply(p, h, config, {})
+    for i in range(2):  # 72 positions: four whole chunks of 16 and a part of one
+        with jax.default_matmul_precision("highest"):
+            want = gdn_ref.gated_deltanet(p, h[i], body)
+        assert np.abs(np.asarray(got[i]) - np.asarray(want)).max() < 2e-4
+    # the convolution without a bias is the convolution with a zero bias
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 9, 3))
+    taps = jax.random.normal(jax.random.PRNGKey(2), (4, 3))
+    assert np.allclose(_trunk.causal_conv(x, taps), _trunk.causal_conv(x, taps, jnp.zeros(3)))
+
+
+def test_gated_attention_block(gdn_blocks):
+    body, config, params, h = gdn_blocks
+    p = params["layers"][3]["attn"]
+    ctx = {"rope_half": _trunk.interleaved_rope_tables(config, 72, 8)}
+    got = _trunk.ATTENTION["gqa_gated"].apply(p, h, config, ctx)
+    for i in range(2):
+        with jax.default_matmul_precision("highest"):
+            want = gdn_ref.attention(p, h[i], body)
+        assert np.abs(np.asarray(got[i]) - np.asarray(want)).max() < 2e-4
+
+
+def _unrotated(body):
+    return dict(body, partial_rotary_factor=0.5)
+
+
+def _ungated_attention(params):
+    layers = list(params["layers"])
+    wq = layers[3]["attn"]["wq"]
+    layers[3] = dict(layers[3], attn=dict(layers[3]["attn"], wq=wq.at[..., wq.shape[-1] // 2 :].set(0.0)))
+    return dict(params, layers=layers)
+
+
+def _ungated_shared(params):
+    return dict(
+        params,
+        layers=[dict(layer, ffn=dict(layer["ffn"], shared_gate=layer["ffn"]["shared_gate"] * 0.0)) for layer in params["layers"]],
+    )
+
+
+@pytest.mark.parametrize("change", ["partial_rotary", "output_gate", "offset_norm", "shared_gate"])
+def test_each_new_piece_changes_the_result(gdn, gdn_runtime, change, monkeypatch):
+    """The rotary's share of the dims, the output gate, the 1 of ``1 + w`` and
+    the shared expert's gate each move the vectors when changed, and (where
+    the change is one of the model's) the reference moves with them."""
+    body, config = gdn
+    ids, mask = batch(2, 64, 4)
+    before = gdn_runtime.forward_ids(ids, mask)
+    params, changed_body = gdn_runtime.params, body
+    if change == "partial_rotary":
+        changed_body = _unrotated(body)
+    elif change == "output_gate":
+        params = _ungated_attention(params)  # sigmoid(0): every gate at one half
+    elif change == "shared_gate":
+        params = _ungated_shared(params)
+    runtime = TrunkRuntime(TrunkConfig.from_dict(changed_body, name="toy-gdn"), max_len=128, dtype=jnp.float32)
+    runtime.params = params
+    if change == "offset_norm":  # a zero-centred norm that forgets its 1: the gain is w alone
+        monkeypatch.setitem(_trunk.NORM, "rms_offset", _trunk.rms_norm)
+    after = runtime.forward_ids(ids, mask)
+    assert np.linalg.norm(after - before, axis=1).min() > 1e-3
+    if change != "offset_norm":
+        want = gdn_reference_rows(params, ids, mask, changed_body)
+        assert np.linalg.norm(after - want, axis=1).max() < F32_TOL
+
+
+def test_gdn_padding_and_companions_change_no_vector(gdn_runtime):
+    ids, mask = batch(rows=3, width=64, seed=5)
+    together = gdn_runtime.forward_ids(ids, mask)
+    for i in range(3):
+        alone = gdn_runtime.forward_ids(ids[i : i + 1], mask[i : i + 1])
+        assert np.abs(alone[0] - together[i]).max() < 1e-5
+    wide, info = gdn_runtime.forward(np.pad(ids, ((0, 0), (0, 64))), np.pad(mask, ((0, 0), (0, 64))))
+    assert info["len_bucket"] == 128 and np.abs(wide - together).max() < 1e-5
+
+
+@pytest.mark.parametrize("layer, kind", [(0, "linear"), (3, "full")])
+def test_the_two_shares_of_a_qwen3_next_layer_add_up_to_the_uncut_layer(layer, kind):
+    """Sixteen experts over two shares of 8 (the deployment's: experts 0-255
+    and 256-511 of 512): what the shares' routed parts give, with the mixer,
+    the router, the shared expert, its gate and x counted once, is the uncut
+    layer as the reference computes it."""
+    whole_body = gdn_dict(num_experts=16, experts_held=None, published={})
+    whole = TrunkConfig.from_dict(whole_body, name="uncut")
+    assert whole.held == (0, 16) and whole.n_routed_experts == 16
+    params = gdn_params(whole, 17)
+    p = params["layers"][layer]
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 40, whole.hidden_size), jnp.float32)
+    block = _trunk.ATTENTION[whole.layer_table()[layer].attention]
+    ctx = {"rope_half": _trunk.interleaved_rope_tables(whole, 40, 8)}
+    mixed = np.asarray(block.apply(p["attn"], _trunk.norm(x, p["attn_norm"], whole), whole, ctx))[0]
+    after = x + mixed[None]
+    flat = _trunk.norm(after, p["ffn_norm"], whole).reshape(-1, whole.hidden_size)
+    valid = jnp.ones(flat.shape[0], bool)
+    parts = []
+    for first in (0, 8):
+        routed, _counts, _choice = moe.expert_layer(
+            flat, valid, p["ffn"]["router"], None, p["ffn"]["w_gate"][first : first + 8],
+            p["ffn"]["w_up"][first : first + 8], p["ffn"]["w_down"][first : first + 8],
+            top_k=3, scale=1.0, experts_held=(first, 8), scoring="softmax",
+        )
+        parts.append(np.asarray(routed))
+    assert all(np.abs(part).max() > 0 for part in parts)
+    gate = jax.nn.sigmoid(flat @ p["ffn"]["shared_gate"])
+    shared = np.asarray(gate * _trunk._gated_ffn(p["ffn"]["shared"], flat))
+    with jax.default_matmul_precision("highest"):
+        want, _logits = gdn_ref.layer(p, x[0], None, whole_body, kind)
+    assert np.abs(np.asarray(after[0]) + shared + sum(parts) - np.asarray(want)).max() < 2e-4
+    # and a cut layer is the program's own layer on its share
+    cut = TrunkConfig.from_dict(gdn_dict(experts_held=[8, 8]), name="cut")
+    held = dict(p, ffn={k: (v[8:16] if k in ("w_gate", "w_up", "w_down") else v) for k, v in p["ffn"].items()})
+    ctx.update(valid=valid, expert_counts=[], expert_choice=[])
+
+    def attend(p_attn, u):
+        return block.apply(p_attn, u, cut, ctx)
+
+    def feed(p_ffn, u):
+        return _trunk.FFN["moe"].apply(p_ffn, u, cut, ctx)
+
+    got = np.asarray(_trunk.RESIDUAL["add"].layer(held, x, attend, feed, cut))[0]
+    assert np.abs(got - (np.asarray(after[0]) + shared + parts[1])).max() < 2e-4

@@ -171,6 +171,47 @@ def test_the_paper_forward_fits_a_v5e_and_keeps_no_decay_mask(one_chip, no_cache
     assert max(floats) <= rows * width * 16768 < mask and f"{width // 256},128,256,256]" not in text
 
 
+@pytest.mark.parametrize("rows, width", [(1, 16384), (2, 8192)], ids=["1x16384", "2x8192"])
+def test_the_delta_rule_forward_fits_a_v5e_and_keeps_no_chunk_products(one_chip, no_cache, monkeypatch, rows, width):
+    """Qwen3-Next-80B-A3B's cut (4 layers, 256 of 512 experts, published
+    widths) at the 16,384 positions of a whole group: parameters and
+    temporaries under the chip's 16.9 GB with room for the index, every
+    Gated DeltaNet layer's scan as the chunked kernel (no [B, T / 64, 32, 64,
+    64] array anywhere), the gated full layer as the blocked kernel at head
+    width 256."""
+    import functools
+
+    from pathway_tpu.ops import gated_delta
+    from pathway_tpu.xpacks.llm import _trunk
+
+    for module in (moe, block_attention, gated_delta):
+        monkeypatch.setattr(module, "pallas_interpret", lambda: False)
+    config = _trunk.TrunkConfig.from_file(
+        os.path.join(ROOT, "benchmarks", "configs", "qwen3-next-80b-a3b.json"), name="qwen3-next-80b-a3b"
+    )
+    template = jax.eval_shape(lambda: _trunk.init_params(config, 0, jnp.bfloat16))
+    params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), template)
+    compiled = jax.jit(functools.partial(_trunk.forward, config=config)).lower(
+        params,
+        jax.ShapeDtypeStruct((rows, width), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert 7.04e9 < memory.argument_size_in_bytes < 7.06e9  # 3,522,030,656 parameters, the router float32
+    # 1.6-1.8 GB of temporaries (the experts' 196,352 rows of 2048 in and out, 0.8 GB each, half of them never
+    # used: the other chip's pairs); 52% of the chip's 16.9 GB with the parameters
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 9.5e9
+    text = compiled.as_text()
+    # three scans, one attention kernel and twelve grouped matmuls, each of them Mosaic's
+    assert text.count("tpu_custom_call") >= 16
+    assert all(name in text for name in (gated_delta.GDN_KERNEL_NAME, block_attention.ATTN_KERNEL_NAME, moe.GMM_KERNEL_NAME))
+    chunks = rows * width // gated_delta.CHUNK
+    assert f"{chunks // rows},32,64,64]" not in text and f"{rows},{chunks // rows},32,64,64]" not in text
+    # the largest float32 shape written is the in-projection's accumulator [B, T, 12,288]
+    floats = [math.prod(map(int, dims.split(","))) for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+    assert max(floats) == rows * width * 12288
+
+
 @pytest.mark.parametrize("batch", [32, 8], ids=["batch_32x512", "probe_8x512"])
 def test_the_residual_mix_kernels_compile_for_a_v5e(one_chip, no_cache, monkeypatch, batch):
     """Xing4.0-29B-A4B's four streams of 3584 at the cell's two shapes: both
