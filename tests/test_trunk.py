@@ -235,35 +235,135 @@ def test_expert_shares_add_up_to_the_whole_layer(blocks, layer):
 # -- (e) the dispatch ------------------------------------------------------------
 
 
-@pytest.mark.parametrize("tokens, top_k", [(48, 2), (300, 2), (200, 3)])
-@pytest.mark.parametrize("routing", ["one_expert", "random"])
-def test_dispatch_loses_and_duplicates_no_row(tokens, top_k, routing):
-    experts, align = 8, moe.TILE_ROWS
+# tokens, top_k, experts, experts held: toy token counts at the trunk cells' expert shapes
+DISPATCH_SHAPES = [
+    pytest.param(48, 2, 8, None, id="48-2"),
+    pytest.param(300, 2, 8, None, id="300-2"),
+    pytest.param(200, 3, 8, None, id="200-3"),
+    pytest.param(64, 10, 512, (0, 256), id="512-first-half-top10"),
+    pytest.param(64, 10, 512, (256, 256), id="512-second-half-top10"),
+    pytest.param(96, 10, 72, (36, 36), id="72-second-half-top10"),
+    pytest.param(96, 8, 128, (0, 16), id="128-first-16-top8"),
+]
+
+
+def dispatch_inputs(tokens, top_k, experts, held, routing):
+    """A routing and its validity, the last 8 positions padding."""
+    first = held[0] if held else 0
     rng = np.random.default_rng(9)
-    if routing == "one_expert":  # every token's first choice is expert 5
-        second = rng.integers(0, 5, tokens)
-        choice = np.stack([np.full(tokens, 5)] + [(second + j) % 5 for j in range(top_k - 1)], axis=1)
+    if routing == "one_expert":  # every token's first choice is the same held expert
+        hot = max(5, top_k)
+        second = rng.integers(0, hot, tokens)
+        choice = first + np.stack([np.full(tokens, hot)] + [(second + j) % hot for j in range(top_k - 1)], axis=1)
     else:
         choice = np.stack([rng.permutation(experts)[:top_k] for _ in range(tokens)])
     valid = np.ones(tokens, bool)
     valid[tokens - 8 :] = False  # padding positions are routed nowhere
-    plan = moe.dispatch(jnp.asarray(choice, jnp.int32), jnp.asarray(valid), experts)
+    return choice, valid
+
+
+@pytest.mark.parametrize("tokens, top_k, experts, held", DISPATCH_SHAPES)
+@pytest.mark.parametrize("routing", ["one_expert", "random"])
+def test_dispatch_loses_and_duplicates_no_row(tokens, top_k, experts, held, routing):
+    align = moe.TILE_ROWS
+    first, count = held or (0, experts)
+    choice, valid = dispatch_inputs(tokens, top_k, experts, held, routing)
+    plan = moe.dispatch(jnp.asarray(choice, jnp.int32), jnp.asarray(valid), experts, held)
     src, dest = np.asarray(plan.src), np.asarray(plan.dest)
     sizes, counts = np.asarray(plan.group_sizes), np.asarray(plan.counts)
-    rows = moe.plan_rows(tokens * top_k, experts)
-    assert len(src) == rows and (sizes % align == 0).all()
+    rows = moe.plan_rows(tokens * top_k, count)
+    assert len(src) == rows and len(sizes) == count and (sizes % align == 0).all()
     assert (counts == np.bincount(choice[valid].reshape(-1), minlength=experts)).all()
-    assert (sizes >= counts).all() and (sizes - counts < align).all()
-    # every valid pair has one row of its own, in its expert's group, fed by its token
+    mine = counts[first : first + count]  # groups are indexed by the held expert's local id
+    assert (sizes >= mine).all() and (sizes - mine < align).all()
+    # every valid pair to a held expert has one row of its own, in its expert's group, fed by its token
     starts = np.cumsum(sizes) - sizes
-    pair_rows = dest[valid].reshape(-1)
-    assert len(set(pair_rows)) == valid.sum() * top_k and pair_rows.max() < rows
-    for t in np.flatnonzero(valid):
-        for j in range(top_k):
-            row, expert = dest[t, j], choice[t, j]
-            assert src[row] == t and starts[expert] <= row < starts[expert] + counts[expert]
-    assert (dest[~valid] == rows).all()
-    assert (src < tokens).sum() == valid.sum() * top_k  # every other row is padding
+    here = valid[:, None] & (choice >= first) & (choice < first + count)
+    pair_rows = dest[here]
+    assert len(set(pair_rows)) == here.sum() > 0 and pair_rows.max() < rows
+    for t, j in zip(*np.nonzero(here)):
+        row, local = dest[t, j], choice[t, j] - first
+        assert src[row] == t and starts[local] <= row < starts[local] + mine[local]
+    assert (dest[~here] == rows).all()
+    assert (src < tokens).sum() == here.sum()  # every other row is padding
+
+
+def dispatch_by_comparison(choice, valid, n_experts, experts_held=None):
+    """``moe.dispatch`` as it was written first: the experts counted by a
+    [pairs, n_experts] comparison, each row's group found by a [rows, held]
+    one, the sort inverted by a second sort. The reference for the bits."""
+    tokens, top_k = choice.shape
+    first, held = experts_held or (0, n_experts)
+    pairs = tokens * top_k
+    rows = moe.plan_rows(pairs, held)
+    flat = jnp.where(jnp.repeat(valid, top_k), choice.reshape(-1), -1)
+    counts_all = (flat[:, None] == jnp.arange(n_experts)[None, :]).sum(0, dtype=jnp.int32)
+    local = flat - first
+    key = jnp.where((flat >= 0) & (local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = counts_all[first : first + held]
+    sizes = -(-counts // moe.TILE_ROWS) * moe.TILE_ROWS
+    starts = jnp.cumsum(sizes) - sizes
+    offsets = jnp.cumsum(counts) - counts
+    row = jnp.arange(rows, dtype=jnp.int32)
+    group = (row[:, None] >= (starts + sizes)[None, :]).sum(1, dtype=jnp.int32)
+    inside = jnp.minimum(group, held - 1)
+    rank = row - starts[inside]
+    live = (group < held) & (rank < counts[inside])
+    at = jnp.clip(offsets[inside] + rank, 0, pairs - 1)
+    src = jnp.where(live, order[at] // top_k, tokens)
+    where = jnp.argsort(order).astype(jnp.int32)
+    sorted_key = key[order]
+    sorted_inside = jnp.minimum(sorted_key, held - 1)
+    sorted_dest = jnp.where(
+        sorted_key < held,
+        starts[sorted_inside] + jnp.arange(pairs, dtype=jnp.int32) - offsets[sorted_inside],
+        rows,
+    )
+    return moe.Plan(src, sorted_dest[where].reshape(tokens, top_k), sizes, counts_all)
+
+
+@pytest.mark.parametrize("tokens, top_k, experts, held", DISPATCH_SHAPES)
+@pytest.mark.parametrize("routing", ["one_expert", "random"])
+def test_dispatch_gives_the_comparisons_plan_bit_for_bit(tokens, top_k, experts, held, routing):
+    choice, valid = dispatch_inputs(tokens, top_k, experts, held, routing)
+    args = (jnp.asarray(choice, jnp.int32), jnp.asarray(valid), experts, held)
+    for name, got, want in zip(moe.Plan._fields, moe.dispatch(*args), dispatch_by_comparison(*args)):
+        assert got.dtype == want.dtype and got.shape == want.shape, name
+        assert np.array_equal(np.asarray(got), np.asarray(want)), name
+
+
+def equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (loop bodies, branches)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                if isinstance(inner, ClosedJaxpr):
+                    yield from equations(inner.jaxpr)
+                elif isinstance(inner, Jaxpr):
+                    yield from equations(inner)
+
+
+@pytest.mark.parametrize(
+    "tokens, top_k, experts, held",
+    [
+        pytest.param(16384, 10, 512, (0, 256), id="qwen3-next"),
+        pytest.param(16384, 10, 72, (0, 36), id="granite"),
+        pytest.param(16384, 8, 128, (0, 16), id="command-a"),
+        pytest.param(16384, 4, 64, None, id="xing4"),
+    ],
+)
+def test_no_dispatch_intermediate_grows_with_pairs_times_experts(tokens, top_k, experts, held):
+    """At the trunk cells' forward of 16,384 positions: traced, not compiled."""
+    traced = jax.make_jaxpr(lambda c, v: moe.dispatch(c, v, experts, held))(
+        jax.ShapeDtypeStruct((tokens, top_k), jnp.int32), jax.ShapeDtypeStruct((tokens,), jnp.bool_)
+    )
+    largest = max(int(np.prod(var.aval.shape)) for eqn in equations(traced.jaxpr) for var in eqn.outvars)
+    rows = moe.plan_rows(tokens * top_k, (held or (0, experts))[1])
+    assert rows <= largest <= 4 * rows
 
 
 # -- (f) the embedder ------------------------------------------------------------
@@ -1009,9 +1109,9 @@ def test_the_two_shares_of_a_granite_layer_add_up_to_the_uncut_layer(ssm, layer,
 @pytest.mark.parametrize(
     "file, leaves, total, signature, program",
     [
-        (CONFIG_FILE, 147, 4_323_079_812, "1a103e61132f4735", "7b44b55b33b91983"),
-        (GQA_FILE, 50, 4_733_292_544, "fc28f37aca32afce", "7e6e329c357d58bb"),
-        (SSM_FILE, 168, 4_757_211_776, "34a1ce4295c7079c", "dbb58d93d5c42386"),
+        (CONFIG_FILE, 147, 4_323_079_812, "1a103e61132f4735", "bc8af1fcd481872d"),
+        (GQA_FILE, 50, 4_733_292_544, "fc28f37aca32afce", "4980178b532d60b4"),
+        (SSM_FILE, 168, 4_757_211_776, "34a1ce4295c7079c", "e3c685766aee49ff"),
     ],
 )
 def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, signature, program):
@@ -1019,7 +1119,8 @@ def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, sig
     dtype), as the commit before the Mamba kind built them (granite's: as
     the commit before the delta-rule kind), and their forwards at the toy
     sizes, equation for equation (the jaxpr), as the commit before the
-    delta-rule kind traced them."""
+    delta-rule kind traced them with the dispatch of one sort and no
+    comparison by group (the same plan: section (e))."""
     import hashlib
 
     config = TrunkConfig.from_file(file, name="as-before")
