@@ -70,6 +70,11 @@ VOCABULARY = (
     "trunk.gdn.scan",
     "trunk.gdn.gate_out",
     "trunk.gqa_gated",
+    "trunk.swa_sink",
+    "trunk.gqa_partial",
+    "trunk.attn.qkv",
+    "trunk.attn.kernel",
+    "trunk.attn.out",
     "trunk.ffn",
     "trunk.moe",
     "trunk.moe.route",

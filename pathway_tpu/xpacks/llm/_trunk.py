@@ -26,14 +26,28 @@ by kind, so another architecture adds kinds, not branches. Kinds so far:
   through ``ops/block_attention.py`` with q and k through the config's norm
   a head, rotary by halves on the first ``head_dim * rotary_pct`` dims and
   the output times ``sigmoid(gate)``, the gate a second half of each query
-  head's projection; a model's ``layer_types`` (or ``qwen3_next``'s
-  ``full_attention_interval``) names them layer by layer;
+  head's projection; ``swa_sink`` and ``gqa_partial`` (``mimo_v2``'s window
+  and full layers): grouped-query attention through
+  ``ops/block_attention.py`` with query and key heads of ``head_dim`` and
+  value heads of their own width (``swa_v_head_dim``, ``v_head_dim``),
+  rotary by halves on the first ``head_dim * rotary_pct`` dims, each kind
+  with its own key-value heads and theta (``swa_num_key_value_heads`` and
+  ``swa_rope_theta`` against ``num_key_value_heads`` and ``rope_theta``), the
+  output times ``attention_value_scale``; the first sees the last
+  ``sliding_window`` tokens and gives each query head a learned **sink** (a
+  logit with no value, its ``sinks`` leaf), the second is causal over the
+  whole row with none; a model's ``layer_types`` (or ``qwen3_next``'s
+  ``full_attention_interval``, or ``mimo_v2``'s ``hybrid_layer_pattern``)
+  names them layer by layer;
 * feed-forward ``dense`` (gated silu) and ``moe`` (``ops/moe.py``: a sigmoid
   router, its top-k bias-corrected and scaled where the config says so, or
   the top-k of the logits softmaxed over the chosen k; dropless grouped
   experts; shared experts summed, averaged, or scaled by ``sigmoid(h
   w_sg)`` (``sigmoid_gate``), of the routed width each or of
-  ``shared_intermediate_size`` together);
+  ``shared_intermediate_size`` together, or none where ``n_shared_experts``
+  is 0; a layer has experts from ``first_k_dense_replace`` on where
+  ``moe_layer_freq`` divides its index, or where the list ``moe_layer_freq``
+  says 1);
 * residual ``mhc``: manifold-constrained hyper-connections (arXiv:2512.24880),
   ``hc_mult`` residual streams mixed by a doubly stochastic matrix per token.
   A sub-layer's step is the two Pallas kernels of ``ops/residual_mix.py`` and
@@ -49,9 +63,10 @@ by kind, so another architecture adds kinds, not branches. Kinds so far:
   ``qwen3_next`` table, its q/k norms too).
 
 ``TrunkConfig.from_dict`` reads the key names of the ``model_type`` it is
-given (``cohere2_moe``'s, ``granitemoehybrid``'s and ``qwen3_next``'s beside
-the default ones) onto the same fields. The embedded tokens are multiplied by
-``embedding_multiplier`` where the config states one.
+given (``cohere2_moe``'s, ``granitemoehybrid``'s, ``qwen3_next``'s and
+``mimo_v2``'s beside the default ones) onto the same fields. The embedded
+tokens are multiplied by ``embedding_multiplier`` where the config states
+one.
 
 ``TrunkRuntime`` has ``EncoderRuntime``'s surface and is what
 ``SentenceTransformerEmbedder(trunk=...)`` runs: the whole forward over a
@@ -114,8 +129,16 @@ _QWEN3_NEXT_FIXED = {
     "shared_expert_combination_strategy": "sigmoid_gate", "position_embedding_type": "rope_half",
 }
 _QWEN3_NEXT_KINDS = {"linear_attention": "gated_deltanet", "full_attention": "gqa_gated"}
+# ``mimo_v2``'s: its table follows from ``hybrid_layer_pattern`` (1 a window
+# layer with sinks, 0 a full layer), its experts from the list
+# ``moe_layer_freq``; its RMS norm's eps is ``layernorm_epsilon``; no shared
+# expert and no scaling where the file says null; rotary by halves; one stream
+_MIMO_V2_KEYS = {"layernorm_epsilon": "rms_norm_eps", "partial_rotary_factor": "rotary_pct"}
+_MIMO_V2_FIXED = {"first_k_dense_replace": 0, "hc_mult": 1, "position_embedding_type": "rope_half"}
+_MIMO_V2_NULLS = {"n_shared_experts": 0, "routed_scaling_factor": 1.0}
+_MIMO_V2_KINDS = {1: "swa_sink", 0: "gqa_partial"}
 # the rotary each ``model_type`` states; granitemoehybrid's attention is unrotated ("nope")
-_POSITIONS = {"granitemoehybrid": "nope", "qwen3_next": "rope_half"}
+_POSITIONS = {"granitemoehybrid": "nope", "qwen3_next": "rope_half", "mimo_v2": "rope_half"}
 _LAYER_TYPES = {
     "sliding_attention": "gqa_window", "full_attention": "gqa_full",
     "mamba": "mamba2", "attention": "gqa_full",  # granitemoehybrid's names; its attention is unrotated ("nope")
@@ -155,7 +178,7 @@ class TrunkConfig:
     n_shared_experts: int = 1
     num_experts_per_tok: int = 4
     first_k_dense_replace: int = 2
-    moe_layer_freq: int = 1
+    moe_layer_freq: int | tuple[int, ...] = 1  # a list: 1 where a layer has experts
     routed_scaling_factor: float = 2.0
     norm_topk_prob: bool = True
     scoring_func: str = "sigmoid"
@@ -210,6 +233,17 @@ class TrunkConfig:
     decoder_sparse_step: int = 1
     mlp_only_layers: tuple[int, ...] = ()
     use_sliding_window: bool = False
+    # window layers with sinks and full layers of split widths (``mimo_v2``)
+    swa_num_attention_heads: int = 0  # 0: num_attention_heads
+    swa_num_key_value_heads: int = 0
+    swa_head_dim: int = 0  # 0: head_dim
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    attention_value_scale: float = 1.0
+    add_swa_attention_sink_bias: bool = False
+    add_full_attention_sink_bias: bool = False
+    attention_projection_layout: str = "fused_qkv"
+    hybrid_block_size: int | None = None
     # the chip's share of the routed experts: (first, count); None holds all
     experts_held: tuple[int, int] | None = None
 
@@ -228,7 +262,7 @@ class TrunkConfig:
             "mamba_conv_bias": self.mamba_conv_bias,
             "mamba_expand": self.mamba_n_heads * self.mamba_d_head in (0, self.mamba_expand * self.hidden_size),
             "normalization_function": self.normalization_function == "rmsnorm",
-            "rotary_pct": self.rotary_pct == 1 or self.model_type == "qwen3_next",
+            "rotary_pct": self.rotary_pct == 1 or self.model_type in ("qwen3_next", "mimo_v2"),
             "use_qk_norm": not self.use_qk_norm or self.model_type == "qwen3_next",
             "use_gated_activation": self.use_gated_activation,
             "shared_expert_combination_strategy": self.shared_expert_combination_strategy
@@ -236,6 +270,12 @@ class TrunkConfig:
             "decoder_sparse_step": self.decoder_sparse_step == 1,
             "mlp_only_layers": not self.mlp_only_layers,
             "use_sliding_window": not self.use_sliding_window,
+            "add_full_attention_sink_bias": not self.add_full_attention_sink_bias,
+            "add_swa_attention_sink_bias": self.add_swa_attention_sink_bias or self.model_type != "mimo_v2",
+            "hybrid_block_size": self.hybrid_block_size is None,
+            "attention_projection_layout": self.attention_projection_layout == "fused_qkv",
+            "swa_num_attention_heads": self.swa_num_attention_heads in (0, self.num_attention_heads),
+            "swa_head_dim": self.swa_head_dim in (0, self.head_dim),
         }
         for key, ok in unsupported.items():
             if not ok:
@@ -245,9 +285,10 @@ class TrunkConfig:
     def from_dict(cls, config: dict, **overrides: Any) -> "TrunkConfig":
         """From a ``config.json``'s keys; keys that say nothing about the
         trunk's shape are passed over. ``model_type`` ``cohere2_moe``,
-        ``granitemoehybrid`` and ``qwen3_next`` have names of their own for
-        some fields; ``qwen3_next``'s table is read from
-        ``full_attention_interval``. A file
+        ``granitemoehybrid``, ``qwen3_next`` and ``mimo_v2`` have names of
+        their own for some fields; ``qwen3_next``'s table is read from
+        ``full_attention_interval``, ``mimo_v2``'s from the first
+        ``num_hidden_layers`` of ``hybrid_layer_pattern``. A file
         cut to one chip's share (``experts_held``) counts the experts held
         under the published key and states the published count, the router's
         width, under ``published``."""
@@ -255,6 +296,7 @@ class TrunkConfig:
             "cohere2_moe": (_COHERE2_MOE_KEYS, _COHERE2_MOE_FIXED, "num_experts"),
             "granitemoehybrid": (_GRANITE_HYBRID_KEYS, _GRANITE_HYBRID_FIXED, "num_local_experts"),
             "qwen3_next": (_QWEN3_NEXT_KEYS, _QWEN3_NEXT_FIXED, "num_experts"),
+            "mimo_v2": (_MIMO_V2_KEYS, _MIMO_V2_FIXED, "n_routed_experts"),
         }.get(config.get("model_type"))
         if renamed is not None:
             keys, fixed, experts_key = renamed
@@ -271,6 +313,14 @@ class TrunkConfig:
                     _QWEN3_NEXT_KINDS["linear_attention" if (i + 1) % interval else "full_attention"]
                     for i in range(config["num_hidden_layers"])
                 ]
+            if config.get("model_type") == "mimo_v2":
+                config.update({k: v for k, v in _MIMO_V2_NULLS.items() if config.get(k) is None})
+                pattern = config.get("hybrid_layer_pattern") if config.get("layer_types") is None else None
+                if pattern is not None:  # a value without a kind keeps a name the registry refuses
+                    config["layer_types"] = [
+                        _MIMO_V2_KINDS.get(kind, f"hybrid_layer_pattern {kind}")
+                        for kind in pattern[: config["num_hidden_layers"]]
+                    ]
         names = {f.name for f in dataclasses.fields(cls)}
         picked = {k: v for k, v in config.items() if k in names}
         if picked.get("layer_types") is not None:
@@ -282,6 +332,8 @@ class TrunkConfig:
         for key in ("experts_held", "mlp_only_layers"):
             if picked.get(key) is not None:
                 picked[key] = tuple(picked[key])
+        if isinstance(picked.get("moe_layer_freq"), list):
+            picked["moe_layer_freq"] = tuple(picked["moe_layer_freq"])
         picked.update(overrides)
         return cls(**picked)
 
@@ -328,12 +380,15 @@ class TrunkConfig:
             raise ValueError(
                 f"trunk config: {self.num_hidden_layers} layers, layer_types names {len(self.layer_types)}"
             )
+        freq = self.moe_layer_freq
+        if isinstance(freq, tuple) and len(freq) < self.num_hidden_layers:
+            raise ValueError(f"trunk config: {self.num_hidden_layers} layers, moe_layer_freq names {len(freq)}")
         table = []
         for i in range(self.num_hidden_layers):
             sparse = (
                 self.n_routed_experts > 0
                 and i >= self.first_k_dense_replace
-                and i % self.moe_layer_freq == 0
+                and (freq[i] == 1 if isinstance(freq, tuple) else i % freq == 0)
             )
             # a type this file has no kind for keeps its own name, and the registry refuses it
             attention = (
@@ -511,12 +566,16 @@ def _gqa_shapes(c: TrunkConfig) -> dict:
     }
 
 
-def interleaved_rope_tables(config: TrunkConfig, length: int, width: int = 0) -> tuple[np.ndarray, np.ndarray]:
+def interleaved_rope_tables(
+    config: TrunkConfig, length: int, width: int = 0, theta: float | None = None
+) -> tuple[np.ndarray, np.ndarray]:
     """cos and sin [length, width / 2] of positions 0..length-1 (``width``
     the rotated dims, all ``head_dim`` of them by default): pair i turns by
-    position x theta^(-2i / width)."""
+    position x theta^(-2i / width), ``theta`` the config's ``rope_theta``
+    unless given."""
     width = width or config.head_dim
-    inv_freq = 1.0 / float(config.rope_theta) ** (np.arange(0, width, 2, dtype=np.float64) / width)
+    theta = config.rope_theta if theta is None else theta
+    inv_freq = 1.0 / float(theta) ** (np.arange(0, width, 2, dtype=np.float64) / width)
     angles = np.arange(length, dtype=np.float64)[:, None] * inv_freq[None, :]
     return np.cos(angles).astype(np.float32), np.sin(angles).astype(np.float32)
 
@@ -666,6 +725,57 @@ def _gqa_gated(p, h, c: TrunkConfig, ctx: dict):
     return out.astype(h.dtype)
 
 
+def _split_shapes(c: TrunkConfig, kv_heads: int, width_v: int) -> dict:
+    heads, width = c.num_attention_heads, c.head_dim
+    return {
+        "wq": ((c.hidden_size, heads, width), "kernel"),
+        "wk": ((c.hidden_size, kv_heads, width), "kernel"),
+        "wv": ((c.hidden_size, kv_heads, width_v), "kernel"),
+        "wo": ((heads, width_v, c.hidden_size), "kernel_out"),
+    }
+
+
+def _swa_sink_shapes(c: TrunkConfig) -> dict:
+    shapes = _split_shapes(c, c.swa_num_key_value_heads, c.swa_v_head_dim)
+    shapes["sinks"] = ((c.num_attention_heads,), "sink")
+    return shapes
+
+
+def _gqa_partial_shapes(c: TrunkConfig) -> dict:
+    return _split_shapes(c, c.num_key_value_heads, c.v_head_dim)
+
+
+def _split_gqa(p, h, c: TrunkConfig, ctx: dict, *, window: bool):
+    """Grouped-query attention with query and key heads of ``head_dim`` and
+    value heads of their own width (``mimo_v2``'s layers): rotary by halves
+    on the first ``head_dim * rotary_pct`` dims of q and k (dims i and i +
+    half paired), query head j reading key-value head j // G, softmax at
+    ``head_dim^-1/2``, the output times ``attention_value_scale``. A window
+    layer (``swa_num_key_value_heads``, ``swa_rope_theta``) sees the last
+    ``sliding_window`` tokens, itself included, beside its query head's sink;
+    a full layer (``num_key_value_heads``, ``rope_theta``) the whole row
+    before it and no sink."""
+    width = c.head_dim
+    with scope("trunk.attn.qkv"):
+        q, k, v = _heads(h, p["wq"]), _heads(h, p["wk"]), _heads(h, p["wv"])
+        cos, sin = ctx["rope_swa" if window else "rope_full"]
+        turned = 2 * cos.shape[-1]
+        q, k = (jnp.concatenate([_rotate_pairs(a[..., :turned], cos, sin), a[..., turned:]], axis=-1) for a in (q, k))
+    batch, heads, length, _ = q.shape
+    kv_heads = k.shape[1]
+    with scope("trunk.attn.kernel"):
+        mixed = block_attention.attention(
+            q.reshape(batch, kv_heads, heads // kv_heads, length, width), k, v,
+            scale=width**-0.5, window=c.sliding_window if window else None, lengths=ctx.get("lengths"),
+            sinks=p["sinks"].reshape(kv_heads, heads // kv_heads) if window else None,
+        ).reshape(batch, heads, length, v.shape[-1])
+    with scope("trunk.attn.out"):  # the value scale is linear in v: it is taken on the out-projection's sum
+        out = jnp.einsum("bhte,hed->btd", mixed, p["wo"].astype(h.dtype), preferred_element_type=jnp.float32)
+        if c.attention_value_scale != 1:
+            out = out * c.attention_value_scale
+        return out.astype(h.dtype)
+
+
 def _gdn_shapes(c: TrunkConfig) -> dict:
     heads = c.linear_num_value_heads
     key, value = c.linear_num_key_heads * c.linear_key_head_dim, heads * c.linear_value_head_dim
@@ -727,7 +837,11 @@ ATTENTION = {
     "mamba2": Block(_mamba2_shapes, _mamba2, "trunk.mamba2"),
     "gqa_gated": Block(_gqa_gated_shapes, _gqa_gated, "trunk.gqa_gated"),
     "gated_deltanet": Block(_gdn_shapes, _gdn, "trunk.gdn"),
+    "swa_sink": Block(_swa_sink_shapes, functools.partial(_split_gqa, window=True), "trunk.swa_sink"),
+    "gqa_partial": Block(_gqa_partial_shapes, functools.partial(_split_gqa, window=False), "trunk.gqa_partial"),
 }
+# the kinds that run ``ops/block_attention.py``, and whether each is a window's
+_BLOCKED = {"gqa_window": True, "swa_sink": True, "gqa_full": False, "gqa_gated": False, "gqa_partial": False}
 
 # -- feed-forward kinds --------------------------------------------------------
 
@@ -762,9 +876,9 @@ def _moe_shapes(c: TrunkConfig) -> dict:
         "w_gate": ((held, d, f), "expert_kernel"),
         "w_up": ((held, d, f), "expert_kernel"),
         "w_down": ((held, f, d), "expert_kernel"),
-        # the shared experts side by side: one gated FFN whose output is their sum
-        "shared": _gated_shapes(d, c.shared_intermediate_size or f * c.n_shared_experts),
     }
+    if c.n_shared_experts:  # the shared experts side by side: one gated FFN whose output is their sum
+        shapes["shared"] = _gated_shapes(d, c.shared_intermediate_size or f * c.n_shared_experts)
     if c.topk_method != "noaux_tc":  # a plain top-k has no correction bias
         del shapes["bias"]
     if c.shared_expert_combination_strategy == "sigmoid_gate":
@@ -781,6 +895,8 @@ def _moe(p, h, c: TrunkConfig, ctx: dict):
     )
     ctx["expert_counts"].append(counts)
     ctx["expert_choice"].append(choice.reshape(h.shape[:-1] + choice.shape[-1:]))
+    if not c.n_shared_experts:
+        return routed.astype(h.dtype).reshape(h.shape)
     with scope("trunk.moe.shared"):
         shared = _gated_ffn(p["shared"], flat).astype(jnp.float32)
         if c.shared_expert_combination_strategy == "sigmoid_gate":
@@ -974,6 +1090,8 @@ def _init_leaf(key, shape, kind, dtype, streams):
         return dt + jnp.log(-jnp.expm1(-dt))
     if kind == "ones":
         return jnp.ones(shape, jnp.float32)
+    if kind == "sink":  # a sink at 0: exp(0) = 1 beside a row's keys
+        return jnp.zeros(shape, jnp.float32)
     if kind == "mhc_proj":  # [n, d, n + n + n * n]: fan-in is all the streams
         return (normal() / math.sqrt(shape[0] * shape[1])).astype(dtype)
     if kind == "mhc_alpha":
@@ -1034,6 +1152,11 @@ def forward(params, ids, mask, *, config: TrunkConfig):
         ctx["rope_pairs"] = interleaved_rope_tables(config, ids.shape[1])
     if "gqa_gated" in kinds_of_attention:
         ctx["rope_half"] = interleaved_rope_tables(config, ids.shape[1], int(config.head_dim * config.rotary_pct))
+    turned = int(config.head_dim * config.rotary_pct)
+    if "swa_sink" in kinds_of_attention:
+        ctx["rope_swa"] = interleaved_rope_tables(config, ids.shape[1], turned, config.swa_rope_theta)
+    if "gqa_partial" in kinds_of_attention:
+        ctx["rope_full"] = interleaved_rope_tables(config, ids.shape[1], turned)
     with scope("trunk.embed"):
         x = params["embed"][ids]
         if config.embedding_multiplier != 1:
@@ -1093,9 +1216,9 @@ class TrunkRuntime:
         table = config.layer_table()
         # the window (None: the whole row) of each blocked attention layer
         self._windows = [
-            config.sliding_window if kinds.attention == "gqa_window" else None
+            config.sliding_window if _BLOCKED[kinds.attention] else None
             for kinds in table
-            if kinds.attention in ("gqa_window", "gqa_full", "gqa_gated")
+            if kinds.attention in _BLOCKED
         ]
         self._scans = sum(kinds.attention == "mamba2" for kinds in table)
         self._deltas = sum(kinds.attention == "gated_deltanet" for kinds in table)
@@ -1123,20 +1246,24 @@ class TrunkRuntime:
         """What the blocked attention layers of one forward are asked for and
         what their kernel visits, in query-key pairs a head: the pairs inside
         the masks over each row's real tokens, and the pairs of the blocks
-        visited at the forwarded ``width`` up to each row's last real token
-        (a bucket's padding rows visit nothing). Nothing for a table without
-        such a layer."""
-        windows = self._windows
-        if not windows:
-            return {}
-        return {
-            "attn_pairs_allowed": sum(
-                block_attention.pairs_allowed(int(t), w) for w in windows for t in lengths
-            ),
-            "attn_pairs_visited": sum(
-                block_attention.pairs_visited(width, w, tokens=int(t)) for w in windows for t in lengths
-            ),
-        }
+        the kernel visits at each layer's window and the forwarded ``width``
+        up to each row's last real token (a bucket's padding rows visit
+        nothing); ``attn_window_pairs_*`` the same of the window layers
+        alone, where the table has one. Nothing for a table without such a
+        layer."""
+        counts = {}
+        for prefix, windows in (
+            ("attn_pairs", self._windows),
+            ("attn_window_pairs", [w for w in self._windows if w is not None]),
+        ):
+            if windows:
+                counts[prefix + "_allowed"] = sum(
+                    block_attention.pairs_allowed(int(t), w) for w in windows for t in lengths
+                )
+                counts[prefix + "_visited"] = sum(
+                    block_attention.pairs_visited(width, w, tokens=int(t)) for w in windows for t in lengths
+                )
+        return counts
 
     def _scan_chunks(self, lengths: np.ndarray, rows: int, width: int) -> dict:
         """The chunks of the recurrent scans of one forward, all ``mamba2``
@@ -1163,7 +1290,9 @@ class TrunkRuntime:
         counts, ``residual="mhc_fused"`` where the streams are mixed by the two
         kernels of ``ops/residual_mix.py``, where the table has blocked
         attention layers their
-        ``attn_pairs_allowed`` and ``attn_pairs_visited``, where it has
+        ``attn_pairs_allowed`` and ``attn_pairs_visited`` (and of its window
+        layers alone ``attn_window_pairs_allowed`` and
+        ``attn_window_pairs_visited``), where it has
         ``mamba2`` layers their ``ssm_chunks_useful`` and
         ``ssm_chunks_visited``, and where it has ``gated_deltanet`` layers
         their ``gdn_chunks_useful`` and ``gdn_chunks_visited``. ``routing=True``

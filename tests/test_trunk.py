@@ -1112,6 +1112,7 @@ def test_the_two_shares_of_a_granite_layer_add_up_to_the_uncut_layer(ssm, layer,
         (CONFIG_FILE, 147, 4_323_079_812, "1a103e61132f4735", "bc8af1fcd481872d"),
         (GQA_FILE, 50, 4_733_292_544, "fc28f37aca32afce", "4980178b532d60b4"),
         (SSM_FILE, 168, 4_757_211_776, "34a1ce4295c7079c", "e3c685766aee49ff"),
+        (os.path.join(ROOT, "benchmarks", "configs", "qwen3-next-80b-a3b.json"), 69, 3_522_030_656, "7c8e5eab530dfeb4", "f4335ab96b8432a5"),
     ],
 )
 def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, signature, program):
@@ -1120,7 +1121,9 @@ def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, sig
     the commit before the delta-rule kind), and their forwards at the toy
     sizes, equation for equation (the jaxpr), as the commit before the
     delta-rule kind traced them with the dispatch of one sort and no
-    comparison by group (the same plan: section (e))."""
+    comparison by group (the same plan: section (e)); qwen3-next's tree and
+    forward as the commit before the window-with-sinks kinds built and
+    traced them."""
     import hashlib
 
     config = TrunkConfig.from_file(file, name="as-before")
@@ -1129,7 +1132,7 @@ def test_the_other_trunks_parameter_trees_are_unchanged(file, leaves, total, sig
     assert (len(flat), sum(int(np.prod(a.shape)) for _, a in flat)) == (leaves, total)
     described = repr([(str(path), a.shape, str(a.dtype)) for path, a in flat])
     assert hashlib.sha256(described.encode()).hexdigest()[:16] == signature
-    if file != SSM_FILE:
+    if file in (CONFIG_FILE, GQA_FILE):
         assert config.attention_multiplier is None and config.residual_multiplier == 1 and config.embedding_multiplier == 1
         assert config.shared_intermediate_size == 0 and config.scoring_func == "sigmoid"
     toy = TrunkConfig.from_dict(toy_dict(file), name="toy")

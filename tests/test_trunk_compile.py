@@ -271,3 +271,38 @@ def test_a_residual_step_passes_over_its_streams_twice_and_no_more(one_chip, no_
     mhc = [r.name.split(".")[0] for r in rows if r.scope == "trunk.mhc"]
     assert residual_mix.MIX_IN_KERNEL_NAME in mhc and residual_mix.MIX_OUT_KERNEL_NAME in mhc
     assert len(mhc) < 20, mhc
+
+
+@pytest.mark.parametrize("rows, width", [(1, 16384), (2, 8192)], ids=["1x16384", "2x8192"])
+def test_the_window_sink_forward_fits_a_v5e_and_keeps_no_logits(one_chip, no_cache, monkeypatch, rows, width):
+    """MiMo-V2.5's cut (7 layers, 16 of 256 experts, published widths) at
+    the 16,384 positions of a whole group: parameters and temporaries under
+    the chip's 16.9 GB with room for the index, the five window layers and
+    the two full layers each as the blocked kernel (no [B, H, T, T] logits
+    anywhere), the window's key block following its 128 tokens."""
+    import functools
+
+    from pathway_tpu.xpacks.llm import _trunk
+
+    for module in (moe, block_attention):
+        monkeypatch.setattr(module, "pallas_interpret", lambda: False)
+    config = _trunk.TrunkConfig.from_file(os.path.join(ROOT, "benchmarks", "configs", "mimo-v2.5.json"), name="mimo-v2.5")
+    template = jax.eval_shape(lambda: _trunk.init_params(config, 0, jnp.bfloat16))
+    params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), template)
+    compiled = jax.jit(functools.partial(_trunk.forward, config=config)).lower(
+        params,
+        jax.ShapeDtypeStruct((rows, width), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert 6.70e9 < memory.argument_size_in_bytes < 6.72e9  # 3,351,836,480 parameters, the routers float32
+    # 2.1-3.1 GB of temporaries (the dense layer's float32 gate and up at [B, T, 16,384]); 58% of the chip's 16.9 GB
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 10.5e9
+    text = compiled.as_text()
+    # seven attention kernels and eighteen grouped matmuls, each of them Mosaic's
+    assert text.count("tpu_custom_call") >= 25
+    assert block_attention.ATTN_KERNEL_NAME in text and moe.GMM_KERNEL_NAME in text
+    assert block_attention.blocks(width, window=128) == (128, 128)
+    # the largest float32 shape written is the dense layer's gate or up [B, T, 16,384]
+    floats = [math.prod(map(int, dims.split(","))) for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+    assert max(floats) == rows * width * 16384 < rows * 64 * width * width
